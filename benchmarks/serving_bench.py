@@ -564,14 +564,16 @@ def run_sharded_decode(smoke: bool, *, mesh_sizes=(1, 2, 4),
     paged, at mesh sizes 1/2/4 — the structural check that the engine
     runs *unchanged* at every mesh size.
 
-    Each cell is a fresh ``repro.launch.serve --mesh N`` subprocess: the
-    host-platform device count locks at the first jax init, so every mesh
-    size needs its own forced placeholder topology.  On one physical CPU
-    the absolute ms/step therefore measures GSPMD partitioning overhead,
-    not speedup (the "devices" share one core); on a real multi-device
-    backend the same sweep measures the actual TP scaling, subprocess-free
-    flag included.
+    Each cell is one ``repro.launch.serve --mesh N`` run.  On the TPU
+    backend all cells run in this process over sub-meshes of the
+    attached chips: a chip belongs to one process, and this one already
+    holds them.  On the CPU each cell is a fresh subprocess, because the
+    host-platform device count locks at the first jax init and every
+    mesh size needs its own forced placeholder topology; there the
+    absolute ms/step measures GSPMD partitioning overhead, not speedup
+    (the "devices" share one core).
     """
+    in_process = jax.default_backend() == "tpu"
     requests, max_new = (3, 4) if smoke else (6, 12)
     out, rows = {}, []
     for layout in layouts:
@@ -579,22 +581,27 @@ def run_sharded_decode(smoke: bool, *, mesh_sizes=(1, 2, 4),
         for n in mesh_sizes:
             fd, path = tempfile.mkstemp(suffix=".json")
             os.close(fd)
-            cmd = [sys.executable, "-m", "repro.launch.serve",
-                   "--arch", "smollm-135m", "--smoke",
-                   "--requests", str(requests), "--tasks", "2",
-                   "--slots", "2", "--max-new", str(max_new),
-                   "--kv-layout", layout, "--mesh", str(n),
-                   "--stats", "--metrics", path]
-            env = dict(
-                os.environ,
-                XLA_FLAGS=f"--xla_force_host_platform_device_count={n}")
+            argv = ["--arch", "smollm-135m", "--smoke",
+                    "--requests", str(requests), "--tasks", "2",
+                    "--slots", "2", "--max-new", str(max_new),
+                    "--kv-layout", layout, "--mesh", str(n),
+                    "--stats", "--metrics", path]
             try:
-                res = subprocess.run(cmd, capture_output=True, text=True,
-                                     timeout=900, env=env)
-                if res.returncode != 0:
-                    raise RuntimeError(
-                        f"sharded_decode cell (mesh={n}, {layout}) failed:\n"
-                        + res.stderr[-2000:])
+                if in_process:
+                    from repro.launch import serve
+
+                    serve.main(argv)
+                else:
+                    env = dict(os.environ, JAX_PLATFORMS="cpu",
+                               XLA_FLAGS="--xla_force_host_platform_"
+                                         f"device_count={n}")
+                    res = subprocess.run(
+                        [sys.executable, "-m", "repro.launch.serve", *argv],
+                        capture_output=True, text=True, timeout=900, env=env)
+                    if res.returncode != 0:
+                        raise RuntimeError(
+                            f"sharded_decode cell (mesh={n}, {layout}) "
+                            "failed:\n" + res.stderr[-2000:])
                 with open(path) as f:
                     metrics = json.load(f)
             finally:
@@ -614,11 +621,12 @@ def run_sharded_decode(smoke: bool, *, mesh_sizes=(1, 2, 4),
                          f"{cell['ms_per_step']:.2f}"))
     print(C.fmt_table(
         rows, ("kv layout", "mesh (data x model)", "decode steps",
-               "ms/step (CPU)")) + "\n")
-    print("sharded_decode: one subprocess per mesh size (device count "
-          "locks at jax init); on a single physical CPU the forced "
-          "devices share one core, so ms/step tracks partitioning "
-          "overhead — the speedup column needs real devices\n")
+               f"ms/step ({jax.default_backend()})")) + "\n")
+    if not in_process:
+        print("sharded_decode: one subprocess per mesh size (device count "
+              "locks at jax init); on a single physical CPU the forced "
+              "devices share one core, so ms/step tracks partitioning "
+              "overhead — the speedup column needs real devices\n")
     return out
 
 

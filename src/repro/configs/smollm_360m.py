@@ -1,4 +1,4 @@
-"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-135M; hf].
+"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-360M; hf].
 
 32L, d_model=960, 15 heads (GQA kv=5), d_ff=2560, vocab=49152.
 """
@@ -20,7 +20,7 @@ def config() -> ModelConfig:
         tie_embeddings=True,
         max_seq=40_960,
         memcom=MemComConfig(num_memory_tokens=512),
-        source="[hf:HuggingFaceTB/SmolLM-135M; hf]",
+        source="[hf:HuggingFaceTB/SmolLM-360M; hf]",
     )
 
 

@@ -121,13 +121,31 @@ def init_memcom(cfg: ModelConfig, target_params, seed: int | Keys = 0,
     }
 
 
+def init_models(cfg: ModelConfig, mesh=None, rules=None, *, seed: int = 0):
+    """Random target (``seed``) and MemCom compressor (``seed + 1``).
+    With a mesh both are created on their shards from their logical axes
+    — a model too large for one device is never built on one."""
+    if mesh is None:
+        target = tfm.init_params(cfg, seed)
+        return target, init_memcom(cfg, target, seed + 1)
+    from repro.sharding.rules import init_sharded
+
+    target = init_sharded(lambda: tfm.init_params(cfg, seed),
+                          tfm.param_specs(cfg), mesh, rules)
+    compressor = init_sharded(lambda t: init_memcom(cfg, t, seed + 1),
+                              memcom_axes(cfg), mesh, rules, target)
+    return target, compressor
+
+
 def compress(mc_params, cfg: ModelConfig, source_tokens=None, *,
              source_embeds=None, encoder_frames=None, remat: bool = False,
-             unroll: bool = False, impl: str = "auto"):
+             unroll: bool = False, impl: str = "auto", mesh=None):
     """Many-shot tokens (B, T) -> Layerwise compressed prefix for the target.
 
     Returns (prefix, info).  prefix entries: attn/mla -> {"h": O^i (B,m,D)};
-    mamba -> {"ssm": final source state (B,H,P,N)}.
+    mamba -> {"ssm": final source state (B,H,P,N)}.  ``mesh``: the
+    compressor's parameters are sharded over it (the kernels then run
+    per device).
     """
     B = (source_tokens if source_tokens is not None else source_embeds).shape[0]
     mem = cfg.memcom.num_memory_tokens
@@ -141,7 +159,7 @@ def compress(mc_params, cfg: ModelConfig, source_tokens=None, *,
         capture_hiddens=True,
         cache=state_cache, cache_index=0 if state_cache is not None else None,
         encoder_frames=encoder_frames, logits=False, remat=remat,
-        unroll=unroll, impl=impl)
+        unroll=unroll, impl=impl, mesh=mesh)
 
     mem_embeds = jnp.broadcast_to(
         mc_params["mem_tokens"][None], (B, mem, cfg.d_model)
@@ -150,7 +168,7 @@ def compress(mc_params, cfg: ModelConfig, source_tokens=None, *,
         mc_params["memory_llm"], cfg, embeds=mem_embeds,
         memcom={"params": _memx_wrap(mc_params["memx"]), "src": aux_s["hiddens"]},
         encoder_out=aux_s["encoder_out"], logits=False, remat=remat,
-        unroll=unroll, impl=impl)
+        unroll=unroll, impl=impl, mesh=mesh)
 
     prefix = build_prefix(cfg, aux_m["omega"], aux_s["cache"])
     info = {"encoder_out": aux_s["encoder_out"]}
@@ -200,7 +218,8 @@ def begin_compress(cfg: ModelConfig, batch: int, total_len: int, *,
 
 
 def compress_chunk(mc_params, cfg: ModelConfig, state: CompressionState,
-                   tokens, *, impl: str = "auto") -> CompressionState:
+                   tokens, *, impl: str = "auto",
+                   mesh=None) -> CompressionState:
     """Run the Source-LLM over one chunk of the shot set and fold the
     result into ``state``.  ``tokens`` is (B, w); ``state.offset`` must be
     a python int (the continuation slice is static, as in engine prefill —
@@ -210,13 +229,13 @@ def compress_chunk(mc_params, cfg: ModelConfig, state: CompressionState,
     _, aux = tfm.forward(
         mc_params["source"], cfg, tokens=tokens, capture_hiddens=True,
         cache=state.cache, cache_index=offset, mask_offset=offset,
-        encoder_out=state.encoder_out, logits=False, impl=impl)
+        encoder_out=state.encoder_out, logits=False, impl=impl, mesh=mesh)
     return replace(state, cache=aux["cache"], offset=offset + tokens.shape[1],
                    hiddens=state.hiddens + [aux["hiddens"]])
 
 
 def finish_compress(mc_params, cfg: ModelConfig, state: CompressionState, *,
-                    impl: str = "auto"):
+                    impl: str = "auto", mesh=None):
     """Close a chunked compression: concatenate the captured H^i along the
     source-time axis, run the Memory-LLM once over the m memory tokens,
     and package the per-layer prefix.  Same return shape as
@@ -235,7 +254,7 @@ def finish_compress(mc_params, cfg: ModelConfig, state: CompressionState, *,
     _, aux_m = tfm.forward(
         mc_params["memory_llm"], cfg, embeds=mem_embeds,
         memcom={"params": _memx_wrap(mc_params["memx"]), "src": hiddens},
-        encoder_out=state.encoder_out, logits=False, impl=impl)
+        encoder_out=state.encoder_out, logits=False, impl=impl, mesh=mesh)
     prefix = build_prefix(cfg, aux_m["omega"], state.cache)
     return prefix, {"encoder_out": state.encoder_out}
 

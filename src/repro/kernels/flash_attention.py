@@ -8,24 +8,31 @@ all expressed through the (q_pos, kv_pos) contract of
 
 TPU mapping
 -----------
+The wrapper moves heads in front of the sequence (``(B, H, S, D)``), so
+every block's last two dims are a (rows, head-width) tile: rows a
+multiple of 8, the head width whole.  That is the layout Mosaic's (8, 128)
+tiling rule accepts for every head width (64/80/128/256 pad to lanes once
+per tile), batch size and GQA group.
+
 Grid ``(B, Hq, nq, nk)`` — the KV-block axis is innermost and
 ``ARBITRARY`` (sequential) so the online-softmax state for one (batch,
 head, q-block) lives in VMEM scratch across its KV sweep; batch/head/
 q-block axes are ``PARALLEL``. Blocks:
 
-* q     (1, bq, 1, D)  — one head's q tile; D kept whole (128-aligned
-  head dims: 64/80/128 pad to lane width once, not per block).
-* k/v   (1, bk, 1, D)  — indexed by ``h // G`` (GQA: G q-heads share one
-  KV head, so consecutive q-heads reuse the same KV tile; with the head
-  axis PARALLEL adjacent programs hit VMEM-resident tiles).
-* positions (1, bq)/(1, bk) int32 — drive masking inside the kernel; the
-  causal test is ``kv_pos <= q_pos`` so decode, sliding windows, and
-  MemCom's "memory slots visible to everyone" all reduce to position
-  vectors, no mask tensors in HBM.
+* q/o   (bq, D)  — one head's query tile.
+* k/v   (bk, D)  — indexed by ``h // G`` (GQA: G q-heads share one KV
+  head, so consecutive q-heads reuse the same KV tile).
+* q positions ``(bq, 1)`` and kv positions ``(1, bk)`` int32 — a column
+  and a row, so the causal test ``kv_pos <= q_pos`` broadcasts to the
+  logits tile with no transpose.  Decode, sliding windows and MemCom's
+  "memory slots visible to everyone" all reduce to position vectors, no
+  mask tensors in HBM.
+* lse   (bq, 1) f32 per head.
 
-Scratch: acc (bq, D) f32, running max m and sum l (bq, 1) f32
-=> VMEM footprint ≈ bq*D*4 + 2*(bq+bk)*D*2 bytes; defaults bq=bk=512,
-D=128 ≈ 1.3 MB — triple-buffered comfortably under the 16 MB/core budget.
+``bk`` must be a multiple of 128 when the KV axis spans several blocks
+(the kv-position row is lane-tiled); the default 512 is.
+
+Scratch: acc (bq, D) f32, running max m and sum l (bq, 1) f32.
 
 Block-level skip: a KV block whose minimum kv_pos exceeds the block's
 maximum q_pos contributes nothing under the causal mask — `pl.when`
@@ -44,6 +51,7 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
+from repro.kernels.pltpu_compat import mxu_precision
 
 NEG_INF = -1e30
 
@@ -52,7 +60,7 @@ def _attn_kernel(
     q_pos_ref, kv_pos_ref, q_ref, k_ref, v_ref,  # inputs
     o_ref, lse_ref,  # outputs
     acc, m_scr, l_scr,  # scratch
-    *, scale: float, causal: bool, softcap: float, block_k: int,
+    *, scale: float, causal: bool, softcap: float,
 ):
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -63,31 +71,33 @@ def _attn_kernel(
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    q_pos = q_pos_ref[0]  # (bq,) int32
-    kv_pos = kv_pos_ref[0]  # (bk,) int32
+    q_pos = q_pos_ref[...]  # (bq, 1) int32
+    kv_pos = kv_pos_ref[...]  # (1, bk) int32
 
     def compute():
-        q = q_ref[0, :, 0, :]  # (bq, D)
-        k = k_ref[0, :, 0, :]  # (bk, D)
-        v = v_ref[0, :, 0, :]  # (bk, D)
+        q = q_ref[...]  # (bq, D)
+        k = k_ref[...]  # (bk, D)
+        v = v_ref[...]  # (bk, Dv)
+        prec = mxu_precision(q.dtype)
         logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())), precision=prec,
             preferred_element_type=jnp.float32) * scale
         if softcap:
             logits = softcap * jnp.tanh(logits / softcap)
-        valid = (kv_pos >= 0)[None, :]
+        valid = kv_pos >= 0
         if causal:
-            valid = valid & (kv_pos[None, :] <= q_pos[:, None])
+            valid = valid & (kv_pos <= q_pos)
+        valid = jnp.broadcast_to(valid, logits.shape)
         logits = jnp.where(valid, logits, NEG_INF)
 
         m_prev = m_scr[...]  # (bq, 1)
         m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
-        p = jnp.exp(logits - m_new)
+        p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)  # (bq, 1)
         l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
         m_scr[...] = m_new
         pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), precision=prec,
             preferred_element_type=jnp.float32)
         acc[...] = acc[...] * corr + pv
 
@@ -103,11 +113,9 @@ def _attn_kernel(
     def _finish():
         l = l_scr[...]
         out = acc[...] / jnp.maximum(l, 1e-37)
-        out = jnp.where(l > 0, out, 0.0)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
-        lse = jnp.where(
+        o_ref[...] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
+        lse_ref[...] = jnp.where(
             l > 0, m_scr[...] + jnp.log(jnp.maximum(l, 1e-37)), NEG_INF)
-        lse_ref[0, :, 0] = lse[:, 0]
 
 
 def _pad_to(x, mult, axis, value=0):
@@ -117,6 +125,10 @@ def _pad_to(x, mult, axis, value=0):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths, constant_values=value)
+
+
+def _round_up(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
 
 
 @functools.partial(
@@ -136,38 +148,47 @@ def flash_attention(
     if scale is None:
         scale = D**-0.5
 
-    bq = min(block_q, max(Sq, 8))
-    bk = min(block_k, max(Skv, 8))
-    qp = _pad_to(q, bq, axis=1)
-    kp = _pad_to(k, bk, axis=1)
-    vp = _pad_to(v, bk, axis=1)
+    # row blocks are multiples of 8 (the sublane tile); a sequence shorter
+    # than the block is padded to one block
+    bq = min(block_q, _round_up(Sq, 8))
+    bk = min(block_k, _round_up(Skv, 8))
+    # head-major: (B, H, S, D)
+    qp = _pad_to(q, bq, axis=1).transpose(0, 2, 1, 3)
+    kp = _pad_to(k, bk, axis=1).transpose(0, 2, 1, 3)
+    vp = _pad_to(v, bk, axis=1).transpose(0, 2, 1, 3)
     # padded q rows: positions below every valid kv so causal masks all;
     # padded kv slots: -1 marks invalid under both mask kinds
-    q_pos_p = _pad_to(q_pos.astype(jnp.int32), bq, axis=1, value=-(2**30))
-    kv_pos_p = _pad_to(kv_pos.astype(jnp.int32), bk, axis=1, value=-1)
-    Sqp, Skvp = qp.shape[1], kp.shape[1]
+    q_pos_p = _pad_to(q_pos.astype(jnp.int32), bq, axis=1,
+                      value=-(2**30))[:, :, None]  # (B, Sqp, 1)
+    kv_pos_p = _pad_to(kv_pos.astype(jnp.int32), bk, axis=1,
+                       value=-1)[:, None, :]  # (B, 1, Skvp)
+    Sqp, Skvp = qp.shape[2], kp.shape[2]
     nq, nk = Sqp // bq, Skvp // bk
 
     kernel = functools.partial(
-        _attn_kernel, scale=scale, causal=causal, softcap=softcap,
-        block_k=bk)
+        _attn_kernel, scale=scale, causal=causal, softcap=softcap)
     out, lse = pl.pallas_call(
         kernel,
         grid=(B, Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq), lambda b, h, iq, ik: (b, iq)),
-            pl.BlockSpec((1, bk), lambda b, h, iq, ik: (b, ik)),
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, Dv), lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((None, bq, 1), lambda b, h, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((None, 1, bk), lambda b, h, iq, ik: (b, 0, ik)),
+            pl.BlockSpec((None, None, bq, D),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((None, None, bk, D),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((None, None, bk, Dv),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, 1, Dv), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, h, iq, ik: (b, iq, h)),
+            pl.BlockSpec((None, None, bq, Dv),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((None, None, bq, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sqp, Hq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, Sqp, Hq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sqp, Dv), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, Sqp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, Dv), jnp.float32),
@@ -180,7 +201,7 @@ def flash_attention(
         interpret=interpret,
     )(q_pos_p, kv_pos_p, qp, kp, vp)
 
-    out = out[:, :Sq]
+    out = out.transpose(0, 2, 1, 3)[:, :Sq]
     if return_lse:
-        return out, lse[:, :Sq]
+        return out, lse[..., 0].transpose(0, 2, 1)[:, :Sq]
     return out

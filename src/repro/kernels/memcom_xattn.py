@@ -20,10 +20,13 @@ are staged right. We tile it as a blocked matmul pipeline in VMEM:
   (bm × D)·(D × bt) product runs at full systolic occupancy without the
   head-dim padding waste a 64/80-wide head would suffer.
 
-VMEM: Q + K + V tiles (bf16) + acc (bm, D, f32). At D = 8192 the acc
-dominates: bm=128 → 4 MB acc + 2 MB Q + 2·(bt=256)·16 KB = 12 MB, under
-budget; at the paper's own scales (D ≤ 4096) bm=256, bt=512 fits.
-``_pick_blocks`` auto-sizes to the VMEM budget.
+VMEM: the pipeline keeps two buffers of every input and output tile
+(Q, K, V and O), plus the f32 acc (bm, D) and the (bm, bt) f32 logits
+temporaries.  ``_pick_blocks`` picks the largest (bm, bt) whose total fits
+``_VMEM_BUDGET`` and the call raises the compiler's scoped-VMEM limit to
+``_VMEM_LIMIT`` (the default scoped limit, 16 MiB on v5e, would refuse
+D ≥ 2304 at useful tile sizes).  E.g. D = 2304 in bf16 gets bm = bt = 512 (25.5 MiB);
+D = 8192 gets bm = 128, bt = 256 (28.4 MiB).
 
 No mask: every memory token sees every source token (the paper's
 compressor is bidirectional over the source), so padding of t is handled
@@ -40,9 +43,11 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
+from repro.kernels.pltpu_compat import mxu_precision
 
 NEG_INF = -1e30
-_VMEM_BUDGET = 12 * 1024 * 1024
+_VMEM_BUDGET = 40 * 1024 * 1024
+_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _xattn_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr,
@@ -59,8 +64,9 @@ def _xattn_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr,
     q = q_ref[0]  # (bm, D)
     k = k_ref[0]  # (bt, D)
     v = v_ref[0]  # (bt, D)
+    prec = mxu_precision(q.dtype)
     logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
+        q, k, (((1,), (1,)), ((), ())), precision=prec,
         preferred_element_type=jnp.float32) * scale  # (bm, bt)
     col = it * block_t + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     logits = jnp.where(col < t_total, logits, NEG_INF)
@@ -72,7 +78,7 @@ def _xattn_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr,
     l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
     m_scr[...] = m_new
     pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())), precision=prec,
         preferred_element_type=jnp.float32)
     acc[...] = acc[...] * corr + pv
 
@@ -81,12 +87,19 @@ def _xattn_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr,
         o_ref[0] = (acc[...] / jnp.maximum(l_scr[...], 1e-37)).astype(o_ref.dtype)
 
 
+def _vmem_bytes(bm: int, bt: int, D: int, itemsize: int) -> int:
+    """VMEM one grid step holds: double-buffered Q/O (bm, D) and K/V
+    (bt, D) tiles, the f32 accumulator, and ~3 (bm, bt) f32 temporaries
+    (logits, probabilities, mask)."""
+    tiles = 2 * (2 * bm * D + 2 * bt * D) * itemsize
+    return tiles + bm * D * 4 + 3 * bm * bt * 4
+
+
 def _pick_blocks(D: int, itemsize: int) -> tuple[int, int]:
-    """Largest (bm, bt) with acc + q + 2 kv tiles under the VMEM budget."""
+    """Largest (bm, bt) whose :func:`_vmem_bytes` fits the VMEM budget."""
     for bm, bt in ((512, 512), (256, 512), (256, 256), (128, 256),
                    (128, 128), (64, 128), (32, 128)):
-        vmem = bm * D * 4 + bm * D * itemsize + 2 * bt * D * itemsize
-        if vmem <= _VMEM_BUDGET:
+        if _vmem_bytes(bm, bt, D, itemsize) <= _VMEM_BUDGET:
             return bm, bt
     return 16, 128
 
@@ -130,7 +143,8 @@ def memcom_xattn(q, k, v, *, scale=None, block_m=None, block_t=None,
         ],
         compiler_params=_CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
-                                 pltpu.ARBITRARY)),
+                                 pltpu.ARBITRARY),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(qp, kp, vp)
     return out[:, :M]

@@ -28,6 +28,7 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
+from repro.kernels.pltpu_compat import mxu_precision
 
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc):
@@ -39,6 +40,7 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc):
 
     acc[...] += jax.lax.dot_general(
         x_ref[0], w_ref[0], (((1,), (0,)), ((), ())),
+        precision=mxu_precision(x_ref.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(idd == pl.num_programs(3) - 1)

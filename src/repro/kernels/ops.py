@@ -6,14 +6,13 @@ Every op has three implementations:
 * ``jnp``    — streaming :mod:`repro.kernels.jnp_impl` (CPU, dry-run lowering)
 * ``pallas`` — TPU kernels in this package (``interpret=True`` on CPU tests)
 
-``impl="auto"`` picks ``pallas`` on TPU backends and ``jnp`` elsewhere,
-falling back to ``dense`` for very small problems where blocking overhead
-dominates.
+``impl="auto"`` picks ``pallas`` for every op on the TPU backend,
+whatever the size.  Elsewhere it picks ``jnp``, or ``dense`` for very
+small problems where blocking overhead dominates.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -35,9 +34,9 @@ def _resolve(impl: str, small: bool) -> str:
         return impl
     if _FORCED_IMPL is not None:
         return _FORCED_IMPL
-    if small:
-        return "dense"
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+    if jax.default_backend() == "tpu":
+        return "pallas"
+    return "dense" if small else "jnp"
 
 
 # ---------------------------------------------------------------------------
@@ -45,9 +44,57 @@ def _resolve(impl: str, small: bool) -> str:
 # ---------------------------------------------------------------------------
 
 
+def _interpret() -> bool:
+    """Pallas kernels compile for the TPU and run interpreted elsewhere."""
+    return jax.default_backend() != "tpu"
+
+
+def _head_parallel(mesh, *operands, head_axis=2):
+    """True when a mesh with a >1 "model" axis is installed and every
+    head-carrying operand's head dim divides it — the condition for
+    splitting a kernel by head (GQA: Hq and Hkv must both split)."""
+    from repro.sharding.serving import model_axis_size
+
+    n = model_axis_size(mesh)
+    return n > 1 and all(x.shape[head_axis] % n == 0 for x in operands)
+
+
+def _per_device(mesh, fn, head_args, rep_args, out_ndims=4):
+    """Run the Pallas call ``fn(*head_args, *rep_args)`` with ``mesh``
+    installed.  Mosaic kernels cannot be partitioned automatically, so on
+    a multi-device mesh every device runs the kernel under shard_map: on
+    its own head slice when each of ``head_args`` splits its head axis
+    over "model" (``out_ndims``: the outputs' ranks, head axis 2), else on
+    the whole, replicated operands."""
+    if mesh is None or mesh.devices.size <= 1:
+        return fn(*head_args, *rep_args)
+    from repro.sharding.serving import shard_map_heads, shard_map_replicated
+
+    if head_args and _head_parallel(mesh, *head_args):
+        wrapped = shard_map_heads(fn, mesh, head_args=len(head_args),
+                                  replicated_args=len(rep_args),
+                                  out_ndims=out_ndims)
+    else:
+        wrapped = shard_map_replicated(fn, mesh)
+    return wrapped(*head_args, *rep_args)
+
+
+def _flash(q, k, v, q_pos, kv_pos, *, mesh, **kw):
+    from repro.kernels import flash_attention  # lazy: TPU-targeted
+
+    def run(q, k, v, q_pos, kv_pos):
+        return flash_attention.flash_attention(
+            q, k, v, q_pos=q_pos, kv_pos=kv_pos, interpret=_interpret(), **kw)
+
+    return _per_device(mesh, run, (q, k, v), (q_pos, kv_pos),
+                       out_ndims=(4, 3) if kw.get("return_lse") else 4)
+
+
 def attention(q, k, v, *, q_pos, kv_pos, causal=True, softcap=0.0, scale=None,
-              impl="auto", kv_chunk=1024, return_lse=False):
-    """General position-masked GQA attention (prefix / decode / cross)."""
+              impl="auto", kv_chunk=1024, return_lse=False, mesh=None):
+    """General position-masked GQA attention (prefix / decode / cross).
+    ``mesh``: the operands live on it; the Pallas kernel then runs per
+    device (by head where heads divide the "model" axis)."""
     small = q.shape[1] * k.shape[1] <= 256 * 256
     impl = _resolve(impl, small)
     if impl == "dense":
@@ -62,12 +109,8 @@ def attention(q, k, v, *, q_pos, kv_pos, causal=True, softcap=0.0, scale=None,
             return out, lse
         return out
     if impl == "pallas":
-        from repro.kernels import flash_attention  # lazy: TPU-targeted
-
-        return flash_attention.flash_attention(
-            q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=causal,
-            softcap=softcap, scale=scale, return_lse=return_lse,
-            interpret=jax.default_backend() != "tpu")
+        return _flash(q, k, v, q_pos, kv_pos, mesh=mesh, causal=causal,
+                      softcap=softcap, scale=scale, return_lse=return_lse)
     return jnp_impl.attention_chunked(
         q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=causal, softcap=softcap,
         scale=scale, kv_chunk=kv_chunk, return_lse=return_lse)
@@ -75,7 +118,7 @@ def attention(q, k, v, *, q_pos, kv_pos, causal=True, softcap=0.0, scale=None,
 
 def self_attention_causal(q, k, v, *, offset=0, softcap=0.0, scale=None,
                           impl="auto", q_chunk=512, kv_chunk=512,
-                          return_lse=False):
+                          return_lse=False, mesh=None):
     """Pure causal self-attention (q_pos = kv_pos = offset + arange(S))."""
     S = q.shape[1]
     small = S * S <= 512 * 512
@@ -93,27 +136,13 @@ def self_attention_causal(q, k, v, *, offset=0, softcap=0.0, scale=None,
             return out, lse
         return out
     if impl == "pallas":
-        from repro.kernels import flash_attention
-
         B = q.shape[0]
         pos = jnp.broadcast_to(offset + jnp.arange(S, dtype=jnp.int32), (B, S))
-        return flash_attention.flash_attention(
-            q, k, v, q_pos=pos, kv_pos=pos, causal=True, softcap=softcap,
-            scale=scale, return_lse=return_lse,
-            interpret=jax.default_backend() != "tpu")
+        return _flash(q, k, v, pos, pos, mesh=mesh, causal=True,
+                      softcap=softcap, scale=scale, return_lse=return_lse)
     return jnp_impl.attention_causal_blocked(
         q, k, v, offset=offset, softcap=softcap, scale=scale,
         q_chunk=q_chunk, kv_chunk=kv_chunk, return_lse=return_lse)
-
-
-def _head_parallel(mesh, *operands, head_axis=2):
-    """True when a mesh with a >1 "model" axis is installed and every
-    head-carrying operand's head dim divides it — the condition for
-    splitting a decode kernel by head (GQA: Hq and Hkv must both split)."""
-    from repro.sharding.serving import model_axis_size
-
-    n = model_axis_size(mesh)
-    return n > 1 and all(x.shape[head_axis] % n == 0 for x in operands)
 
 
 def decode_attention(q, k, v, *, lengths, softcap=0.0, scale=None,
@@ -128,29 +157,30 @@ def decode_attention(q, k, v, *, lengths, softcap=0.0, scale=None,
     different compressed prefixes and ragged prompts share one batched step.
 
     The jnp path skips KV chunks beyond ``max(lengths)`` at runtime; the
-    pallas path reuses the flash kernel with per-slot position masks.
+    pallas path reads each slot's stripe as consecutive pool blocks of the
+    paged kernel (no copy of the cache; the flash kernel with per-slot
+    position masks when ``L`` is not a multiple of 8).
 
     ``mesh``: tensor-parallel serving.  Q/K/V split on the head axis over
     the mesh's "model" axis while ``lengths`` stays replicated — the jnp
     path is pinned head-parallel via a sharding constraint (GSPMD handles
     the rest), the pallas path runs per-shard under ``shard_map`` (pallas
     has no GSPMD partitioning rule).  Heads that don't divide the axis
-    fall back to the unsharded call.
+    run replicated.
     """
     B, S = q.shape[:2]
     small = S * k.shape[1] <= 256 * 256
     impl = _resolve(impl, small)
+    if impl == "pallas" and k.shape[1] % 8 == 0:
+        from repro.kernels import paged_attention  # lazy: TPU-targeted
+
+        def run(q, k, v, lengths):
+            return paged_attention.dense_flash_decode(
+                q, k, v, lengths=lengths, softcap=softcap, scale=scale,
+                interpret=_interpret())
+
+        return _per_device(mesh, run, (q, k, v), (lengths,))
     if impl in ("dense", "pallas"):
-        if impl == "pallas" and _head_parallel(mesh, q, k, v):
-            from repro.sharding.serving import shard_map_heads
-
-            def per_shard(qs, ks, vs, lens):
-                return decode_attention(qs, ks, vs, lengths=lens,
-                                        softcap=softcap, scale=scale,
-                                        impl="pallas", mesh=None)
-
-            return shard_map_heads(per_shard, mesh, head_args=3,
-                                   replicated_args=1)(q, k, v, lengths)
         L = k.shape[1]
         slot = jnp.arange(L, dtype=jnp.int32)
         kv_pos = jnp.broadcast_to(slot[None, :], (B, L))
@@ -158,12 +188,8 @@ def decode_attention(q, k, v, *, lengths, softcap=0.0, scale=None,
         if impl == "dense":
             return ref.attention_ref(q, k, v, q_pos=q_pos, kv_pos=kv_pos,
                                      causal=True, softcap=softcap, scale=scale)
-        from repro.kernels import flash_attention
-
-        return flash_attention.flash_attention(
-            q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=True,
-            softcap=softcap, scale=scale,
-            interpret=jax.default_backend() != "tpu")
+        return _flash(q, k, v, q_pos, kv_pos, mesh=mesh, causal=True,
+                      softcap=softcap, scale=scale)
     if _head_parallel(mesh, q, k, v):
         from repro.sharding.serving import constrain_heads
 
@@ -215,23 +241,16 @@ def paged_decode_attention(q, k_pool, v_pool, *, block_tables, lengths,
         return ref.attention_ref(q, k, v, q_pos=q_pos, kv_pos=kv_pos,
                                  causal=True, softcap=softcap, scale=scale)
     if impl == "pallas":
-        if _head_parallel(mesh, q, k_pool, v_pool):
-            from repro.sharding.serving import shard_map_heads
-
-            def per_shard(qs, ks, vs, tbl, lens):
-                return paged_decode_attention(
-                    qs, ks, vs, block_tables=tbl, lengths=lens,
-                    softcap=softcap, scale=scale, impl="pallas", mesh=None)
-
-            return shard_map_heads(per_shard, mesh, head_args=3,
-                                   replicated_args=2)(
-                q, k_pool, v_pool, block_tables, lengths)
         from repro.kernels import paged_attention  # lazy: TPU-targeted
 
-        return paged_attention.paged_flash_decode(
-            q, k_pool, v_pool, block_tables=block_tables, lengths=lengths,
-            softcap=softcap, scale=scale,
-            interpret=jax.default_backend() != "tpu")
+        def run(q, k_pool, v_pool, block_tables, lengths):
+            return paged_attention.paged_flash_decode(
+                q, k_pool, v_pool, block_tables=block_tables,
+                lengths=lengths, softcap=softcap, scale=scale,
+                interpret=_interpret())
+
+        return _per_device(mesh, run, (q, k_pool, v_pool),
+                           (block_tables, lengths))
     if _head_parallel(mesh, q, k_pool, v_pool):
         from repro.sharding.serving import constrain_heads
 
@@ -244,7 +263,8 @@ def paged_decode_attention(q, k_pool, v_pool, *, block_tables, lengths,
 
 
 def attention_with_prefix(q, k_self, v_self, k_pre, v_pre, *, pre_pos=None,
-                          offset=None, softcap=0.0, scale=None, impl="auto"):
+                          offset=None, softcap=0.0, scale=None, impl="auto",
+                          mesh=None):
     """Causal self-attention plus a fully-visible KV prefix (MemCom memory).
 
     Computed as two FLOP-optimal partials merged exactly via log-sum-exp —
@@ -259,12 +279,12 @@ def attention_with_prefix(q, k_self, v_self, k_pre, v_pre, *, pre_pos=None,
         pre_pos = jnp.broadcast_to(jnp.arange(m, dtype=jnp.int32), (B, m))
     o_self, l_self = self_attention_causal(
         q, k_self, v_self, offset=offset, softcap=softcap, scale=scale,
-        impl=impl, return_lse=True)
+        impl=impl, return_lse=True, mesh=mesh)
     q_pos = jnp.broadcast_to(
         offset + jnp.arange(q.shape[1], dtype=jnp.int32), (B, q.shape[1]))
     o_pre, l_pre = attention(
         q, k_pre, v_pre, q_pos=q_pos, kv_pos=pre_pos, causal=False,
-        softcap=softcap, scale=scale, impl=impl, return_lse=True)
+        softcap=softcap, scale=scale, impl=impl, return_lse=True, mesh=mesh)
     return jnp_impl.combine_attention_partials([(o_self, l_self), (o_pre, l_pre)])
 
 
@@ -273,8 +293,10 @@ def attention_with_prefix(q, k_self, v_self, k_pre, v_pre, *, pre_pos=None,
 # ---------------------------------------------------------------------------
 
 
-def memcom_xattn(q, k, v, *, scale=None, impl="auto"):
-    """1-head cross-attention, head width = d_model: (B,M,D)x(B,T,D)->(B,M,D)."""
+def memcom_xattn(q, k, v, *, scale=None, impl="auto", mesh=None):
+    """1-head cross-attention, head width = d_model: (B,M,D)x(B,T,D)->(B,M,D).
+    With a ``mesh`` the Pallas kernel runs whole on every device (one head
+    offers nothing to split)."""
     small = q.shape[1] * k.shape[1] <= 256 * 256
     impl = _resolve(impl, small)
     if impl == "dense":
@@ -282,8 +304,11 @@ def memcom_xattn(q, k, v, *, scale=None, impl="auto"):
     if impl == "pallas":
         from repro.kernels import memcom_xattn as kx
 
-        return kx.memcom_xattn(q, k, v, scale=scale,
-                               interpret=jax.default_backend() != "tpu")
+        def run(q, k, v):
+            return kx.memcom_xattn(q, k, v, scale=scale,
+                                   interpret=_interpret())
+
+        return _per_device(mesh, run, (), (q, k, v))
     # jnp streaming: reuse chunked attention with a single head
     B, M, D = q.shape
     T = k.shape[1]
@@ -310,7 +335,7 @@ def gmm(x, w, *, impl="auto"):
     if impl == "pallas":
         from repro.kernels import moe_gmm
 
-        return moe_gmm.gmm(x, w, interpret=jax.default_backend() != "tpu")
+        return moe_gmm.gmm(x, w, interpret=_interpret())
     return ref.gmm_ref(x, w)
 
 
@@ -328,7 +353,7 @@ def ssd(x, dt, A, Bm, Cm, *, init_state=None, chunk=256, impl="auto"):
         from repro.kernels import ssd_scan
 
         return ssd_scan.ssd(x, dt, A, Bm, Cm, init_state=init_state,
-                            chunk=chunk, interpret=jax.default_backend() != "tpu")
+                            chunk=chunk, interpret=_interpret())
     return jnp_impl.ssd_chunked(x, dt, A, Bm, Cm, init_state=init_state, chunk=chunk)
 
 

@@ -9,21 +9,26 @@ memory once (O(tasks), not O(slots)).
 
 TPU mapping
 -----------
-Grid ``(B, Hq, nb)`` — one program per (slot, head) *walking that slot's
-block table*; the block axis is innermost and ``ARBITRARY`` (sequential)
-so the online-softmax state lives in VMEM scratch across the walk, exactly
-the flash-decode inner loop.
+Grid ``(B, nb)`` — one program per slot *walking that slot's block
+table*; the block axis is innermost and ``ARBITRARY`` (sequential) so the
+online-softmax state lives in VMEM scratch across the walk, exactly the
+flash-decode inner loop.  Each program serves every head of its slot, so
+a pool block is streamed once per slot, not once per (slot, head).
 
 The physical block to stream is data-dependent (``table[b, j]``), which a
 plain ``BlockSpec`` index map cannot express — block tables and per-slot
 lengths ride in as **scalar-prefetch** operands
 (``pltpu.PrefetchScalarGridSpec``), available to the index maps before the
 kernel body runs, so the pipeline DMAs pool block ``table[b, j]`` while
-program ``j-1`` computes:
+program ``j-1`` computes.  Every block's last two dims satisfy Mosaic's
+(8, 128) tiling rule without moving the pool:
 
-* q        (1, Sp, 1, D)   — the slot's last S query rows (padded to 8).
-* k/v pool (1, bs, 1, D)   — block ``table[b*nb + j]``, KV head ``h // G``
-  (GQA fold as in flash_attention).
+* q        (Hq*Sp, D)   — the slot's last S query rows padded to Sp (a
+  multiple of 8), head-major, so KV head ``g``'s query group is the
+  contiguous row range ``[g*G*Sp, (g+1)*G*Sp)`` (GQA fold).
+* k/v pool (bs, Hkv*D)  — pool block ``table[b*nb + j]`` viewed as rows
+  of all KV heads side by side (a free reshape of the pool); head ``g``
+  is the lane range ``[g*D, (g+1)*D)``.  ``bs`` must be a multiple of 8.
 * tables   (B*nb,) int32 SMEM — flattened so the index map stays 1-D.
 * lengths  (B,)    int32 SMEM — drives masking *and* the per-slot early
   skip: a block whose start position is at or past ``lengths[b]`` is
@@ -46,6 +51,7 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
+from repro.kernels.pltpu_compat import mxu_precision
 
 NEG_INF = -1e30
 
@@ -56,10 +62,11 @@ def _paged_kernel(
     o_ref,  # output
     acc, m_scr, l_scr,  # scratch
     *, scale: float, softcap: float, block_size: int, s_valid: int,
+    s_pad: int, kv_heads: int, group: int, head_dim: int, v_dim: int,
 ):
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
+    j = pl.program_id(1)
+    nb = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
@@ -72,42 +79,43 @@ def _paged_kernel(
 
     @pl.when(start < length)
     def _compute():
-        q = q_ref[0, :, 0, :]  # (Sp, D)
-        k = k_ref[0, :, 0, :]  # (bs, D)
-        v = v_ref[0, :, 0, :]  # (bs, D)
-        logits = jax.lax.dot_general(
-            q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if softcap:
-            logits = softcap * jnp.tanh(logits / softcap)
-        Sp = q.shape[0]
-        row = jax.lax.broadcasted_iota(jnp.int32, (Sp, block_size), 0)
+        rows = group * s_pad  # query rows of one KV head's group
+        # query row r holds token r % s_pad of its head, at cache position
+        # length - s_valid + r % s_pad; padded tokens are masked out
+        tok = jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 0) % s_pad
         pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (Sp, block_size), 1)
-        # query row r sits at cache position length - s_valid + r; padded
-        # rows (r >= s_valid) are masked out entirely
-        q_pos = length - s_valid + row
-        valid = (row < s_valid) & (pos <= q_pos)
-        logits = jnp.where(valid, logits, NEG_INF)
+            jnp.int32, (rows, block_size), 1)
+        valid = (tok < s_valid) & (pos <= length - s_valid + tok)
+        prec = mxu_precision(q_ref.dtype)
+        for g in range(kv_heads):
+            r = pl.ds(g * rows, rows)
+            q = q_ref[r, :]  # (rows, D)
+            k = k_ref[:, pl.ds(g * head_dim, head_dim)]  # (bs, D)
+            v = v_ref[:, pl.ds(g * v_dim, v_dim)]  # (bs, Dv)
+            logits = jax.lax.dot_general(
+                q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+                precision=prec, preferred_element_type=jnp.float32) * scale
+            if softcap:
+                logits = softcap * jnp.tanh(logits / softcap)
+            logits = jnp.where(valid, logits, NEG_INF)
 
-        m_prev = m_scr[...]  # (Sp, 1)
-        m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
-        p = jnp.exp(logits - m_new)
-        p = jnp.where(valid, p, 0.0)  # exp(NEG_INF - NEG_INF) = 1 guard
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-        m_scr[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc[...] = acc[...] * corr + pv
+            m_prev = m_scr[r, :]  # (rows, 1)
+            m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[r, :] = l_scr[r, :] * corr + p.sum(axis=-1, keepdims=True)
+            m_scr[r, :] = m_new
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                precision=prec,
+                preferred_element_type=jnp.float32)
+            acc[r, :] = acc[r, :] * corr + pv
 
     @pl.when(j == nb - 1)
     def _finish():
         l = l_scr[...]
         out = acc[...] / jnp.maximum(l, 1e-37)
-        out = jnp.where(l > 0, out, 0.0)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[...] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -125,50 +133,87 @@ def paged_flash_decode(
     ``block_tables[b, j]``.
     """
     B, S, Hq, D = q.shape
-    _, bs, Hkv, Dv = v_pool.shape
+    N, bs, Hkv, Dv = v_pool.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     G = Hq // Hkv
     nb = block_tables.shape[1]
     if scale is None:
         scale = D**-0.5
 
-    # pad query rows to the 8-sublane floor; padded rows are masked via
-    # the in-kernel row < s_valid test and sliced off below
-    Sp = max(S, 8)
+    # pad query rows to a multiple of the 8-sublane tile; padded rows are
+    # masked via the in-kernel token < s_valid test and sliced off below
+    Sp = -(-S // 8) * 8
     if Sp != S:
         q = jnp.pad(q, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
+    q_rows = q.transpose(0, 2, 1, 3).reshape(B, Hq * Sp, D)
+    k_rows = k_pool.reshape(N, bs, Hkv * D)
+    v_rows = v_pool.reshape(N, bs, Hkv * Dv)
 
     tables_flat = block_tables.astype(jnp.int32).reshape(-1)  # (B*nb,)
     lengths = lengths.astype(jnp.int32)
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, softcap=softcap, block_size=bs,
-        s_valid=S)
+        s_valid=S, s_pad=Sp, kv_heads=Hkv, group=G, head_dim=D, v_dim=Dv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hq, nb),
+        grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, Sp, 1, D), lambda b, h, j, tbl, lens: (b, 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, j, tbl, lens: (tbl[b * nb + j], 0, h // G, 0)),
-            pl.BlockSpec((1, bs, 1, Dv),
-                         lambda b, h, j, tbl, lens: (tbl[b * nb + j], 0, h // G, 0)),
+            pl.BlockSpec((None, Hq * Sp, D),
+                         lambda b, j, tbl, lens: (b, 0, 0)),
+            pl.BlockSpec((None, bs, Hkv * D),
+                         lambda b, j, tbl, lens: (tbl[b * nb + j], 0, 0)),
+            pl.BlockSpec((None, bs, Hkv * Dv),
+                         lambda b, j, tbl, lens: (tbl[b * nb + j], 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, Sp, 1, Dv), lambda b, h, j, tbl, lens: (b, 0, h, 0)),
+            (None, Hq * Sp, Dv), lambda b, j, tbl, lens: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Sp, Dv), jnp.float32),
-            pltpu.VMEM((Sp, 1), jnp.float32),
-            pltpu.VMEM((Sp, 1), jnp.float32),
+            pltpu.VMEM((Hq * Sp, Dv), jnp.float32),
+            pltpu.VMEM((Hq * Sp, 1), jnp.float32),
+            pltpu.VMEM((Hq * Sp, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sp, Hq, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq * Sp, Dv), q.dtype),
         compiler_params=_CompilerParams(
-            dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
-                                 pltpu.ARBITRARY)),
+            dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
-    )(tables_flat, lengths, q, k_pool, v_pool)
-    return out[:, :S]
+    )(tables_flat, lengths, q_rows, k_rows, v_rows)
+    return out.reshape(B, Hq, Sp, Dv).transpose(0, 2, 1, 3)[:, :S]
+
+
+def _dense_block_size(L: int, cap: int = 512):
+    """Rows per block when a dense ``L``-row cache stripe is read as pool
+    blocks: the largest multiple of 8 that divides ``L`` and is at most
+    ``cap``, or ``None`` when ``L`` is not a multiple of 8."""
+    if L % 8:
+        return None
+    return max(b for b in range(8, min(L, cap) + 1, 8) if L % b == 0)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("softcap", "scale", "interpret"),
+)
+def dense_flash_decode(q, k, v, *, lengths, softcap=0.0, scale=None,
+                       interpret=False):
+    """Decode over dense per-slot caches ``k``/``v`` (B, L, Hkv, D) with
+    the paged kernel.  Slot ``b``'s stripe is read, without a copy, as
+    ``L // bs`` consecutive pool blocks (a free reshape to
+    ``(B * nb, bs, Hkv, D)`` and identity block tables), so every head of
+    a slot is served from one pass over its rows — no head-major
+    transpose of the cache on each step.  ``L`` must be a multiple of 8
+    (:func:`_dense_block_size` picks the rows per block)."""
+    B, L = k.shape[:2]
+    bs = _dense_block_size(L)
+    assert bs is not None, f"cache length {L} is not a multiple of 8"
+    nb = L // bs
+    tables = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
+    return paged_flash_decode(
+        q, k.reshape(B * nb, bs, *k.shape[2:]),
+        v.reshape(B * nb, bs, *v.shape[2:]), block_tables=tables,
+        lengths=lengths, softcap=softcap, scale=scale, interpret=interpret)
+

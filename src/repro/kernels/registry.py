@@ -23,6 +23,8 @@ REFERENCE_TWINS = {
     "moe_gmm:gmm": "ref:gmm_ref",
     # paged decode attention <-> streaming jnp block-table walk
     "paged_attention:paged_flash_decode": "jnp_impl:paged_decode_attention_lengths",
+    # dense-stripe decode (the paged kernel over a reshaped cache)
+    "paged_attention:dense_flash_decode": "jnp_impl:decode_attention_lengths",
     # mamba2 state-space chunked scan
     "ssd_scan:ssd": "ref:ssd_ref",
 }

@@ -17,27 +17,25 @@ import numpy as np
 from jax.sharding import Mesh
 
 
-def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType`` itself) only exist on newer releases."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding rules and
+    ``with_sharding_constraint`` calls in this repo leave propagation to
+    GSPMD, which ``jax.make_mesh``'s default ``Explicit`` axes refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1, data: int | None = None):
     """Small mesh over whatever devices exist (tests / local runs)."""
     n = len(jax.devices())
     data = data or (n // model)
-    return _make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_serving_mesh(model: int = 1, data: int = 1):

@@ -64,10 +64,19 @@ output is token-identical to the non-speculative engine).
 tensor-parallel: target params placed from their logical axes, KV
 caches/pools split by head over the mesh "model" axis, block tables and
 per-slot lengths replicated (see docs/ARCHITECTURE.md §"Sharded
-serving").  On a CPU host with too few devices the launcher forces
+serving").  Target and compressor are created on their shards.  With
+``JAX_PLATFORMS=cpu`` set and too few devices the launcher forces
 ``--xla_force_host_platform_device_count`` *before the first jax
-import* — so ``--mesh 2`` works on single-CPU CI out of the box;
-``--rules {baseline,fsdp}`` picks the weight-sharding rule set.
+import* — so ``--mesh 2`` works on single-CPU CI out of the box; on any
+other platform the devices must exist, and a missing accelerator is an
+error rather than a CPU run.  ``--rules {baseline,fsdp}`` picks the
+weight-sharding rule set.
+
+Without ``--smoke`` the model keeps its published widths and vocabulary
+(the synthetic traffic draws its ids from the low end of it); ``--smoke``
+shrinks both to the CPU-sized config and the 388-id synthetic vocabulary.
+The persistent compilation cache lives in ``$JAX_COMPILATION_CACHE_DIR``
+or ``<checkout>/.jax_cache/`` (:mod:`repro.launch.compile_cache`).
 
 On a fleet the same entry point runs with the production mesh and
 sharded weights (launch/steps.py `compress` + `decode` objectives are
@@ -95,11 +104,14 @@ def _parse_mesh(spec: str):
 
 
 def _mesh_device_fallback() -> None:
-    """``--mesh N`` needs N devices, and the host-platform device count
-    locks at the first jax import — so peek at argv *before* any jax
-    import and force the placeholder topology when the operator has not
-    set XLA_FLAGS themselves.  Inert on real TPU backends (the flag only
-    affects the host platform)."""
+    """``--mesh N`` on the CPU needs N host devices, and the host-platform
+    device count locks at the first jax import — so peek at argv *before*
+    any jax import and force the placeholder topology when the operator
+    has not set XLA_FLAGS themselves.  Only with ``JAX_PLATFORMS=cpu``
+    set explicitly: elsewhere a failed accelerator start must not turn
+    into a run over fake CPU devices."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return
     spec = None
     for i, arg in enumerate(sys.argv):
         if arg.startswith("--mesh="):
@@ -134,12 +146,14 @@ from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import memcom
 from repro.data import (ICLTaskSpec, SyntheticVocab, build_manyshot_prompt,
                         make_episode, make_query)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
 from repro.serving import Request, ServingEngine, materialize_prefix
 from repro.utils.pytree import tree_bytes
 
 
-def main():
+def main(argv=None):
+    """Run the launcher on ``argv`` (default: the command line)."""
     # no prefix abbreviations: the pre-jax-import device-count fallback
     # scans argv for the literal --mesh, so an abbreviated --mes must be
     # rejected here rather than silently skip the forced topology
@@ -241,8 +255,8 @@ def main():
                          "step and slot (0 = speculative decoding off)")
     ap.add_argument("--mesh", default=None,
                     help="serve tensor-parallel: M (model-parallel ways) or "
-                         "DxM (data x model); forces the host device count "
-                         "on CPU so it runs anywhere")
+                         "DxM (data x model); with JAX_PLATFORMS=cpu forces "
+                         "the host device count so it runs anywhere")
     ap.add_argument("--rules", choices=("baseline", "fsdp"),
                     default="baseline",
                     help="weight-sharding rule set for --mesh (baseline: "
@@ -271,7 +285,7 @@ def main():
                     help="bound the tracer's ring buffer to the last N "
                          "events (the flight recorder: dumped to "
                          "--trace-out on a crash); default keeps all")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.tasks < 1 or args.slots < 1 or args.requests < 1:
         ap.error("--tasks, --slots and --requests must all be >= 1")
     if args.block_size < 1:
@@ -310,24 +324,19 @@ def main():
     if args.http_linger and args.http_port is None:
         ap.error("--http-linger needs --http-port")
 
+    enable_compile_cache()
     vocab = SyntheticVocab()
-    cfg = (get_smoke_config(args.arch) if args.smoke
-           else get_config(args.arch)).replace(vocab_size=vocab.size)
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    if args.smoke:
+        cfg = cfg.replace(vocab_size=vocab.size)
+    if vocab.size > cfg.vocab_size:
+        raise SystemExit(f"{cfg.name}: vocabulary of {cfg.vocab_size} ids "
+                         f"cannot hold the {vocab.size} synthetic ids")
     if cfg.memcom is None:
         raise SystemExit(f"{args.arch}: attention-free — serve with the "
-                         "native SSM state snapshot (see DESIGN.md §4)")
+                         "native SSM state snapshot")
     m = cfg.memcom.num_memory_tokens
 
-    print(f"[cloud] target {cfg.name} ({cfg.param_count()/1e6:.1f}M), "
-          f"m={m} memory tokens, {args.tasks} task(s)")
-    target = tfm.init_params(cfg, 0)
-    compressor = memcom.init_memcom(cfg, target, 1)
-
-    rng = np.random.default_rng(0)
-    paged_kw = {}
-    if args.kv_layout == "paged":
-        paged_kw = dict(block_size=args.block_size,
-                        num_blocks=args.num_blocks)
     mesh = rules = None
     if args.mesh:
         from repro.launch.mesh import make_serving_mesh
@@ -338,6 +347,16 @@ def main():
         rules = {"baseline": BASELINE_RULES, "fsdp": FSDP_RULES}[args.rules]
         print(f"[edge] tensor-parallel mesh {data}x{model} "
               f"(data x model), rules={args.rules}")
+
+    print(f"[cloud] target {cfg.name} ({cfg.param_count()/1e6:.1f}M), "
+          f"m={m} memory tokens, {args.tasks} task(s)")
+    target, compressor = memcom.init_models(cfg, mesh, rules)
+
+    rng = np.random.default_rng(0)
+    paged_kw = {}
+    if args.kv_layout == "paged":
+        paged_kw = dict(block_size=args.block_size,
+                        num_blocks=args.num_blocks)
     spec_draft = None
     if args.spec_k:
         if args.spec_draft == "self":
@@ -346,7 +365,7 @@ def main():
         else:
             dcfg = (get_smoke_config(args.spec_draft) if args.smoke
                     else get_config(args.spec_draft)).replace(
-                        vocab_size=vocab.size)
+                        vocab_size=cfg.vocab_size)
             spec_draft = (dcfg, tfm.init_params(dcfg, 1))
             print(f"[edge] speculative decoding: drafter {dcfg.name} "
                   f"({dcfg.param_count()/1e6:.1f}M), k={args.spec_k}")
@@ -435,7 +454,7 @@ def main():
                                        budget=args.context_tokens)
         if not args.raw_shots:  # stage 1: compress offline, register
             prefix, _ = memcom.compress(compressor, cfg,
-                                        jnp.asarray(prompt[None]))
+                                        jnp.asarray(prompt[None]), mesh=mesh)
             kv = materialize_prefix(target, cfg, prefix)
             engine.add_prefix(f"task{t}", kv)
             payload += tree_bytes(kv)

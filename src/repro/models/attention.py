@@ -123,8 +123,8 @@ def apply_attention(
     impl: str = "auto",
 ):
     """Returns (out (B,S,D), new_cache_or_None).  ``mesh`` (tensor-parallel
-    serving) reaches the decode kernels, which split Q/K/V by head over
-    its "model" axis while per-slot lengths and block tables stay
+    serving) reaches the kernels, which split Q/K/V by head over its
+    "model" axis while positions, per-slot lengths and block tables stay
     replicated — see :mod:`repro.sharding.serving`.
 
     ``lane_valid`` (B,) int32 (fused serving step, per-slot decode only)
@@ -162,7 +162,7 @@ def apply_attention(
         kv_pos = jnp.zeros((B, F), jnp.int32)
         out = ops.attention(q, k.astype(q.dtype), v.astype(q.dtype), q_pos=q_pos,
                             kv_pos=kv_pos, causal=False, softcap=softcap,
-                            scale=scale, impl=impl)
+                            scale=scale, impl=impl, mesh=mesh)
         return out.reshape(B, S, -1) @ p["wo"], cache
 
     q = project_q(p, cfg, x, positions)
@@ -209,7 +209,7 @@ def apply_attention(
         q_pos = jnp.broadcast_to(q_pos, (B, S))
         out = ops.attention(q, k_cache.astype(q.dtype), v_cache.astype(q.dtype),
                             q_pos=q_pos, kv_pos=kv_pos, causal=True,
-                            softcap=softcap, scale=scale, impl=impl)
+                            softcap=softcap, scale=scale, impl=impl, mesh=mesh)
         return out.reshape(B, S, -1) @ p["wo"], {"k": k_cache, "v": v_cache}
 
     # ---------------- train / prefill: full self-attention ----------------
@@ -247,10 +247,11 @@ def apply_attention(
         out = ops.attention_with_prefix(
             q, k, v, k_pre.astype(q.dtype), v_pre.astype(q.dtype),
             offset=mask_offset if mask_offset else m,
-            softcap=softcap, scale=scale, impl=impl)
+            softcap=softcap, scale=scale, impl=impl, mesh=mesh)
     else:
         out = ops.self_attention_causal(q, k, v, offset=mask_offset,
-                                        softcap=softcap, scale=scale, impl=impl)
+                                        softcap=softcap, scale=scale, impl=impl,
+                                        mesh=mesh)
     new_cache = None
     if cache is not None:  # prefill writes the cache
         start = cache_index if cache_index is not None else 0

@@ -126,7 +126,7 @@ def apply_block(
             cross_cache = {"ck": cache["ck"], "cv": cache["cv"]}
         o, c = apply_attention(p["xattn_enc"], cfg, hx, positions=positions,
                                kv_source=encoder_out, cache=cross_cache,
-                               impl=impl)
+                               mesh=mesh, impl=impl)
         if c is not None:
             new_cache.update(c)
         h = h + o
@@ -134,7 +134,7 @@ def apply_block(
     # ---- MemCom compression cross-attention (Memory-LLM only) ----
     if memcom is not None:
         h = h + apply_memcom_xattn(memcom["params"]["memx"], cfg, h,
-                                   memcom["src"], impl=impl)
+                                   memcom["src"], impl=impl, mesh=mesh)
         aux["omega"] = h  # O^i — the layer's compressed representation
 
     # ---- channel MLP ----

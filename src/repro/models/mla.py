@@ -164,7 +164,7 @@ def apply_mla(
             q_pos = jnp.broadcast_to(cache_index + jnp.arange(S, dtype=jnp.int32), (B, S))
             o_lat = ops.attention(q_eff, k_eff.astype(q_eff.dtype), v_eff.astype(q_eff.dtype),
                                   q_pos=q_pos, kv_pos=kv_pos, causal=True,
-                                  scale=scale, impl=impl)  # (B,S,nh,R)
+                                  scale=scale, impl=impl, mesh=mesh)  # (B,S,nh,R)
         wuv = wukv[:, :, m.qk_nope_head_dim :]
         out = jnp.einsum("bshr,rhd->bshd", o_lat, wuv)
         return out.reshape(B, S, -1) @ p["wo"], {"ckv": ckv_cache, "kr": kr_cache}
@@ -205,10 +205,11 @@ def apply_mla(
             axis=-1)
         out = ops.attention_with_prefix(
             q, k, v, k_pre.astype(q.dtype), v_pre.astype(q.dtype),
-            offset=mask_offset if mask_offset else mlen, scale=scale, impl=impl)
+            offset=mask_offset if mask_offset else mlen, scale=scale, impl=impl,
+            mesh=mesh)
     else:
         out = ops.self_attention_causal(q, k, v, offset=mask_offset,
-                                        scale=scale, impl=impl)
+                                        scale=scale, impl=impl, mesh=mesh)
     new_cache = None
     if cache is not None:
         start = cache_index if cache_index is not None else 0

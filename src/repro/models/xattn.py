@@ -39,9 +39,11 @@ def init_memcom_xattn(b: ParamBuilder, cfg: ModelConfig) -> None:
         xb.make("wo", (d, d), ("heads", "embed"), scale=0.1)
 
 
-def apply_memcom_xattn(p, cfg: ModelConfig, mem_h, src_h, *, impl: str = "auto"):
+def apply_memcom_xattn(p, cfg: ModelConfig, mem_h, src_h, *, impl: str = "auto",
+                       mesh=None):
     """mem_h: (B, m, D) memory residual; src_h: (B, T, D) source layer reps.
-    Returns the cross-attention output (B, m, D) to be residually added."""
+    Returns the cross-attention output (B, m, D) to be residually added.
+    ``mesh`` reaches the kernels (Pallas runs per device on a mesh)."""
     mc = cfg.memcom
     q_in = apply_norm(p["norm"], cfg, mem_h)
     B, M, D = q_in.shape
@@ -51,7 +53,7 @@ def apply_memcom_xattn(p, cfg: ModelConfig, mem_h, src_h, *, impl: str = "auto")
         q = q_in @ p["wq"]
         k = src_h @ p["wk"]
         v = src_h @ p["wv"]
-        o = ops.memcom_xattn(q, k, v, impl=impl)
+        o = ops.memcom_xattn(q, k, v, impl=impl, mesh=mesh)
         return o @ p["wo"]
 
     H = mc.xattn_heads
@@ -62,5 +64,6 @@ def apply_memcom_xattn(p, cfg: ModelConfig, mem_h, src_h, *, impl: str = "auto")
     v = (src_h @ p["wv"]).reshape(B, T, kv_heads, hd)
     q_pos = jnp.zeros((B, M), jnp.int32)
     kv_pos = jnp.zeros((B, T), jnp.int32)
-    o = ops.attention(q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=False, impl=impl)
+    o = ops.attention(q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=False,
+                      impl=impl, mesh=mesh)
     return o.reshape(B, M, D) @ p["wo"]

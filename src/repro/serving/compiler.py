@@ -219,12 +219,12 @@ class PrefixCompiler:
         engine's *fused* serving step can inline a compile chunk into
         the same program as the batched decode — one dispatch instead of
         a decode gap (see ``ServingEngine(fused_step=True)``)."""
-        cfg, impl = self.cfg, self.impl
+        cfg, impl, mesh = self.cfg, self.impl, self.mesh
 
         def run(compressor, cache, tokens):
             state = memcom.CompressionState(cache=cache, offset=offset)
             state = memcom.compress_chunk(compressor, cfg, state, tokens,
-                                          impl=impl)
+                                          impl=impl, mesh=mesh)
             return state.cache, state.hiddens[0]
 
         return run
@@ -254,7 +254,7 @@ class PrefixCompiler:
                 state = memcom.CompressionState(
                     cache=cache, offset=total, hiddens=list(hiddens))
                 prefix, _ = memcom.finish_compress(compressor, cfg, state,
-                                                   impl=impl)
+                                                   impl=impl, mesh=mesh)
                 out = materialize_prefix(target_params, cfg, prefix)
                 if mesh is not None:
                     out = constrain_cache(out, mesh,

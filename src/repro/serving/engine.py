@@ -90,6 +90,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ModelConfig
+from repro.core import memcom
 from repro.models import transformer as tfm
 from repro.sharding import rules as sharding_rules
 from repro.sharding.serving import constrain_cache, shard_cache
@@ -285,11 +286,11 @@ class ServingEngine:
         self.max_len = max_len
         self.impl = impl
         self.kv_layout = kv_layout
-        # tensor-parallel serving: params placed via their logical-axis
-        # tree, KV caches/pools split by head over the mesh "model" axis,
-        # block tables and per-slot lengths replicated host-side — the
-        # python control plane (scheduler, allocator, stores) is
-        # mesh-oblivious by construction
+        # tensor-parallel serving: target and compressor params placed via
+        # their logical-axis trees, KV caches/pools split by head over the
+        # mesh "model" axis, block tables and per-slot lengths replicated
+        # host-side — the python control plane (scheduler, allocator,
+        # stores) is mesh-oblivious by construction
         self.mesh = mesh
         self.rules = None
         if mesh is not None:
@@ -299,6 +300,12 @@ class ServingEngine:
                 target_params,
                 sharding_rules.logical_to_shardings(
                     target_params, tfm.param_specs(cfg), mesh, self.rules))
+            if compressor is not None:
+                compressor = jax.device_put(
+                    compressor,
+                    sharding_rules.logical_to_shardings(
+                        compressor, memcom.memcom_axes(cfg), mesh,
+                        self.rules))
         elif rules is not None:
             raise ValueError("rules given without a mesh")
         self.params = target_params

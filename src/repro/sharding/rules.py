@@ -114,6 +114,16 @@ def logical_to_shardings(abstract_params, axes_tree, mesh: Mesh, rules: Rules):
     return jax.tree.unflatten(treedef, out)
 
 
+def init_sharded(init_fn, axes_tree, mesh: Mesh, rules: Rules, *args):
+    """Run ``init_fn(*args)`` under jit with every output leaf placed from
+    its logical axes: parameters are created on their shards and never
+    gathered on one device first (a model too large for one chip can
+    only be built this way)."""
+    abstract = jax.eval_shape(init_fn, *args)
+    shardings = logical_to_shardings(abstract, axes_tree, mesh, rules)
+    return jax.jit(init_fn, out_shardings=shardings)(*args)
+
+
 def batch_sharding(mesh: Mesh, ndim: int = 2, batch_dim: int = 0):
     """Shard the batch dim over (pod, data); replicate the rest."""
     entries = [None] * ndim

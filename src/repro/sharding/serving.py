@@ -38,7 +38,7 @@ from repro.sharding.rules import BASELINE_RULES, Rules, spec_for
 __all__ = [
     "BASELINE_RULES", "cache_shardings", "constrain_cache",
     "constrain_heads", "leaf_sharding", "leaf_spec", "model_axis_size",
-    "shard_cache", "shard_map_heads",
+    "shard_cache", "shard_map_heads", "shard_map_replicated",
 ]
 
 #: trailing logical dims per cache/prefix leaf key; leading dims (layer
@@ -142,33 +142,23 @@ def constrain_heads(x, mesh: Optional[Mesh], axis: int = 2):
 
 
 def _shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map across jax versions (experimental → jax.shard_map)."""
-    try:
-        from jax.experimental.shard_map import shard_map
-
-        # pallas_call has no replication rule — checking is pointless here
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except ImportError:
-        try:  # newer jax renamed the replication-check opt-out
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
+    # pallas_call has no replication rule — checking is pointless here
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def shard_map_heads(f, mesh: Mesh, head_args, replicated_args: int,
-                    head_axis: int = 2):
+                    head_axis: int = 2, out_ndims=4):
     """Wrap a head-parallel kernel in shard_map: the first ``head_args``
     operands split their ``head_axis`` over "model" (batch, positions and
     block structure replicated), the remaining ``replicated_args``
-    operands (lengths, block tables) are replicated on every shard, and
-    the output is head-split like the inputs.
+    operands (positions, lengths, block tables) are replicated on every
+    shard, and the outputs — ``out_ndims`` gives each one's rank, an int
+    for a single output or a tuple — are head-split like the inputs.
 
-    This is what makes the *pallas* decode kernels mesh-runnable: unlike
-    jnp ops they have no GSPMD partitioning rule, so each shard must run
-    the kernel on its own head slice explicitly.
+    This is what makes the *pallas* kernels mesh-runnable: unlike jnp ops
+    they have no GSPMD partitioning rule, so each shard must run the
+    kernel on its own head slice explicitly.
     """
     def head_spec(ndim):
         entries = [None] * ndim
@@ -178,8 +168,20 @@ def shard_map_heads(f, mesh: Mesh, head_args, replicated_args: int,
     def wrapped(*args):
         assert len(args) == head_args + replicated_args
         in_specs = tuple(head_spec(a.ndim) for a in args[:head_args]) + \
-            tuple(P(*([None] * a.ndim)) for a in args[head_args:])
-        out_specs = head_spec(4)  # attention output: (B, S, Hq, Dv)
+            tuple(P() for _ in args[head_args:])
+        out_specs = (head_spec(out_ndims) if isinstance(out_ndims, int)
+                     else tuple(head_spec(n) for n in out_ndims))
         return _shard_map(f, mesh, in_specs, out_specs)(*args)
+
+    return wrapped
+
+
+def shard_map_replicated(f, mesh: Mesh):
+    """Run ``f`` whole on every device of ``mesh``, every operand and
+    output replicated: the mesh path for a Pallas kernel whose operands
+    cannot be split (a one-head cross-attention, heads that do not divide
+    the "model" axis)."""
+    def wrapped(*args):
+        return _shard_map(f, mesh, tuple(P() for _ in args), P())(*args)
 
     return wrapped

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.sharding import ctx
 
 
@@ -21,7 +22,7 @@ def test_moe_plan_noop_without_context(rng):
 
 
 def test_moe_plan_disabled_switch():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = NamedSharding(mesh, P("data", "model", None))
     x = jnp.zeros((4, 4, 8))
     with ctx.act_sharding(sh):
@@ -34,7 +35,7 @@ def test_moe_plan_disabled_switch():
 
 
 def test_act_sharding_context_restores():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = NamedSharding(mesh, P("data", None, None))
     x = jnp.zeros((2, 4, 8))
     with ctx.act_sharding(sh):
@@ -48,7 +49,7 @@ def test_act_sharding_context_restores():
 
 
 def test_constrain_skips_mismatched_rank():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = NamedSharding(mesh, P("data", None, None))
     with ctx.act_sharding(sh):
         x2d = jnp.zeros((2, 4))

@@ -3,9 +3,11 @@ sharded lower/compile on an 8-device placeholder topology (subprocess,
 so the main test process keeps its single real device)."""
 
 import json
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -18,6 +20,8 @@ from repro.data import (
 )
 from repro.data.pipeline import host_slice
 from repro.sharding.rules import BASELINE_RULES, FSDP_RULES, spec_for
+
+REPO_ROOT = Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------------------
@@ -136,11 +140,12 @@ MINI_DRYRUN = textwrap.dedent("""
                                     param_shardings, _with_shardings,
                                     act_sharding_for, opt_shardings)
     from repro.core import memcom
+    from repro.launch.mesh import make_mesh
     from repro.optim import AdamW
     from repro.sharding.ctx import act_sharding
     import jax.numpy as jnp
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_smoke_config("smollm-135m").replace(
         d_model=128, num_heads=4, num_kv_heads=2, d_ff=256)
     step, _ = build_memcom_train_step(cfg, phase=1)
@@ -161,8 +166,6 @@ MINI_DRYRUN = textwrap.dedent("""
     with act_sharding(act_sharding_for(mesh, cfg, 8, 32)):
         compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returned [dict]
-        ca = ca[0] if ca else {}
     print(json.dumps({"ok": True, "flops": float(ca.get("flops", -1))}))
 """)
 
@@ -175,8 +178,8 @@ def test_sharded_memcom_train_compiles_8dev(tmp_path):
     script.write_text(MINI_DRYRUN)
     res = subprocess.run(
         [sys.executable, str(script)], capture_output=True, text=True,
-        timeout=900, env={**__import__("os").environ, "PYTHONPATH": "src"},
-        cwd="/root/repo")
+        timeout=900, env={**os.environ, "PYTHONPATH": "src"},
+        cwd=REPO_ROOT)
     assert res.returncode == 0, res.stderr[-3000:]
     out = json.loads(res.stdout.strip().splitlines()[-1])
     assert out["ok"] and out["flops"] != 0

@@ -34,6 +34,12 @@ ATTN_CASES = [
     (1, 37, 53, 4, 2, 64, False, 0.0),    # cross, ragged shapes
     (2, 1, 80, 4, 2, 64, True, 0.0),      # decode row
     (1, 200, 100, 2, 2, 128, True, 0.0),  # Sq > Skv
+    # head-major tiles: GQA groups of 3 (smollm-360m's 15/5 heads), B > 1
+    # with S = 1 (padded to one 8-row tile), Sq off the 8-row tile and
+    # spanning two q blocks
+    (3, 1, 100, 15, 5, 64, True, 0.0),
+    (2, 13, 40, 6, 2, 32, True, 0.0),
+    (1, 45, 45, 6, 2, 32, True, 50.0),
 ]
 
 
@@ -134,16 +140,18 @@ def test_lse_merge_matches_monolithic(rng):
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("L", [53, 56])
 @pytest.mark.parametrize("S", [1, 3])
 @pytest.mark.parametrize("impl", ["dense", "jnp", "pallas"])
-def test_decode_attention_per_slot_lengths(rng, impl, S):
+def test_decode_attention_per_slot_lengths(rng, impl, S, L):
     """Continuous-batching decode: slot b sees exactly cache[:lengths[b]],
-    whatever the other slots' lengths, on every backend."""
-    B, L, Hq, Hkv, D = 4, 53, 6, 2, 32
+    whatever the other slots' lengths, on every backend.  ``L`` = 56 runs
+    the Pallas path over the stripe as paged blocks, 53 the flash kernel."""
+    B, Hq, Hkv, D = 4, 6, 2, 32
     q = _rand(rng, B, S, Hq, D)
     k = _rand(rng, B, L, Hkv, D)
     v = _rand(rng, B, L, Hkv, D)
-    lengths = jnp.asarray([S, 17, 40, 53], jnp.int32)  # ragged, incl. edges
+    lengths = jnp.asarray([S, 17, 40, L], jnp.int32)  # ragged, incl. edges
     slot = jnp.arange(L, dtype=jnp.int32)
     kv_pos = jnp.where(slot[None] < lengths[:, None], slot[None], -1)
     q_pos = lengths[:, None] - S + jnp.arange(S, dtype=jnp.int32)[None]
@@ -152,6 +160,18 @@ def test_decode_attention_per_slot_lengths(rng, impl, S):
                                kv_chunk=16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_auto_resolves_to_pallas_on_tpu(monkeypatch):
+    """On the TPU backend ``auto`` runs the Pallas kernel at every size;
+    the dense oracle stays the CPU small-shape path and an explicit
+    choice."""
+    assert ops._resolve("auto", small=True) == "dense"
+    assert ops._resolve("auto", small=False) == "jnp"
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops._resolve("auto", small=True) == "pallas"
+    assert ops._resolve("auto", small=False) == "pallas"
+    assert ops._resolve("dense", small=True) == "dense"
 
 
 def test_decode_attention_ignores_unseated_tail(rng):

@@ -87,6 +87,50 @@ def test_paged_decode_matches_dense(rng, impl, hq, hkv):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
 
 
+@pytest.mark.parametrize("S", [1, 3])
+@pytest.mark.parametrize("bs", [8, 16])
+def test_paged_pallas_gqa3_vs_ref(rng, S, bs):
+    """The paged kernel's row layout (heads side by side in one pool row,
+    query groups of G*Sp rows) against the oracle: GQA groups of 3 as in
+    smollm-360m's 15/5 heads, S query rows padded to 8, several slots."""
+    B, L, hq, hkv, D = 3, 48, 15, 5, 16
+    lengths = jnp.asarray([S, 21, 48], jnp.int32)
+    k = np.asarray(rng.standard_normal((B, L, hkv, D)), np.float32)
+    v = np.asarray(rng.standard_normal((B, L, hkv, D)), np.float32)
+    q = jnp.asarray(rng.standard_normal((B, S, hq, D)), jnp.float32)
+    pool_k, pool_v, tables = _paged_copy(k, v, bs, rng)
+    want = ops.paged_decode_attention(q, pool_k, pool_v, block_tables=tables,
+                                      lengths=lengths, impl="dense")
+    got = ops.paged_decode_attention(q, pool_k, pool_v, block_tables=tables,
+                                     lengths=lengths, impl="pallas")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("L,bs", [(8, 8), (48, 48), (576, 288), (1040, 208)])
+def test_dense_block_size(L, bs):
+    from repro.kernels.paged_attention import _dense_block_size
+
+    assert _dense_block_size(L) == bs
+    assert _dense_block_size(L + 3) is None
+
+
+@pytest.mark.parametrize("S", [1, 3])
+def test_dense_stripe_as_paged_blocks_vs_ref(rng, S):
+    """Dense-layout decode through the paged kernel: each slot's stripe
+    read as consecutive pool blocks (576 rows -> 2 blocks of 288), GQA
+    15/5, S query rows padded to 8, ragged lengths across block edges."""
+    B, L, hq, hkv, D = 3, 576, 15, 5, 16
+    lengths = jnp.asarray([S, 200, 576], jnp.int32)
+    k = jnp.asarray(rng.standard_normal((B, L, hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, L, hkv, D)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, S, hq, D)), jnp.float32)
+    want = ops.decode_attention(q, k, v, lengths=lengths, impl="dense")
+    got = ops.decode_attention(q, k, v, lengths=lengths, impl="pallas")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
 def test_paged_scatter_then_decode(rng):
     """paged_scatter lands tokens at per-slot positions: scattering into
     the pool equals writing the dense cache rows."""

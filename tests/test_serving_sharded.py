@@ -77,6 +77,34 @@ SCRIPT = textwrap.dedent("""
             q, pk, pv, tables, lengths)
         report[f"paged_jnp_{model}"] = float(jnp.abs(out - pref).max())
 
+    # ---- prefill / compression kernels on a mesh: Mosaic calls cannot be
+    # auto-partitioned, so they run per device (by head, or replicated) ----
+    Sq, Sk = 24, 40
+    qs = jnp.asarray(rng.standard_normal((2, Sq, Hq, D)), jnp.float32)
+    ks = jnp.asarray(rng.standard_normal((2, Sk, Hkv, D)), jnp.float32)
+    vs = jnp.asarray(rng.standard_normal((2, Sk, Hkv, D)), jnp.float32)
+    kp3 = jnp.asarray(rng.standard_normal((2, 16, Hkv, D)), jnp.float32)
+    xq = jnp.asarray(rng.standard_normal((1, 8, 32)), jnp.float32)
+    xk = jnp.asarray(rng.standard_normal((1, 40, 32)), jnp.float32)
+    want_causal = ops.self_attention_causal(qs, ks[:, :Sq], vs[:, :Sq],
+                                            impl="dense")
+    want_prefix = ops.attention_with_prefix(qs, ks[:, :Sq], vs[:, :Sq], kp3,
+                                            kp3, impl="jnp")
+    want_x = ops.memcom_xattn(xq, xk, xk, impl="dense")
+    for model in (2, 4):
+        mesh = make_serving_mesh(model=model)
+        for name, fn, want in (
+                ("causal", lambda: ops.self_attention_causal(
+                    qs, ks[:, :Sq], vs[:, :Sq], impl="pallas", mesh=mesh),
+                 want_causal),
+                ("prefix", lambda: ops.attention_with_prefix(
+                    qs, ks[:, :Sq], vs[:, :Sq], kp3, kp3, impl="pallas",
+                    mesh=mesh), want_prefix),
+                ("xattn", lambda: ops.memcom_xattn(
+                    xq, xk, xk, impl="pallas", mesh=mesh), want_x)):
+            out = jax.jit(fn)()
+            report[f"{name}_pallas_{model}"] = float(jnp.abs(out - want).max())
+
     # ---- engine parity: offline prefixes, dense + paged ----
     cfg = get_smoke_config("smollm-135m").replace(
         d_model=128, num_heads=8, num_kv_heads=4, d_ff=256)
