@@ -1,0 +1,9 @@
+"""CPU tests of the benchmark: they never touch an accelerator, and the
+program's package is imported from ``src/``."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+if str(ROOT / "src") not in sys.path:
+    sys.path.insert(0, str(ROOT / "src"))
