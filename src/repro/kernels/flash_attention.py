@@ -199,6 +199,7 @@ def flash_attention(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
+        name="flash_attention",
     )(q_pos_p, kv_pos_p, qp, kp, vp)
 
     out = out.transpose(0, 2, 1, 3)[:, :Sq]

@@ -146,5 +146,6 @@ def memcom_xattn(q, k, v, *, scale=None, block_m=None, block_t=None,
                                  pltpu.ARBITRARY),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name="memcom_xattn",
     )(qp, kp, vp)
     return out[:, :M]

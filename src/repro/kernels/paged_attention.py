@@ -181,6 +181,7 @@ def paged_flash_decode(
         compiler_params=_CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
+        name="paged_flash_decode",
     )(tables_flat, lengths, q_rows, k_rows, v_rows)
     return out.reshape(B, Hq, Sp, Dv).transpose(0, 2, 1, 3)[:, :S]
 

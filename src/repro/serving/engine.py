@@ -322,7 +322,6 @@ class ServingEngine:
             self.compiler.stats = self.metrics.group(
                 "serving_compiler", self.compiler.stats,
                 help="online prefix compiler counter")
-        self.trace: List[Tuple] = []  # per-serve event log (tests/bench)
         # the counter "dict" is a registry-backed MetricGroup: every
         # `self._counters[k] += 1` site lands in a `serving_engine_*`
         # gauge, stats() stays a view over the registry, and the
@@ -455,6 +454,9 @@ class ServingEngine:
                 logits, new_cache = step(params, cache, tok, lengths, *rest)
                 # argmax on device: ship (slots,) ids, not (slots, vocab)
                 return jnp.argmax(logits, -1).astype(jnp.int32), new_cache
+            # runs under the step's own name (jit_paged_decode_fn), which
+            # is how a profiler trace tells the decode program apart
+            fn.__name__ = fn.__qualname__ = step.__name__
             return fn
 
         # base is static: prefill-continuation slices the seated cache
@@ -818,14 +820,20 @@ class ServingEngine:
         Requests carrying ``arrival_s`` are held until the engine clock
         reaches that offset from serve() start — that is how the traffic
         harness replays a timed Poisson/ON-OFF trace.  Per-request
-        timings (arrival, first token, finish, preemption count) land in
-        ``self.request_log`` for the SLO metrics.
+        timings land in ``self.request_log`` for the SLO metrics, as
+        offsets on the engine clock from serve() start: ``arrival_s`` (the
+        due time), ``released_s`` (handed to the scheduler),
+        ``admitted_s`` (its first admission began), ``first_token_s`` and
+        ``finish_s``, in that order, plus the preemption count.
+
+        Each host phase of the loop runs under ``self.tracer.phase``, so
+        a profiler trace shows it as a ``serve.*`` annotation beside the
+        device's work; phases are siblings, never nested.
         """
         epoch = self.clock()  # request_log times are offsets from here
         sched = Scheduler(self.slots, clock=self.clock,
                           aging_interval_s=self.priority_aging_s,
                           metrics=self.metrics)
-        self.trace = []
         self.request_log = {}
         tr = self.tracer
         # trace ids are serve-local arrival ordinals, NOT Request.uid:
@@ -843,10 +851,12 @@ class ServingEngine:
 
         def _arrive(req: Request) -> None:
             rid = self._rids[req.uid] = len(self._rids)
+            now = self.clock() - epoch
             self.request_log[req.uid] = {
                 "priority": int(req.priority),
                 "arrival_s": float(req.arrival_s if req.arrival_s is not None
-                                   else self.clock() - epoch),
+                                   else now),
+                "released_s": now, "admitted_s": None,
                 "first_token_s": None, "finish_s": None,
                 "tokens": 0, "preemptions": 0,
             }
@@ -921,127 +931,136 @@ class ServingEngine:
             if wd is not None:
                 wd_steps0 = self._counters["decode_steps"]
                 wd_toks0 = self._counters["tokens_generated"]
-            # release timed arrivals whose moment has come
-            now_s = self.clock() - epoch
-            while future and future[0].arrival_s <= now_s:
-                _arrive(future.pop(0))
+            with tr.phase("release"):
+                # release timed arrivals whose moment has come
+                now_s = self.clock() - epoch
+                while future and future[0].arrival_s <= now_s:
+                    _arrive(future.pop(0))
             if not sched.has_work():
                 # idle until the next arrival: a virtual clock jumps
                 # there, a wall clock sleeps in short slices
                 self._advance_to(epoch + future[0].arrival_s)
                 continue
-            if self.compiler is not None:
-                self._drain_compiler(sched)
-            if self.tiers is not None:
-                self._drain_promoter(sched)
-            admitted = sched.admit(can_seat)
-            if paged and not admitted and not sched.active_slots() \
-                    and sched.pending:
-                # nothing running and the head request doesn't pass the
-                # free-block gate: reclaim every free slot's private
-                # blocks, then retry once — fail fast instead of spinning
-                self._reclaim_free_slots(sched)
+            with tr.phase("admit"):
+                if self.compiler is not None:
+                    self._drain_compiler(sched)
+                if self.tiers is not None:
+                    self._drain_promoter(sched)
                 admitted = sched.admit(can_seat)
-                if not admitted:
-                    raise OutOfBlocksError(
-                        f"paged KV pool ({self.alloc.num_blocks} blocks of "
-                        f"{self.block_size}) cannot hold the next request "
-                        "even with every free slot reclaimed — grow "
-                        "num_blocks or evict resident prefixes")
-            if self.preemption and sched.pending:
-                admitted += self._preempt_for_priority(
-                    sched, can_seat,
-                    protected={s for s, _ in admitted} | set(self._joining))
-            # fused chunked admission: while other slots are mid-decode, a
-            # new request "joins" — its prompt streams through the fused
-            # step in fused_chunk_tokens-sized chunk lanes instead of one
-            # monolithic prefill gap.  A slot that is itself mid-join counts
-            # as busy too: its chunks flow through fused steps, so a classic
-            # prefill here would land between them as a gap.  Only with
-            # nothing decoding *and* no join in flight does the classic
-            # per-slot prefill stall nobody and stay the fast path.
-            admitted_slots = {s for s, _ in admitted}
-            busy_decode = any(s not in admitted_slots
-                              and s not in self._joining
-                              for s in sched.active_slots())
+                if paged and not admitted and not sched.active_slots() \
+                        and sched.pending:
+                    # nothing running and the head request doesn't pass
+                    # the free-block gate: reclaim every free slot's
+                    # private blocks, then retry once — fail fast instead
+                    # of spinning
+                    self._reclaim_free_slots(sched)
+                    admitted = sched.admit(can_seat)
+                    if not admitted:
+                        raise OutOfBlocksError(
+                            f"paged KV pool ({self.alloc.num_blocks} blocks "
+                            f"of {self.block_size}) cannot hold the next "
+                            "request even with every free slot reclaimed — "
+                            "grow num_blocks or evict resident prefixes")
+                if self.preemption and sched.pending:
+                    admitted += self._preempt_for_priority(
+                        sched, can_seat,
+                        protected={s for s, _ in admitted}
+                        | set(self._joining))
+                # fused chunked admission: while other slots are
+                # mid-decode, a new request "joins" — its prompt streams
+                # through the fused step in fused_chunk_tokens-sized chunk
+                # lanes instead of one monolithic prefill gap.  A slot
+                # that is itself mid-join counts as busy too: its chunks
+                # flow through fused steps, so a classic prefill here
+                # would land between them as a gap.  Only with nothing
+                # decoding *and* no join in flight does the classic
+                # per-slot prefill stall nobody and stay the fast path.
+                admitted_slots = {s for s, _ in admitted}
+                busy_decode = any(s not in admitted_slots
+                                  and s not in self._joining
+                                  for s in sched.active_slots())
             for slot, req in admitted:
-                t_adm = self.clock() if tr.enabled else 0.0
-                if req.prefix is not None:
-                    # skip the re-seat when the slot provably still holds
-                    # this prefix (KV region [0, m) is never overwritten;
-                    # only recurrent state can have been advanced)
-                    if self._seated[slot] != req.prefix or self._recurrent:
-                        self.seat_prefix(slot, req.prefix)
-                else:
-                    self._reset_slot(slot)
-                # a preempted request resumes by re-prefilling everything
-                # it had already consumed *and emitted* behind the seated
-                # prefix — byte-for-byte the refill path, so the rebuilt
-                # KV state (and thus every later token) is exact
-                resumed = sched.emitted_tokens(slot)
-                toks = (np.concatenate([req.tokens, resumed])
-                        if resumed.size else req.tokens)
-                if paged:
-                    # the gate's pending reservation becomes this slot's:
-                    # prefill allocates its share now, the rest stays
-                    # reserved for the decode steps to draw down
-                    self._reserved_pending -= self._blocks_needed(
-                        req, self._req_base(req),
-                        extra=resumed.size)  # what the gate added
-                    base = int(self.base[slot])
-                    need = self._blocks_needed(req, base, extra=resumed.size)
-                if resumed.size:
-                    self._counters["preempted_tokens_refilled"] += \
-                        int(resumed.size)
-                    self.trace.append(("resume", req.uid, slot,
-                                       int(resumed.size)))
-                    if tr.enabled:
-                        tr.instant(f"slot{slot}", "resume",
-                                   rid=self._rids[req.uid],
-                                   tokens=int(resumed.size))
-                if self.fused and (busy_decode or self._joining):
-                    self._joining[slot] = {"req": req, "toks": toks,
-                                           "consumed": 0, "t0": t_adm}
-                    lengths[slot] = self.base[slot]
-                    if paged:
-                        # the whole window stays reserved; chunk prefills
-                        # and decode steps draw it down as they allocate
-                        self._reserved[slot] = need
-                    self.trace.append(("admit", req.uid, slot))
-                    self.trace.append(("join", req.uid, slot, len(toks)))
-                    continue
-                if paged:
-                    n = len(toks)
-                    width = (_bucket(n, self.max_len - base)
-                             if self._pad_prefill else n)
-                    covered = (self.alloc.blocks_for(base + width)
-                               - self.alloc.blocks_for(base)
-                               + (1 if base % self.block_size else 0))
-                    self._reserved[slot] = max(0, need - covered)
-                row_logits = self._prefill_slot(slot, toks)
-                lengths[slot] = self.base[slot] + len(toks)
-                if self.spec_k:
-                    self._draft_prefill(slot, toks)
-                tok = self._sample_row(row_logits, req.temperature,
-                                       _stream(req))
-                pending[slot] = tok
-                self.trace.append(("admit", req.uid, slot))
-                if tr.enabled:
-                    tr.span(f"slot{slot}", "admission", t_adm,
-                            rid=self._rids[req.uid], prefix=req.prefix,
-                            prompt_tokens=len(toks),
-                            resumed=int(resumed.size))
+                rid = self._rids[req.uid]
                 log = self.request_log[req.uid]
-                if log["first_token_s"] is None:
-                    log["first_token_s"] = self.clock() - epoch
-                    self._m_ttft.observe(
-                        log["first_token_s"] - log["arrival_s"],
-                        priority=log["priority"])
-                    if wd is not None:
-                        wd.observe("ttft",
-                                   log["first_token_s"] - log["arrival_s"])
-                if sched.record_token(slot, tok):
-                    _finish(slot)
+                with tr.phase("admit", rid=rid):
+                    t_adm = self.clock()
+                    if log["admitted_s"] is None:
+                        log["admitted_s"] = t_adm - epoch
+                    if req.prefix is not None:
+                        # skip the re-seat when the slot provably still
+                        # holds this prefix (KV region [0, m) is never
+                        # overwritten; only recurrent state can have been
+                        # advanced)
+                        if self._seated[slot] != req.prefix or \
+                                self._recurrent:
+                            self.seat_prefix(slot, req.prefix)
+                    else:
+                        self._reset_slot(slot)
+                    # a preempted request resumes by re-prefilling
+                    # everything it had already consumed *and emitted*
+                    # behind the seated prefix — byte-for-byte the refill
+                    # path, so the rebuilt KV state (and thus every later
+                    # token) is exact
+                    resumed = sched.emitted_tokens(slot)
+                    toks = (np.concatenate([req.tokens, resumed])
+                            if resumed.size else req.tokens)
+                    if paged:
+                        # the gate's pending reservation becomes this
+                        # slot's: prefill allocates its share now, the
+                        # rest stays reserved for the decode steps to draw
+                        # down
+                        self._reserved_pending -= self._blocks_needed(
+                            req, self._req_base(req),
+                            extra=resumed.size)  # what the gate added
+                        base = int(self.base[slot])
+                        need = self._blocks_needed(req, base,
+                                                   extra=resumed.size)
+                    if resumed.size:
+                        self._counters["preempted_tokens_refilled"] += \
+                            int(resumed.size)
+                        if tr.enabled:
+                            tr.instant(f"slot{slot}", "resume", rid=rid,
+                                       tokens=int(resumed.size))
+                    if self.fused and (busy_decode or self._joining):
+                        self._joining[slot] = {"req": req, "toks": toks,
+                                               "consumed": 0, "t0": t_adm}
+                        lengths[slot] = self.base[slot]
+                        if paged:
+                            # the whole window stays reserved; chunk
+                            # prefills and decode steps draw it down as
+                            # they allocate
+                            self._reserved[slot] = need
+                        continue
+                    if paged:
+                        n = len(toks)
+                        width = (_bucket(n, self.max_len - base)
+                                 if self._pad_prefill else n)
+                        covered = (self.alloc.blocks_for(base + width)
+                                   - self.alloc.blocks_for(base)
+                                   + (1 if base % self.block_size else 0))
+                        self._reserved[slot] = max(0, need - covered)
+                row_logits = self._prefill_slot(slot, toks)
+                with tr.phase("tokens", rid=rid):
+                    lengths[slot] = self.base[slot] + len(toks)
+                    if self.spec_k:
+                        self._draft_prefill(slot, toks)
+                    tok = self._sample_row(row_logits, req.temperature,
+                                           _stream(req))
+                    pending[slot] = tok
+                    if tr.enabled:
+                        tr.span(f"slot{slot}", "admission", t_adm, rid=rid,
+                                prefix=req.prefix, prompt_tokens=len(toks),
+                                resumed=int(resumed.size))
+                    if log["first_token_s"] is None:
+                        log["first_token_s"] = self.clock() - epoch
+                        self._m_ttft.observe(
+                            log["first_token_s"] - log["arrival_s"],
+                            priority=log["priority"])
+                        if wd is not None:
+                            wd.observe("ttft", log["first_token_s"]
+                                       - log["arrival_s"])
+                    if sched.record_token(slot, tok):
+                        _finish(slot)
             active = sched.active_slots()
             compiling = (self.compiler is not None
                          and self.compiler.has_compile_work())
@@ -1073,62 +1092,57 @@ class ServingEngine:
                                         or comp is not None)
             if not use_fused:
                 # ---- classic single-token decode step ----
-                greedy = all(sched.request_in(s).temperature <= 0
-                             for s in active)
-                self._note_geometry("decode", (bool(greedy),))
-                step = self._decode_greedy if greedy else self._decode
-                step_args = ()
-                if paged:
-                    # grow each active slot's table before its write crosses
-                    # into an unallocated block (idle slots write into their
-                    # own stale blocks or the trash block — both masked)
-                    self._ensure_decode_blocks(active, lengths)
-                    step_args = (jnp.asarray(self.tables),)
-                t_start = self.clock()
-                out, self.cache = step(
-                    self.params, self.cache, jnp.asarray(pending[:, None]),
-                    jnp.asarray(lengths, jnp.int32), *step_args)
-                self._charge("decode_step", 1)
-                # the batched step advances *every* slot's recurrent state
-                # (idle rows included), so all slots are dirty from here on
-                self._dirty[:] = True
-                out = np.asarray(out)  # greedy: (slots,) ids; else logits
-                self._counters["decode_time_s"] += self.clock() - t_start
-                if last_decode_done is not None:
-                    # decode gap = non-decode time since the previous step —
-                    # admissions, prefills, and (above all) compile chunks;
-                    # the online_compile bench reads the dip off these
-                    gap = t_start - last_decode_done
-                    c = self._counters
-                    c["decode_gap_max_s"] = max(c["decode_gap_max_s"], gap)
-                    c["decode_gap_sum_s"] += gap
-                    c["decode_gaps"] += 1
-                    self._gap_samples.append(gap)
-                    self._gap_window.append(gap)
-                    self._m_gap.observe(gap)
-                    if wd is not None:
-                        wd.observe("decode_gap", gap)
-                last_decode_done = self.last_step_t = self.clock()
-                if tr.enabled:
-                    tr.span("engine", "decode_step", t_start,
-                            last_decode_done, active=len(active))
-                self._counters["decode_steps"] += 1
-                if compiling:
-                    self._counters["decode_steps_during_compile"] += 1
-                if promoting:
-                    self._counters["decode_steps_during_promote"] += 1
-                self.trace.append(("decode", len(active)))
-                for slot in active:
-                    lengths[slot] += 1  # the step consumed this slot's token
-                    req = sched.request_in(slot)
-                    tok = int(out[slot]) if greedy else self._sample_row(
-                        out[slot], req.temperature, _stream(req))
-                    pending[slot] = tok
-                    self._counters["tokens_generated"] += 1
-                    if self.spec_k:
-                        self._draft_len[slot] += 1
-                    if sched.record_token(slot, tok):
-                        _finish(slot)
+                with tr.phase("decode.dispatch"):
+                    greedy = all(sched.request_in(s).temperature <= 0
+                                 for s in active)
+                    self._note_geometry("decode", (bool(greedy),))
+                    step = self._decode_greedy if greedy else self._decode
+                    step_args = ()
+                    if paged:
+                        # grow each active slot's table before its write
+                        # crosses into an unallocated block (idle slots
+                        # write into their own stale blocks or the trash
+                        # block — both masked)
+                        self._ensure_decode_blocks(active, lengths)
+                        step_args = (jnp.asarray(self.tables),)
+                    t_start = self.clock()
+                    out, self.cache = step(
+                        self.params, self.cache, jnp.asarray(pending[:, None]),
+                        jnp.asarray(lengths, jnp.int32), *step_args)
+                    self._charge("decode_step", 1)
+                    # the batched step advances *every* slot's recurrent
+                    # state (idle rows included), so all slots are dirty
+                    self._dirty[:] = True
+                with tr.phase("decode.fetch"):
+                    out = np.asarray(out)  # greedy: (slots,) ids; else logits
+                with tr.phase("tokens"):
+                    self._counters["decode_time_s"] += self.clock() - t_start
+                    if last_decode_done is not None:
+                        # decode gap = non-decode time since the previous
+                        # step — admissions, prefills, and (above all)
+                        # compile chunks; the online_compile bench reads
+                        # the dip off these
+                        self._note_gap(t_start - last_decode_done)
+                    last_decode_done = self.last_step_t = self.clock()
+                    if tr.enabled:
+                        tr.span("engine", "decode_step", t_start,
+                                last_decode_done, active=len(active))
+                    self._counters["decode_steps"] += 1
+                    if compiling:
+                        self._counters["decode_steps_during_compile"] += 1
+                    if promoting:
+                        self._counters["decode_steps_during_promote"] += 1
+                    for slot in active:
+                        lengths[slot] += 1  # the step consumed its token
+                        req = sched.request_in(slot)
+                        tok = int(out[slot]) if greedy else self._sample_row(
+                            out[slot], req.temperature, _stream(req))
+                        pending[slot] = tok
+                        self._counters["tokens_generated"] += 1
+                        if self.spec_k:
+                            self._draft_len[slot] += 1
+                        if sched.record_token(slot, tok):
+                            _finish(slot)
                 if compiling:
                     # interleave: at most compile_token_budget source tokens
                     # of compilation behind this decode step, then decode
@@ -1144,203 +1158,203 @@ class ServingEngine:
                 # everything below up to the post-step bookkeeping happens
                 # inside the decode-step timing window, so admission/compile
                 # churn never widens the measured decode gap
-                t_start = self.clock()
-                drafts = None
-                k_eff = np.zeros((self.slots,), np.int64)
-                if spec:
+                with tr.phase("fused.dispatch"):
+                    t_start = self.clock()
+                    drafts = None
+                    k_eff = np.zeros((self.slots,), np.int64)
+                    if spec:
+                        for s in decode_lanes:
+                            req = sched.request_in(s)
+                            left = req.max_new - len(sched.emitted_tokens(s))
+                            k_eff[s] = max(0, min(
+                                self.spec_k, left - 1,
+                                self.max_len - int(lengths[s]) - 1))
+                        drafts, self._draft_cache = self._draft_prog(
+                            self.spec_k)(
+                            self._draft_params, self._draft_cache,
+                            jnp.asarray(pending),
+                            jnp.asarray(self._draft_len, jnp.int32))
+                        drafts = np.asarray(drafts)
+                        self._charge("draft_step", self.spec_k + 1)
+                        self._counters["spec_rounds"] += 1
+                    chunk_n, jn = 0, None
+                    if chunk_slot is not None:
+                        jn = self._joining[chunk_slot]
+                        chunk_n = min(len(jn["toks"]) - jn["consumed"],
+                                      self.fused_chunk_tokens)
+                    lanes = 1 + (self.spec_k if spec else 0)
+                    W = pow2_bucket(max(lanes, chunk_n), 1)
+                    tokens_in = np.zeros((self.slots, W), np.int32)
+                    valids = np.zeros((self.slots,), np.int32)
+                    for s in decode_lanes:
+                        tokens_in[s, 0] = pending[s]
+                        kk = int(k_eff[s])
+                        if kk:
+                            tokens_in[s, 1:1 + kk] = drafts[s, :kk]
+                        valids[s] = 1 + kk
+                    completing = False
+                    if chunk_slot is not None:
+                        c0 = jn["consumed"]
+                        tokens_in[chunk_slot, :chunk_n] = \
+                            jn["toks"][c0:c0 + chunk_n]
+                        valids[chunk_slot] = chunk_n
+                        completing = c0 + chunk_n == len(jn["toks"])
+                    greedy = all(sched.request_in(s).temperature <= 0
+                                 for s in decode_lanes)
+                    if completing and jn["req"].temperature > 0:
+                        greedy = False  # the chunk's first token is sampled
+                    if paged:
+                        self._ensure_decode_blocks(decode_lanes, lengths,
+                                                   widths=valids)
+                        if chunk_slot is not None:
+                            got = self._prepare_prefill(
+                                chunk_slot, int(lengths[chunk_slot]), chunk_n)
+                            self._reserved[chunk_slot] = max(
+                                0, int(self._reserved[chunk_slot]) - got)
+                    comp_geom = comp_args = None
+                    cw = 0
+                    if comp is not None:
+                        job, offset, cw, clen = comp
+                        comp_geom = (offset, cw, clen)
+                        comp_args = (self.compiler.compressor,
+                                     job.state.cache,
+                                     self.compiler.chunk_tokens(job, cw))
+                    prog = self._fused_program(W, greedy, comp_geom)
+                    out, self.cache, comp_out = prog(
+                        self.params, self.cache, jnp.asarray(tokens_in),
+                        jnp.asarray(lengths, jnp.int32), jnp.asarray(valids),
+                        jnp.asarray(self.tables) if paged else None,
+                        comp_args)
+                    self._charge("decode_step", 1)
+                    if chunk_n:
+                        self._charge("prefill_token", chunk_n)
+                    if comp is not None:
+                        self._charge("compile_token", cw)
+                    self._dirty[:] = True
+                with tr.phase("fused.fetch"):
+                    # greedy: (slots, W) ids; else logits
+                    out = np.asarray(out)
+                with tr.phase("tokens"):
+                    self._counters["decode_time_s"] += self.clock() - t_start
+                    if last_decode_done is not None:
+                        self._note_gap(t_start - last_decode_done)
+                    last_decode_done = self.last_step_t = self.clock()
+                    if tr.enabled:
+                        tr.span("engine", "fused_step", t_start,
+                                last_decode_done, lanes=len(decode_lanes),
+                                chunk_tokens=int(chunk_n),
+                                compile_tokens=int(cw))
+                    self._counters["decode_steps"] += 1
+                    self._counters["fused_steps"] += 1
+                    if chunk_n or comp is not None:
+                        self._counters["fused_chunks"] += 1
+                    if compiling:
+                        self._counters["decode_steps_during_compile"] += 1
+                    if promoting:
+                        self._counters["decode_steps_during_promote"] += 1
+                    if chunk_slot is not None:
+                        jn["consumed"] += chunk_n
+                        lengths[chunk_slot] += chunk_n
+                        self._counters["fused_prefill_chunks"] += 1
+                        self._counters["fused_prefill_tokens"] += int(chunk_n)
+                        if completing:
+                            del self._joining[chunk_slot]
+                            req = jn["req"]
+                            self._counters["prefills"] += 1
+                            if greedy:
+                                tok = int(out[chunk_slot, chunk_n - 1])
+                            else:
+                                tok = self._sample_row(
+                                    out[chunk_slot, chunk_n - 1],
+                                    req.temperature, _stream(req))
+                            pending[chunk_slot] = tok
+                            if self.spec_k:
+                                self._draft_prefill(chunk_slot, jn["toks"])
+                            if tr.enabled:
+                                tr.span(f"slot{chunk_slot}", "admission",
+                                        jn["t0"], rid=self._rids[req.uid],
+                                        prefix=req.prefix,
+                                        prompt_tokens=len(jn["toks"]),
+                                        fused_join=True)
+                            log = self.request_log[req.uid]
+                            if log["first_token_s"] is None:
+                                log["first_token_s"] = self.clock() - epoch
+                                self._m_ttft.observe(
+                                    log["first_token_s"] - log["arrival_s"],
+                                    priority=log["priority"])
+                                if wd is not None:
+                                    wd.observe("ttft", log["first_token_s"]
+                                               - log["arrival_s"])
+                            if sched.record_token(chunk_slot, tok):
+                                _finish(chunk_slot)
                     for s in decode_lanes:
                         req = sched.request_in(s)
-                        left = req.max_new - len(sched.emitted_tokens(s))
-                        k_eff[s] = max(0, min(
-                            self.spec_k, left - 1,
-                            self.max_len - int(lengths[s]) - 1))
-                    drafts, self._draft_cache = self._draft_prog(self.spec_k)(
-                        self._draft_params, self._draft_cache,
-                        jnp.asarray(pending),
-                        jnp.asarray(self._draft_len, jnp.int32))
-                    drafts = np.asarray(drafts)
-                    self._charge("draft_step", self.spec_k + 1)
-                    self._counters["spec_rounds"] += 1
-                chunk_n, jn = 0, None
-                if chunk_slot is not None:
-                    jn = self._joining[chunk_slot]
-                    chunk_n = min(len(jn["toks"]) - jn["consumed"],
-                                  self.fused_chunk_tokens)
-                lanes = 1 + (self.spec_k if spec else 0)
-                W = pow2_bucket(max(lanes, chunk_n), 1)
-                tokens_in = np.zeros((self.slots, W), np.int32)
-                valids = np.zeros((self.slots,), np.int32)
-                for s in decode_lanes:
-                    tokens_in[s, 0] = pending[s]
-                    kk = int(k_eff[s])
-                    if kk:
-                        tokens_in[s, 1:1 + kk] = drafts[s, :kk]
-                    valids[s] = 1 + kk
-                completing = False
-                if chunk_slot is not None:
-                    c0 = jn["consumed"]
-                    tokens_in[chunk_slot, :chunk_n] = \
-                        jn["toks"][c0:c0 + chunk_n]
-                    valids[chunk_slot] = chunk_n
-                    completing = c0 + chunk_n == len(jn["toks"])
-                greedy = all(sched.request_in(s).temperature <= 0
-                             for s in decode_lanes)
-                if completing and jn["req"].temperature > 0:
-                    greedy = False  # the chunk's first token is sampled
-                if paged:
-                    self._ensure_decode_blocks(decode_lanes, lengths,
-                                               widths=valids)
-                    if chunk_slot is not None:
-                        got = self._prepare_prefill(
-                            chunk_slot, int(lengths[chunk_slot]), chunk_n)
-                        self._reserved[chunk_slot] = max(
-                            0, int(self._reserved[chunk_slot]) - got)
-                comp_geom = comp_args = None
-                cw = 0
-                if comp is not None:
-                    job, offset, cw, clen = comp
-                    comp_geom = (offset, cw, clen)
-                    comp_args = (self.compiler.compressor, job.state.cache,
-                                 self.compiler.chunk_tokens(job, cw))
-                prog = self._fused_program(W, greedy, comp_geom)
-                out, self.cache, comp_out = prog(
-                    self.params, self.cache, jnp.asarray(tokens_in),
-                    jnp.asarray(lengths, jnp.int32), jnp.asarray(valids),
-                    jnp.asarray(self.tables) if paged else None, comp_args)
-                self._charge("decode_step", 1)
-                if chunk_n:
-                    self._charge("prefill_token", chunk_n)
-                if comp is not None:
-                    self._charge("compile_token", cw)
-                self._dirty[:] = True
-                out = np.asarray(out)  # greedy: (slots, W) ids; else logits
-                self._counters["decode_time_s"] += self.clock() - t_start
-                if last_decode_done is not None:
-                    gap = t_start - last_decode_done
-                    c = self._counters
-                    c["decode_gap_max_s"] = max(c["decode_gap_max_s"], gap)
-                    c["decode_gap_sum_s"] += gap
-                    c["decode_gaps"] += 1
-                    self._gap_samples.append(gap)
-                    self._gap_window.append(gap)
-                    self._m_gap.observe(gap)
-                    if wd is not None:
-                        wd.observe("decode_gap", gap)
-                last_decode_done = self.last_step_t = self.clock()
-                if tr.enabled:
-                    tr.span("engine", "fused_step", t_start,
-                            last_decode_done, lanes=len(decode_lanes),
-                            chunk_tokens=int(chunk_n),
-                            compile_tokens=int(cw))
-                self._counters["decode_steps"] += 1
-                self._counters["fused_steps"] += 1
-                if chunk_n or comp is not None:
-                    self._counters["fused_chunks"] += 1
-                if compiling:
-                    self._counters["decode_steps_during_compile"] += 1
-                if promoting:
-                    self._counters["decode_steps_during_promote"] += 1
-                self.trace.append(("fused", len(decode_lanes), int(chunk_n),
-                                   int(cw)))
-                if chunk_slot is not None:
-                    jn["consumed"] += chunk_n
-                    lengths[chunk_slot] += chunk_n
-                    self._counters["fused_prefill_chunks"] += 1
-                    self._counters["fused_prefill_tokens"] += int(chunk_n)
-                    if completing:
-                        del self._joining[chunk_slot]
-                        req = jn["req"]
-                        self._counters["prefills"] += 1
-                        if greedy:
-                            tok = int(out[chunk_slot, chunk_n - 1])
+                        kk = int(k_eff[s])
+                        if kk == 0:  # plain decode lane (no drafts)
+                            lengths[s] += 1
+                            tok = (int(out[s, 0]) if greedy
+                                   else self._sample_row(
+                                       out[s, 0], req.temperature,
+                                       _stream(req)))
+                            pending[s] = tok
+                            self._counters["tokens_generated"] += 1
+                            if self.spec_k:
+                                self._draft_len[s] += 1
+                            if sched.record_token(s, tok):
+                                _finish(s)
+                            continue
+                        self._counters["draft_proposed"] += kk
+                        dr = drafts[s, :kk]
+                        if greedy or req.temperature <= 0:
+                            # greedy acceptance: the longest prefix where
+                            # the drafter matched the target's argmax — the
+                            # emitted tokens are exactly the
+                            # non-speculative sequence
+                            g = (out[s, :kk + 1] if greedy else
+                                 np.argmax(out[s, :kk + 1], axis=-1))
+                            a = 0
+                            while a < kk and int(dr[a]) == int(g[a]):
+                                a += 1
+                            emitted = [int(t) for t in g[:a + 1]]
                         else:
-                            tok = self._sample_row(
-                                out[chunk_slot, chunk_n - 1],
-                                req.temperature, _stream(req))
-                        pending[chunk_slot] = tok
-                        if self.spec_k:
-                            self._draft_prefill(chunk_slot, jn["toks"])
-                        self.trace.append(("join_done", req.uid, chunk_slot))
+                            emitted, a = self._spec_sample(
+                                out[s, :kk + 1], dr, req.temperature,
+                                _stream(req))
+                        self._counters["draft_accepted"] += a
                         if tr.enabled:
-                            tr.span(f"slot{chunk_slot}", "admission",
-                                    jn["t0"], rid=self._rids[req.uid],
-                                    prefix=req.prefix,
-                                    prompt_tokens=len(jn["toks"]),
-                                    fused_join=True)
-                        log = self.request_log[req.uid]
-                        if log["first_token_s"] is None:
-                            log["first_token_s"] = self.clock() - epoch
-                            self._m_ttft.observe(
-                                log["first_token_s"] - log["arrival_s"],
-                                priority=log["priority"])
-                            if wd is not None:
-                                wd.observe(
-                                    "ttft",
-                                    log["first_token_s"] - log["arrival_s"])
-                        if sched.record_token(chunk_slot, tok):
-                            _finish(chunk_slot)
-                for s in decode_lanes:
-                    req = sched.request_in(s)
-                    kk = int(k_eff[s])
-                    if kk == 0:  # plain decode lane (no drafts this round)
-                        lengths[s] += 1
-                        tok = (int(out[s, 0]) if greedy else self._sample_row(
-                            out[s, 0], req.temperature, _stream(req)))
-                        pending[s] = tok
-                        self._counters["tokens_generated"] += 1
-                        if self.spec_k:
-                            self._draft_len[s] += 1
-                        if sched.record_token(s, tok):
+                            tr.instant(f"slot{s}", "spec_accept",
+                                       rid=self._rids[req.uid],
+                                       proposed=kk, accepted=int(a))
+                        # implicit KV rollback: only the accepted prefix
+                        # counts — rejected lanes' cache rows sit beyond
+                        # the new length (dense) / in private tail blocks
+                        # (paged) and are causally invisible until
+                        # overwritten next round
+                        lengths[s] += len(emitted)
+                        self._draft_len[s] += len(emitted)
+                        pending[s] = emitted[-1]
+                        fin = False
+                        for t in emitted:
+                            self._counters["tokens_generated"] += 1
+                            if sched.record_token(s, t):
+                                fin = True
+                                break
+                        if fin:
                             _finish(s)
-                        continue
-                    self._counters["draft_proposed"] += kk
-                    dr = drafts[s, :kk]
-                    if greedy or req.temperature <= 0:
-                        # greedy acceptance: the longest prefix where the
-                        # drafter matched the target's argmax — the emitted
-                        # tokens are exactly the non-speculative sequence
-                        g = (out[s, :kk + 1] if greedy else
-                             np.argmax(out[s, :kk + 1], axis=-1))
-                        a = 0
-                        while a < kk and int(dr[a]) == int(g[a]):
-                            a += 1
-                        emitted = [int(t) for t in g[:a + 1]]
-                    else:
-                        emitted, a = self._spec_sample(
-                            out[s, :kk + 1], dr, req.temperature, _stream(req))
-                    self._counters["draft_accepted"] += a
-                    if tr.enabled:
-                        tr.instant(f"slot{s}", "spec_accept",
-                                   rid=self._rids[req.uid],
-                                   proposed=kk, accepted=int(a))
-                    # implicit KV rollback: only the accepted prefix counts —
-                    # rejected lanes' cache rows sit beyond the new length
-                    # (dense) / in private tail blocks (paged) and are
-                    # causally invisible until overwritten next round
-                    lengths[s] += len(emitted)
-                    self._draft_len[s] += len(emitted)
-                    pending[s] = emitted[-1]
-                    fin = False
-                    for t in emitted:
-                        self._counters["tokens_generated"] += 1
-                        if sched.record_token(s, t):
-                            fin = True
-                            break
-                    if fin:
-                        _finish(s)
-                if comp is not None:
-                    self.compiler.absorb_chunk(job, comp_out[0], comp_out[1],
-                                               cw)
-                    self._counters["fused_compile_chunks"] += 1
-                    self._counters["compile_chunks_interleaved"] += 1
-                    self.trace.append(("compile", cw))
-                    if tr.enabled:
-                        # the chunk rode the fused dispatch: its span is
-                        # the step's own window on the compiler track
-                        tr.span("compiler", "compile_chunk", t_start,
-                                last_decode_done, tokens=int(cw),
-                                fused=True)
-                elif compiling and self.compile_token_budget is None:
+                    if comp is not None:
+                        self.compiler.absorb_chunk(job, comp_out[0],
+                                                   comp_out[1], cw)
+                        self._counters["fused_compile_chunks"] += 1
+                        self._counters["compile_chunks_interleaved"] += 1
+                        if tr.enabled:
+                            # the chunk rode the fused dispatch: its span
+                            # is the step's own window on the compiler
+                            # track
+                            tr.span("compiler", "compile_chunk", t_start,
+                                    last_decode_done, tokens=int(cw),
+                                    fused=True)
+                if comp is None and compiling \
+                        and self.compile_token_budget is None:
                     # unbudgeted compile cannot ride the chunk lane — run
                     # the whole job behind this step (the stalled baseline)
                     self._compile_step(None)
@@ -1397,7 +1411,6 @@ class ServingEngine:
             self._seated[victim] = None
         self._counters["preemptions"] += 1
         self.request_log[req.uid]["preemptions"] += 1
-        self.trace.append(("preempt", req.uid, victim))
         if self.tracer.enabled:
             self.tracer.instant(f"slot{victim}", "preempt",
                                 rid=self._rids[req.uid],
@@ -1406,14 +1419,31 @@ class ServingEngine:
 
     def _advance_to(self, t: float) -> None:
         """Wait until the clock reads ``t``: a virtual clock jumps there;
-        a wall clock sleeps one short slice (the loop re-checks)."""
+        a wall clock sleeps one short slice (the loop re-checks).  The
+        ``serve.idle`` phase carries the due time (``due_s``, from serve()
+        start) and the planned sleep (``sleep_ms``): how late the loop
+        woke is the phase's length less ``sleep_ms``."""
         jump = getattr(self.clock, "advance_to", None)
-        if jump is not None:
-            jump(t)
-            return
-        dt = t - self.clock()
-        if dt > 0:
-            time.sleep(min(dt, 0.02))
+        sleep = 0.0 if jump is not None else min(max(0.0, t - self.clock()),
+                                                 0.02)
+        with self.tracer.phase("idle", due_s=t - self._epoch,
+                               sleep_ms=1e3 * sleep):
+            if jump is not None:
+                jump(t)
+            elif sleep > 0:
+                time.sleep(sleep)
+
+    def _note_gap(self, gap: float) -> None:
+        """Record one decode gap: non-decode time since the last step."""
+        c = self._counters
+        c["decode_gap_max_s"] = max(c["decode_gap_max_s"], gap)
+        c["decode_gap_sum_s"] += gap
+        c["decode_gaps"] += 1
+        self._gap_samples.append(gap)
+        self._gap_window.append(gap)
+        self._m_gap.observe(gap)
+        if self.watchdog is not None:
+            self.watchdog.observe("decode_gap", gap)
 
     def _autotune_step(self) -> None:
         """Feedback controller on the compile/promote budgets: while the
@@ -1439,9 +1469,6 @@ class ServingEngine:
                 changed = True
             if changed:
                 self._counters["autotune_shrinks"] += 1
-                self.trace.append(("autotune", "shrink",
-                                   self.compile_token_budget,
-                                   self.promote_layer_budget))
                 if self.tracer.enabled:
                     self.tracer.instant(
                         "engine", "autotune", action="shrink",
@@ -1459,9 +1486,6 @@ class ServingEngine:
                 changed = True
             if changed:
                 self._counters["autotune_grows"] += 1
-                self.trace.append(("autotune", "grow",
-                                   self.compile_token_budget,
-                                   self.promote_layer_budget))
                 if self.tracer.enabled:
                     self.tracer.instant(
                         "engine", "autotune", action="grow",
@@ -1519,7 +1543,6 @@ class ServingEngine:
                     self.compiler.submit(req.prefix, req.raw_shots,
                                          priority=req.priority)
                 sched.park(req)
-                self.trace.append(("park", req.uid, req.prefix))
                 if self.tracer.enabled:
                     self.tracer.begin_async(
                         "scheduler", "waiting_on_prefix",
@@ -1535,13 +1558,13 @@ class ServingEngine:
                 f"exceeds max_len={self.max_len}")
 
     def _compile_step(self, token_budget: Optional[int]) -> None:
-        before = self.compiler.stats["tokens"]
-        t0 = self.clock()
-        self.compiler.step(token_budget)
-        consumed = self.compiler.stats["tokens"] - before
+        with self.tracer.phase("compile_chunk"):
+            before = self.compiler.stats["tokens"]
+            t0 = self.clock()
+            self.compiler.step(token_budget)
+            consumed = self.compiler.stats["tokens"] - before
         if consumed:
             self._charge("compile_token", consumed)
-            self.trace.append(("compile", consumed))
             if self.tracer.enabled:
                 self.tracer.span("compiler", "compile_chunk", t0,
                                  tokens=int(consumed))
@@ -1551,13 +1574,13 @@ class ServingEngine:
     # ------------------------------------------------------------------
 
     def _promote_step(self, chunk_budget: Optional[int]) -> None:
-        before = self.tiers.tier_stats["promote_chunks"]
-        t0 = self.clock()
-        self.tiers.promote_step(chunk_budget)
-        copied = self.tiers.tier_stats["promote_chunks"] - before
+        with self.tracer.phase("promote_chunk"):
+            before = self.tiers.tier_stats["promote_chunks"]
+            t0 = self.clock()
+            self.tiers.promote_step(chunk_budget)
+            copied = self.tiers.tier_stats["promote_chunks"] - before
         if copied:
             self._charge("promote_chunk", copied)
-            self.trace.append(("promote", copied))
             if self.tracer.enabled:
                 self.tracer.span("promoter", "promote_chunk", t0,
                                  chunks=int(copied))
@@ -1581,11 +1604,9 @@ class ServingEngine:
         if not self._install(put, sched):
             return  # paged seat pressure: retry on a later iteration
         self.tiers.mark_promoted(name)
-        self.trace.append(("promoted", name))
         if self.tracer.enabled:
             self.tracer.instant("promoter", "promoted", prefix=name)
         for req in sched.wake(name):
-            self.trace.append(("wake", req.uid, name))
             if self.tracer.enabled:
                 self.tracer.end_async("scheduler", "waiting_on_prefix",
                                       self._rids[req.uid])
@@ -1603,11 +1624,9 @@ class ServingEngine:
                                  sched):
             return  # paged seat pressure: retry on a later iteration
         self.compiler.mark_installed(name)
-        self.trace.append(("seat", name))
         if self.tracer.enabled:
             self.tracer.instant("compiler", "prefix_installed", prefix=name)
         for req in sched.wake(name):
-            self.trace.append(("wake", req.uid, name))
             if self.tracer.enabled:
                 self.tracer.end_async("scheduler", "waiting_on_prefix",
                                       self._rids[req.uid])
@@ -1767,36 +1786,39 @@ class ServingEngine:
         base = int(self.base[slot])
         cap = self.max_len - base
         assert 0 < n <= cap, (n, cap)
-        self._counters["prefills"] += 1
-        width = _bucket(n, cap) if self._pad_prefill else n
-        self._note_geometry("prefill", (width, base))
-        self._charge("prefill_token", width)
-        padded = np.zeros((1, width), np.int32)
-        padded[0, :n] = tokens
-        if self.kv_layout == "paged":
-            snap = None
-            if not persist:
-                snap = (self.alloc.snapshot(), self.tables[slot].copy(),
-                        list(self._slot_blocks[slot]))
-            self._prepare_prefill(slot, base, width)
-            logits, new_cache = self._prefill(
-                self.params, self.cache, jnp.asarray(padded),
-                jnp.int32(slot), jnp.asarray(self.tables[slot]), base)
-            if snap is not None:
-                # one-shot scoring: roll the allocator and table back; the
-                # discarded blocks may hold scatter garbage, but a block is
-                # only ever read after being re-allocated *and* re-written
-                self.alloc.restore(snap[0])
-                self.tables[slot] = snap[1]
-                self._slot_blocks[slot] = snap[2]
-        else:
-            logits, new_cache = self._prefill(
-                self.params, self.cache, jnp.asarray(padded),
-                jnp.int32(slot), base)
-        if persist:
-            self.cache = new_cache
-            self._dirty[slot] = True
-        return np.asarray(logits[n - 1])
+        with self.tracer.phase("prefill.dispatch"):
+            self._counters["prefills"] += 1
+            width = _bucket(n, cap) if self._pad_prefill else n
+            self._note_geometry("prefill", (width, base))
+            self._charge("prefill_token", width)
+            padded = np.zeros((1, width), np.int32)
+            padded[0, :n] = tokens
+            if self.kv_layout == "paged":
+                snap = None
+                if not persist:
+                    snap = (self.alloc.snapshot(), self.tables[slot].copy(),
+                            list(self._slot_blocks[slot]))
+                self._prepare_prefill(slot, base, width)
+                logits, new_cache = self._prefill(
+                    self.params, self.cache, jnp.asarray(padded),
+                    jnp.int32(slot), jnp.asarray(self.tables[slot]), base)
+                if snap is not None:
+                    # one-shot scoring: roll the allocator and table back;
+                    # the discarded blocks may hold scatter garbage, but a
+                    # block is only ever read after being re-allocated
+                    # *and* re-written
+                    self.alloc.restore(snap[0])
+                    self.tables[slot] = snap[1]
+                    self._slot_blocks[slot] = snap[2]
+            else:
+                logits, new_cache = self._prefill(
+                    self.params, self.cache, jnp.asarray(padded),
+                    jnp.int32(slot), base)
+            if persist:
+                self.cache = new_cache
+                self._dirty[slot] = True
+        with self.tracer.phase("prefill.fetch"):
+            return np.asarray(logits[n - 1])
 
     # ------------------------------------------------------------------
     # Paged capacity management

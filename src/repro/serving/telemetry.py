@@ -24,6 +24,13 @@ enabled telemetry on the virtual clock is a deterministic function of
 Disabled telemetry is the :data:`NULL_TRACER` no-op singleton — the
 serving loop's token stream is bit-exact with tracing on or off,
 because telemetry only ever *reads* the clock and never charges it.
+
+Both tracers mark the serving loop's host phases with
+:meth:`Tracer.phase`: a ``jax.profiler.TraceAnnotation`` named
+``serve.<phase>`` that lands on the profiler's own clock, beside the
+device's operations, whenever a profiler session is running (about a
+microsecond when none is), plus a ``loop``-track span in the ring
+buffer when the tracer is enabled.
 """
 
 from __future__ import annotations
@@ -35,6 +42,8 @@ import time
 from collections import OrderedDict, deque
 from typing import (Callable, Dict, Iterable, List, Mapping, MutableMapping,
                     Optional, Sequence, Tuple)
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Tracer", "NullTracer", "NULL_TRACER",
@@ -50,7 +59,8 @@ __all__ = [
 # Fixed Chrome-trace thread ids for the shared tracks; per-slot tracks
 # ("slot0", "slot1", …) sit at _SLOT_TID_BASE + index so traces from
 # engines of any slot count lay out identically.
-_TRACK_TIDS = {"engine": 1, "compiler": 2, "promoter": 3, "scheduler": 4}
+_TRACK_TIDS = {"engine": 1, "compiler": 2, "promoter": 3, "scheduler": 4,
+               "loop": 5}
 _SLOT_TID_BASE = 16
 _PID = 1
 
@@ -60,6 +70,11 @@ _PID = 1
 #: when speculative decoding is on).
 REQUIRED_SPANS = ("admission", "waiting_on_prefix", "compile_chunk",
                   "promote_chunk", "preempt", "resume", "decode_step")
+
+#: prefix of every host-phase name (profiler annotation and ring-buffer
+#: span alike) and the ring-buffer track the phases land on
+PHASE_PREFIX = "serve."
+PHASE_TRACK = "loop"
 
 
 def _track_tid(track: str) -> int:
@@ -143,6 +158,14 @@ class Tracer:
                     "t": self.now() if t is None else float(t),
                     "args": args})
 
+    def phase(self, name: str, **args) -> "_Phase":
+        """Context manager over one host phase of the serving loop: a
+        profiler annotation ``serve.<name>`` and, on exit, a complete
+        span of the same name on the ``loop`` track.  Phases are
+        siblings, never nested: a trace reader names each device-idle
+        gap after the phase that overlaps it most."""
+        return _Phase(self, PHASE_PREFIX + name, args)
+
     def clear(self) -> None:
         self._events.clear()
         self.dropped = 0
@@ -222,6 +245,25 @@ class Tracer:
             return None
 
 
+class _Phase:
+    """One traced host phase: the profiler annotation, then its span."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_ann", "_t0")
+
+    def __init__(self, tracer: Tracer, name: str, args: dict):
+        self._tracer, self._name, self._args = tracer, name, args
+        self._ann = TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "_Phase":
+        self._ann.__enter__()
+        self._t0 = self._tracer.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._tracer.span(PHASE_TRACK, self._name, self._t0, **self._args)
+        self._ann.__exit__(*exc)
+
+
 class NullTracer:
     """No-op tracer: the default.  Every method is a pass so disabled
     telemetry costs one attribute lookup per site and the serving loop
@@ -247,6 +289,10 @@ class NullTracer:
 
     def end_async(self, *a, **k) -> None:
         pass
+
+    def phase(self, name: str, **args) -> TraceAnnotation:
+        """The profiler annotation alone (see :meth:`Tracer.phase`)."""
+        return TraceAnnotation(PHASE_PREFIX + name, **args)
 
     def clear(self) -> None:
         pass

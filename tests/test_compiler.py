@@ -15,6 +15,7 @@ from repro.serving import (
     PrefixCompiler,
     Request,
     ServingEngine,
+    Tracer,
     materialize_prefix,
 )
 
@@ -191,18 +192,20 @@ def test_decode_continues_during_compile(setup, rng):
     kv_a = materialize_prefix(
         params, cfg, memcom.compress(mc, cfg, jnp.asarray(shots_a[None]))[0])
 
+    tracer = Tracer(capacity=None)
     eng = ServingEngine(cfg, params, slots=2, max_len=m + 40,
-                        compressor=mc, compile_token_budget=8)
+                        compressor=mc, compile_token_budget=8, tracer=tracer)
     eng.add_prefix("A", kv_a)
     warm = Request(tokens=prompt, max_new=20, prefix="A")
     cold = Request(tokens=prompt, max_new=3, raw_shots=shots_b)
     out = eng.serve([warm, cold])
 
-    compile_idx = [i for i, e in enumerate(eng.trace) if e[0] == "compile"]
-    decode_between = [i for i, e in enumerate(eng.trace)
-                      if e[0] == "decode" and compile_idx[0] < i < compile_idx[-1]]
-    assert len(compile_idx) >= 3, eng.trace  # 48 tokens / 8-token budget
-    assert decode_between, eng.trace  # decode interleaved with compilation
+    names = [e["name"] for e in tracer.events()]
+    compile_idx = [i for i, n in enumerate(names) if n == "compile_chunk"]
+    decode_between = [i for i, n in enumerate(names) if n == "decode_step"
+                      and compile_idx[0] < i < compile_idx[-1]]
+    assert len(compile_idx) >= 3, names  # 48 tokens / 8-token budget
+    assert decode_between, names  # decode interleaved with compilation
     assert eng.stats()["engine"]["decode_steps_during_compile"] >= 3
 
     solo = ServingEngine(cfg, params, slots=1, max_len=m + 40)

@@ -12,7 +12,7 @@ from repro.configs import get_smoke_config
 from repro.core import memcom
 from repro.kernels import ops
 from repro.models import transformer as tfm
-from repro.serving import Request, VirtualClock
+from repro.serving import Request, Tracer, VirtualClock
 from repro.serving.engine import ServingEngine
 
 PROMPT_LENS = (5, 11, 8, 3, 7, 9)
@@ -155,15 +155,17 @@ def _check_token_conservation(setup, ref, idx, chunk, stagger, spec_k):
     and the decode counter equals total output minus the first tokens."""
     cfg, params, prompts = setup
     kw = {} if spec_k == 0 else {"spec_draft": "self", "spec_k": spec_k}
+    tracer = Tracer(capacity=None)
     eng = ServingEngine(cfg, params, slots=2, max_len=40, kv_layout="paged",
                         clock=VirtualClock(), fused_step=True,
-                        fused_chunk_tokens=chunk, **kw)
+                        fused_chunk_tokens=chunk, tracer=tracer, **kw)
     got = _serve(eng, prompts, idx,
                  arrival_s=[stagger * j for j in range(len(idx))])
     assert got == [ref[i] for i in idx]
     es = eng.stats()["engine"]
     assert es["tokens_generated"] == len(idx) * MAX_NEW - len(idx)
-    joined = sum(t[3] for t in eng.trace if t[0] == "join")
+    joined = sum(e["args"]["prompt_tokens"] for e in tracer.events()
+                 if e["name"] == "admission" and e["args"].get("fused_join"))
     assert es["fused_prefill_tokens"] == joined
 
 

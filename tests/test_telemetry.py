@@ -261,10 +261,31 @@ def test_validate_chrome_trace_catches_malformed():
     assert any("missing_span" in e for e in errs)
 
 
+def test_phase_span_on_loop_track():
+    """A phase is one span of its own on the "loop" track, timed by the
+    tracer's clock, with the phase's arguments."""
+    clock = VirtualClock()
+    tr = Tracer(clock)
+    with tr.phase("admit", rid=3):
+        clock.advance(0.004)
+    with tr.phase("tokens"):
+        pass
+    a, b = tr.events()
+    assert (a["track"], a["name"], a["args"]) == ("loop", "serve.admit",
+                                                  {"rid": 3})
+    assert math.isclose(a["dur"], 0.004) and b["t"] == a["t"] + a["dur"]
+    assert b["name"] == "serve.tokens" and b["dur"] == 0.0
+    tid = {e["args"]["name"]: e["tid"] for e in tr.chrome_trace()
+           ["traceEvents"] if e["name"] == "thread_name"}
+    assert tid == {"loop": 5}
+
+
 def test_null_tracer_is_inert():
     assert NULL_TRACER.enabled is False
     NULL_TRACER.span("engine", "x", 0.0)
     NULL_TRACER.instant("engine", "y")
+    with NULL_TRACER.phase("admit", rid=0):
+        pass
     assert NULL_TRACER.events() == []
     assert NULL_TRACER.chrome_trace() == {"traceEvents": []}
     assert NULL_TRACER.dump_on_error() is None
@@ -322,25 +343,46 @@ def churn_traced(setup, tmp_path_factory):
     runs = []
     for sub in ("a", "b"):
         tracer, reg = Tracer(), MetricsRegistry()
-        _, tokens = _churn_run(cfg, params, mc, root / sub,
-                               tracer=tracer, metrics=reg)
+        eng, tokens = _churn_run(cfg, params, mc, root / sub,
+                                 tracer=tracer, metrics=reg)
         runs.append({"dumps": tracer.dumps(), "tokens": tokens,
-                     "registry": reg})
-    _, tokens_off = _churn_run(cfg, params, mc, root / "off")
-    return runs[0], runs[1], tokens_off
+                     "registry": reg, "log": eng.request_log})
+    eng, tokens_off = _churn_run(cfg, params, mc, root / "off")
+    return runs[0], runs[1], tokens_off, eng.request_log
 
 
 def test_trace_byte_identical_across_same_seed_runs(churn_traced):
-    a, b, _ = churn_traced
+    a, b, *_ = churn_traced
     assert a["dumps"] == b["dumps"]           # byte-for-byte
     assert len(a["dumps"]) > 1000             # and non-trivial
+
+
+def test_request_stamps_in_order(churn_traced):
+    """Under churn (queueing, parking on compiles and promotions,
+    preemption) every request's stamps run due → released → admitted →
+    first token → finish, queue wait plus admission-to-first-token is its
+    TTFT, and the stamps are the same with tracing off."""
+    a, _, _, log_off = churn_traced
+    order = ("arrival_s", "released_s", "admitted_s", "first_token_s",
+             "finish_s")
+    assert len(a["log"]) == CHURN.num_requests
+    for r in a["log"].values():
+        stamps = [r[k] for k in order]
+        assert stamps == sorted(stamps), r
+        ttft = r["first_token_s"] - r["arrival_s"]
+        assert math.isclose((r["admitted_s"] - r["arrival_s"])
+                            + (r["first_token_s"] - r["admitted_s"]), ttft,
+                            rel_tol=0, abs_tol=1e-12)
+    assert any(r["admitted_s"] > r["released_s"] for r in a["log"].values())
+    assert sorted(map(sorted, (r.items() for r in a["log"].values()))) == \
+        sorted(map(sorted, (r.items() for r in log_off.values())))
 
 
 def test_trace_covers_request_lifecycle(churn_traced):
     """The churn trace contains every span the taxonomy guarantees:
     admission, waiting_on_prefix, compile_chunk, promote_chunk,
     preempt, resume, decode_step."""
-    a, _, _ = churn_traced
+    a, *_ = churn_traced
     trace = json.loads(a["dumps"])
     assert validate_chrome_trace(trace, require_spans=REQUIRED_SPANS) == []
 
@@ -348,7 +390,7 @@ def test_trace_covers_request_lifecycle(churn_traced):
 def test_tracer_on_off_token_identity_dense(churn_traced):
     """Telemetry only reads the clock: the traced churn run emits
     exactly the tokens of the untraced one."""
-    a, _, tokens_off = churn_traced
+    a, _, tokens_off, _ = churn_traced
     assert a["tokens"] == tokens_off
 
 
@@ -390,7 +432,7 @@ def test_churn_prometheus_exposition(churn_traced):
     """The registry a churn engine filled renders every subsystem's
     series: engine/compiler/store/tier counters, scheduler gauges, the
     decode-gap histogram and the virtual-clock charge counters."""
-    a, b, _ = churn_traced
+    a, b, *_ = churn_traced
     text = a["registry"].render_prometheus()
     for needle in (
             "# TYPE serving_engine_decode_steps gauge",

@@ -19,6 +19,7 @@ from repro.serving import (
     PrefixSeatedError,
     Request,
     ServingEngine,
+    Tracer,
     materialize_prefix,
 )
 from repro.serving.prefix_store import take_prefix_row
@@ -256,8 +257,10 @@ def test_park_wake_fifo_on_cold_miss(setup, rng):
     kv_b = _compress_kv(cfg, params, mc,
                         rng.integers(4, cfg.vocab_size, 40).astype(np.int32))
 
+    tracer = Tracer(capacity=None)
     eng = ServingEngine(cfg, params, slots=1, max_len=m + 24,
-                        host_capacity=4, promote_layer_budget=1)
+                        host_capacity=4, promote_layer_budget=1,
+                        tracer=tracer)
     eng.add_prefix("A", kv_a)
     eng.add_prefix("B", kv_b)
     eng.store.demote("B")
@@ -267,12 +270,15 @@ def test_park_wake_fifo_on_cold_miss(setup, rng):
     r3 = Request(tokens=prompt, max_new=2, prefix="B")   # parks (joined)
     eng.serve([r1, r2, r3])
 
-    parked = [e[1] for e in eng.trace if e[0] == "park"]
-    assert parked == [r1.uid, r3.uid]
-    admits = [e[1] for e in eng.trace if e[0] == "admit"]
+    rid = {r.uid: eng._rids[r.uid] for r in (r1, r2, r3)}
+    events = tracer.events()
+    parked = [e["id"] for e in events
+              if e["ph"] == "b" and e["name"] == "waiting_on_prefix"]
+    assert parked == [str(rid[r1.uid]), str(rid[r3.uid])]
+    admits = [e["args"]["rid"] for e in events if e["name"] == "admission"]
     # one slot: strict admission order — warm r2 immediately, then the
     # woken cold requests in arrival order
-    assert admits == [r2.uid, r1.uid, r3.uid]
+    assert admits == [rid[r2.uid], rid[r1.uid], rid[r3.uid]]
     assert eng.stats()["prefix_tiers"]["host_promotes"] == 1  # single-flight
 
 
@@ -294,8 +300,10 @@ def test_decode_continues_during_promotion(setup, rng):
     kv_b = _compress_kv(cfg, params, mc,
                         rng.integers(4, cfg.vocab_size, 48).astype(np.int32))
 
+    tracer = Tracer(capacity=None)
     eng = ServingEngine(cfg, params, slots=2, max_len=m + 40,
-                        host_capacity=4, promote_layer_budget=1)
+                        host_capacity=4, promote_layer_budget=1,
+                        tracer=tracer)
     eng.add_prefix("A", kv_a)
     eng.add_prefix("B", kv_b)
     eng.store.demote("B")
@@ -303,11 +311,12 @@ def test_decode_continues_during_promotion(setup, rng):
     cold = Request(tokens=prompt, max_new=3, prefix="B")
     out = eng.serve([warm, cold])
 
-    promote_idx = [i for i, e in enumerate(eng.trace) if e[0] == "promote"]
-    decode_between = [i for i, e in enumerate(eng.trace) if e[0] == "decode"
+    names = [e["name"] for e in tracer.events()]
+    promote_idx = [i for i, n in enumerate(names) if n == "promote_chunk"]
+    decode_between = [i for i, n in enumerate(names) if n == "decode_step"
                       and promote_idx[0] < i < promote_idx[-1]]
-    assert len(promote_idx) >= 2, eng.trace  # budget=1 forces chunking
-    assert decode_between, eng.trace
+    assert len(promote_idx) >= 2, names  # budget=1 forces chunking
+    assert decode_between, names
     assert eng.stats()["engine"]["decode_steps_during_promote"] >= 2
 
     solo = ServingEngine(cfg, params, slots=1, max_len=m + 40)
