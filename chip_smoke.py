@@ -241,7 +241,8 @@ def _decode_probe(jax, jnp, np, engine, label: str):
     compiled = engine._decode.lower(*args).compile()
     check("tpu_custom_call" in compiled.as_text(),
           f"{label}: decode step holds no Pallas kernel")
-    logits, _ = compiled(*args)
+    # a paged engine's step takes its cache donated: keep the one returned
+    logits, engine.cache = compiled(*args)
     logits = np.asarray(jax.block_until_ready(logits), np.float32)
     check(np.isfinite(logits).all(), f"{label}: non-finite decode logits")
     say(f"{label}: decode step compiled in {time.perf_counter() - t0:.2f}s "

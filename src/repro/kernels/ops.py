@@ -59,21 +59,26 @@ def _head_parallel(mesh, *operands, head_axis=2):
     return n > 1 and all(x.shape[head_axis] % n == 0 for x in operands)
 
 
-def _per_device(mesh, fn, head_args, rep_args, out_ndims=4):
+def _per_device(mesh, fn, head_args, rep_args, out_ndims=4, head_axes=None,
+                split=None):
     """Run the Pallas call ``fn(*head_args, *rep_args)`` with ``mesh``
     installed.  Mosaic kernels cannot be partitioned automatically, so on
     a multi-device mesh every device runs the kernel under shard_map: on
     its own head slice when each of ``head_args`` splits its head axis
-    over "model" (``out_ndims``: the outputs' ranks, head axis 2), else on
-    the whole, replicated operands."""
+    (``head_axes``, one per head arg, default 2) over "model"
+    (``out_ndims``: the outputs' ranks, head axis 2), else on the whole,
+    replicated operands.  ``split`` overrides the divisibility test where
+    a head axis holds more than the heads (lane-merged pools)."""
     if mesh is None or mesh.devices.size <= 1:
         return fn(*head_args, *rep_args)
     from repro.sharding.serving import shard_map_heads, shard_map_replicated
 
-    if head_args and _head_parallel(mesh, *head_args):
+    if split is None:
+        split = bool(head_args) and _head_parallel(mesh, *head_args)
+    if split:
         wrapped = shard_map_heads(fn, mesh, head_args=len(head_args),
                                   replicated_args=len(rep_args),
-                                  out_ndims=out_ndims)
+                                  out_ndims=out_ndims, head_axes=head_axes)
     else:
         wrapped = shard_map_replicated(fn, mesh)
     return wrapped(*head_args, *rep_args)
@@ -202,17 +207,23 @@ def decode_attention(q, k, v, *, lengths, softcap=0.0, scale=None,
 
 
 def paged_decode_attention(q, k_pool, v_pool, *, block_tables, lengths,
-                           softcap=0.0, scale=None, impl="auto", mesh=None):
+                           layer=None, kv_heads=None, softcap=0.0, scale=None,
+                           impl="auto", mesh=None):
     """Per-slot decode attention over a paged (block-pool) KV cache.
 
     ``q`` (B, S, Hq, D) holds each slot's last S tokens; ``k_pool`` /
-    ``v_pool`` (num_blocks, block_size, Hkv, D) are the shared physical
-    pools; ``block_tables`` (B, nb) int32 maps slot ``b``'s logical block
-    ``j`` to a pool block; ``lengths`` (B,) is each slot's total valid
-    length *including* the S new tokens.  Slot ``b`` attends causally
-    within logical positions ``[0, lengths[b])`` — identical semantics to
-    :func:`decode_attention` on the materialized view, but prefix blocks
-    shared between slots are stored (and streamed) once.
+    ``v_pool`` are the shared physical pools, lane-merged
+    ``(num_blocks, block_size, W)`` as the paged cache stores them (rows
+    zero-padded past their heads name ``kv_heads``, see
+    :func:`jnp_impl.merged_heads`), or ``(num_blocks, block_size, Hkv,
+    D)``; with ``layer`` (a traced int) they carry a leading per-layer
+    axis and only that layer's blocks are read.  ``block_tables`` (B, nb)
+    int32 maps slot ``b``'s logical block ``j`` to a pool block;
+    ``lengths`` (B,) is each slot's total valid length *including* the S
+    new tokens.  Slot ``b`` attends causally within logical positions
+    ``[0, lengths[b])`` — identical semantics to :func:`decode_attention`
+    on the materialized view, but prefix blocks shared between slots are
+    stored (and streamed) once.
 
     The jnp path gathers one ``(B, block_size, ...)`` chunk per table
     column and skips columns past ``max(lengths)``; the pallas path walks
@@ -220,46 +231,65 @@ def paged_decode_attention(q, k_pool, v_pool, *, block_tables, lengths,
     reusing the flash-decode inner loop); the dense path materializes each
     slot's view and defers to :func:`decode_attention`'s oracle.
 
-    ``mesh``: tensor-parallel serving.  Q and the physical pools split on
-    their head axis over the "model" mesh axis; ``block_tables`` and
-    ``lengths`` are replicated on every shard (the table resolves block
-    *indices*, identical per head shard — the control plane never shards).
-    The jnp path is pinned head-parallel with a sharding constraint; the
-    pallas path runs per-shard under ``shard_map``.
+    ``mesh``: tensor-parallel serving.  Q splits on its head axis and the
+    pools on their lane axis, by whole KV heads, over the "model" mesh
+    axis where the heads divide it and the rows hold them exactly
+    (replicated otherwise); ``block_tables``, ``lengths`` and ``layer``
+    are replicated on every shard (the table resolves block *indices*,
+    identical per head shard — the control plane never shards).  The jnp
+    path is pinned head-parallel with a sharding constraint; the pallas
+    path runs per-shard under ``shard_map``.
     """
-    B, S = q.shape[:2]
-    bs = k_pool.shape[1]
+    B, S, Hq, D = q.shape
+    lead = 0 if layer is None else 1
+    if k_pool.ndim == lead + 4:  # (..., Hkv, D): merge the heads into lanes
+        k_pool = k_pool.reshape(*k_pool.shape[:-2], -1)
+        v_pool = v_pool.reshape(*v_pool.shape[:-2], -1)
+    bs = k_pool.shape[lead + 1]
+    Hkv, Dv = jnp_impl.merged_heads(k_pool, v_pool, D, kv_heads)
     L = block_tables.shape[1] * bs
     small = S * L <= 256 * 256
     impl = _resolve(impl, small)
     if impl == "dense":
-        k = jnp_impl.paged_gather(k_pool, block_tables).astype(q.dtype)
-        v = jnp_impl.paged_gather(v_pool, block_tables).astype(q.dtype)
+        k = jnp_impl.paged_gather(k_pool, block_tables, layer)
+        v = jnp_impl.paged_gather(v_pool, block_tables, layer)
+        k = k[..., :Hkv * D].reshape(B, L, Hkv, D).astype(q.dtype)
+        v = v[..., :Hkv * Dv].reshape(B, L, Hkv, Dv).astype(q.dtype)
         slot = jnp.arange(L, dtype=jnp.int32)
         kv_pos = jnp.broadcast_to(slot[None, :], (B, L))
         q_pos = lengths[:, None] - S + jnp.arange(S, dtype=jnp.int32)[None, :]
         return ref.attention_ref(q, k, v, q_pos=q_pos, kv_pos=kv_pos,
                                  causal=True, softcap=softcap, scale=scale)
+    from repro.sharding.serving import model_axis_size
+
+    n = model_axis_size(mesh)
+    exact = k_pool.shape[-1] == Hkv * D and v_pool.shape[-1] == Hkv * Dv
+    split = n > 1 and exact and Hq % n == 0 and Hkv % n == 0
     if impl == "pallas":
         from repro.kernels import paged_attention  # lazy: TPU-targeted
 
-        def run(q, k_pool, v_pool, block_tables, lengths):
+        def run(q, k_pool, v_pool, block_tables, lengths, *lyr):
             return paged_attention.paged_flash_decode(
                 q, k_pool, v_pool, block_tables=block_tables,
-                lengths=lengths, softcap=softcap, scale=scale,
-                interpret=_interpret())
+                lengths=lengths, layer=lyr[0] if lyr else None,
+                kv_heads=None if exact else Hkv, softcap=softcap,
+                scale=scale, interpret=_interpret())
 
-        return _per_device(mesh, run, (q, k_pool, v_pool),
-                           (block_tables, lengths))
-    if _head_parallel(mesh, q, k_pool, v_pool):
+        rep = (block_tables, lengths)
+        if layer is not None:
+            rep += (jnp.asarray(layer, jnp.int32),)
+        return _per_device(mesh, run, (q, k_pool, v_pool), rep,
+                           head_axes=(2, -1, -1), split=split)
+    if split:
         from repro.sharding.serving import constrain_heads
 
         q = constrain_heads(q, mesh)
-        k_pool = constrain_heads(k_pool, mesh)
-        v_pool = constrain_heads(v_pool, mesh)
+        k_pool = constrain_heads(k_pool, mesh, axis=k_pool.ndim - 1)
+        v_pool = constrain_heads(v_pool, mesh, axis=v_pool.ndim - 1)
     return jnp_impl.paged_decode_attention_lengths(
         q, k_pool, v_pool, block_tables=block_tables, lengths=lengths,
-        softcap=softcap, scale=scale)
+        softcap=softcap, scale=scale, layer=layer,
+        kv_heads=None if exact else Hkv)
 
 
 def attention_with_prefix(q, k_self, v_self, k_pre, v_pre, *, pre_pos=None,
@@ -361,6 +391,7 @@ ssd_decode_step = jnp_impl.ssd_decode_step
 
 # paged-cache primitives (pure jnp, re-exported so model code depends on
 # ops alone and the pallas kernel module stays a lazy import).
+# layer= addresses one layer of a stacked pool in place.
 # paged_scatter(valid=) is the fused serving step's ragged-lane contract:
 # lanes >= valid[b] are geometry padding and land in the trash block.
 paged_scatter = jnp_impl.paged_scatter

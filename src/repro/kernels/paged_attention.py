@@ -1,11 +1,16 @@
 """Paged decode-attention Pallas TPU kernel (block-pool KV cache).
 
 Serving counterpart of :mod:`repro.kernels.flash_attention`: K/V live in a
-single ``(num_blocks, block_size, Hkv, D)`` pool per layer and each batch
-slot owns a *block table* — a row of physical block ids — instead of a
-contiguous cache stripe.  N slots seated on the same compressed ICL task
-point at the same prefix blocks, so the pool holds each distinct task's
-memory once (O(tasks), not O(slots)).
+single lane-merged ``(num_blocks, block_size, W)`` pool per layer — or one
+``(layers, num_blocks, block_size, W)`` stack for the scanned layers, read
+at a layer index — and each batch slot owns a *block table* — a row of
+physical block ids — instead of a contiguous cache stripe.  A pool row
+holds the ``Hkv`` heads of ``D`` lanes side by side; the serving cache
+pads it with zeros to ``W``, a multiple of 128 lanes, because the TPU lays
+out an array whose minor dim is off the 128-lane tile with another dim
+minor, which the kernel cannot read in place.  N slots seated on the same
+compressed ICL task point at the same prefix blocks, so the pool holds
+each distinct task's memory once (O(tasks), not O(slots)).
 
 TPU mapping
 -----------
@@ -26,14 +31,19 @@ program ``j-1`` computes.  Every block's last two dims satisfy Mosaic's
 * q        (Hq*Sp, D)   — the slot's last S query rows padded to Sp (a
   multiple of 8), head-major, so KV head ``g``'s query group is the
   contiguous row range ``[g*G*Sp, (g+1)*G*Sp)`` (GQA fold).
-* k/v pool (bs, Hkv*D)  — pool block ``table[b*nb + j]`` viewed as rows
-  of all KV heads side by side (a free reshape of the pool); head ``g``
-  is the lane range ``[g*D, (g+1)*D)``.  ``bs`` must be a multiple of 8.
+* k/v pool (bs, W)      — pool block ``table[b*nb + j]`` (of layer
+  ``layer`` in a stack) as the pool stores it: rows of all KV heads side
+  by side, head ``g`` at the lane range ``[g*D, (g+1)*D)``.  The pool is
+  kept in this layout, so the block is read where it lies; a
+  ``(..., Hkv, D)`` pool would have to be relaid out whole on every call.
+  ``bs`` must be a multiple of 8.
 * tables   (B*nb,) int32 SMEM — flattened so the index map stays 1-D.
 * lengths  (B,)    int32 SMEM — drives masking *and* the per-slot early
   skip: a block whose start position is at or past ``lengths[b]`` is
   skipped via ``pl.when`` (idle slots cost ~nothing; young slots pay only
   for blocks they filled).
+* layer    (1,)    int32 SMEM — stacked pools only: the layer whose
+  blocks the index map names.
 
 Unused table entries must still hold a *valid* pool index (the engine
 keeps them at 0, a reserved scratch block) — they are never read into the
@@ -50,6 +60,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.jnp_impl import merged_heads
 from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
 from repro.kernels.pltpu_compat import mxu_precision
 
@@ -57,13 +68,14 @@ NEG_INF = -1e30
 
 
 def _paged_kernel(
-    tbl_ref, len_ref,  # scalar prefetch (SMEM)
-    q_ref, k_ref, v_ref,  # inputs
-    o_ref,  # output
-    acc, m_scr, l_scr,  # scratch
-    *, scale: float, softcap: float, block_size: int, s_valid: int,
+    *refs, scale: float, softcap: float, block_size: int, s_valid: int,
     s_pad: int, kv_heads: int, group: int, head_dim: int, v_dim: int,
+    num_scalars: int,
 ):
+    # scalar prefetch (SMEM): tables, lengths[, layer]; then the inputs,
+    # the output and the scratch
+    _tbl_ref, len_ref = refs[:2]
+    q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr = refs[num_scalars:]
     b = pl.program_id(0)
     j = pl.program_id(1)
     nb = pl.num_programs(1)
@@ -120,21 +132,30 @@ def _paged_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("softcap", "scale", "interpret"),
+    static_argnames=("kv_heads", "softcap", "scale", "interpret"),
 )
 def paged_flash_decode(
-    q, k_pool, v_pool, *, block_tables, lengths, softcap=0.0, scale=None,
-    interpret=False,
+    q, k_pool, v_pool, *, block_tables, lengths, layer=None, kv_heads=None,
+    softcap=0.0, scale=None, interpret=False,
 ):
-    """(B,S,Hq,D) x pool (N,bs,Hkv,D) x tables (B,nb) -> (B,S,Hq,Dv).
+    """(B,S,Hq,D) x pool (N,bs,W) x tables (B,nb) -> (B,S,Hq,Dv).
 
-    Slot ``b`` attends causally within its logical cache positions
-    ``[0, lengths[b])``; logical block ``j`` resolves to pool block
-    ``block_tables[b, j]``.
+    The pools are lane-merged: row ``r`` of block ``n`` holds every KV
+    head side by side, head ``g`` at lanes ``[g*D, (g+1)*D)``; rows
+    zero-padded past the heads name ``kv_heads`` (see
+    :func:`repro.kernels.jnp_impl.merged_heads`).  With ``layer`` (an
+    int32 scalar, traced) they are per-layer stacks ``(layers, N, bs, W)``
+    and the kernel streams blocks of that layer only, so a stack carried
+    through the layer scan is read where it lies.  Slot ``b`` attends
+    causally within its logical cache positions ``[0, lengths[b])``;
+    logical block ``j`` resolves to pool block ``block_tables[b, j]``.
     """
     B, S, Hq, D = q.shape
-    N, bs, Hkv, Dv = v_pool.shape
-    assert Hq % Hkv == 0, (Hq, Hkv)
+    stacked = layer is not None
+    bs, W = k_pool.shape[-2:]
+    Wv = v_pool.shape[-1]
+    Hkv, Dv = merged_heads(k_pool, v_pool, D, kv_heads)
+    assert Hkv * D <= W and Hkv * Dv <= Wv and Hq % Hkv == 0, (W, Hkv, Hq)
     G = Hq // Hkv
     nb = block_tables.shape[1]
     if scale is None:
@@ -146,28 +167,38 @@ def paged_flash_decode(
     if Sp != S:
         q = jnp.pad(q, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
     q_rows = q.transpose(0, 2, 1, 3).reshape(B, Hq * Sp, D)
-    k_rows = k_pool.reshape(N, bs, Hkv * D)
-    v_rows = v_pool.reshape(N, bs, Hkv * Dv)
 
-    tables_flat = block_tables.astype(jnp.int32).reshape(-1)  # (B*nb,)
-    lengths = lengths.astype(jnp.int32)
+    scalars = [block_tables.astype(jnp.int32).reshape(-1),  # (B*nb,)
+               lengths.astype(jnp.int32)]
+    if stacked:
+        scalars.append(jnp.asarray(layer, jnp.int32).reshape(1))
+
+        def pool_spec(w):
+            return pl.BlockSpec(
+                (None, None, bs, w),
+                lambda b, j, tbl, lens, lyr: (lyr[0], tbl[b * nb + j], 0, 0))
+
+        def row_spec(rows, w):
+            return pl.BlockSpec((None, rows, w),
+                                lambda b, j, tbl, lens, lyr: (b, 0, 0))
+    else:
+        def pool_spec(w):
+            return pl.BlockSpec(
+                (None, bs, w), lambda b, j, tbl, lens: (tbl[b * nb + j], 0, 0))
+
+        def row_spec(rows, w):
+            return pl.BlockSpec((None, rows, w),
+                                lambda b, j, tbl, lens: (b, 0, 0))
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, softcap=softcap, block_size=bs,
-        s_valid=S, s_pad=Sp, kv_heads=Hkv, group=G, head_dim=D, v_dim=Dv)
+        s_valid=S, s_pad=Sp, kv_heads=Hkv, group=G, head_dim=D, v_dim=Dv,
+        num_scalars=len(scalars))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(B, nb),
-        in_specs=[
-            pl.BlockSpec((None, Hq * Sp, D),
-                         lambda b, j, tbl, lens: (b, 0, 0)),
-            pl.BlockSpec((None, bs, Hkv * D),
-                         lambda b, j, tbl, lens: (tbl[b * nb + j], 0, 0)),
-            pl.BlockSpec((None, bs, Hkv * Dv),
-                         lambda b, j, tbl, lens: (tbl[b * nb + j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (None, Hq * Sp, Dv), lambda b, j, tbl, lens: (b, 0, 0)),
+        in_specs=[row_spec(Hq * Sp, D), pool_spec(W), pool_spec(Wv)],
+        out_specs=row_spec(Hq * Sp, Dv),
         scratch_shapes=[
             pltpu.VMEM((Hq * Sp, Dv), jnp.float32),
             pltpu.VMEM((Hq * Sp, 1), jnp.float32),
@@ -182,7 +213,7 @@ def paged_flash_decode(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
         name="paged_flash_decode",
-    )(tables_flat, lengths, q_rows, k_rows, v_rows)
+    )(*scalars, q_rows, k_pool, v_pool)
     return out.reshape(B, Hq, Sp, Dv).transpose(0, 2, 1, 3)[:, :S]
 
 
@@ -202,19 +233,21 @@ def _dense_block_size(L: int, cap: int = 512):
 def dense_flash_decode(q, k, v, *, lengths, softcap=0.0, scale=None,
                        interpret=False):
     """Decode over dense per-slot caches ``k``/``v`` (B, L, Hkv, D) with
-    the paged kernel.  Slot ``b``'s stripe is read, without a copy, as
-    ``L // bs`` consecutive pool blocks (a free reshape to
-    ``(B * nb, bs, Hkv, D)`` and identity block tables), so every head of
-    a slot is served from one pass over its rows — no head-major
-    transpose of the cache on each step.  ``L`` must be a multiple of 8
-    (:func:`_dense_block_size` picks the rows per block)."""
+    the paged kernel.  Slot ``b``'s stripe is read as ``L // bs``
+    consecutive pool blocks (a reshape to ``(B * nb, bs, Hkv*D)`` and
+    identity block tables), so every head of a slot is served from one
+    pass over its rows — no head-major transpose of the cache on each
+    step.  The reshape merges the head axes into lanes, which on the TPU
+    relays the stripes out unless they are stored lane-merged already.
+    ``L`` must be a multiple of 8 (:func:`_dense_block_size` picks the
+    rows per block)."""
     B, L = k.shape[:2]
     bs = _dense_block_size(L)
     assert bs is not None, f"cache length {L} is not a multiple of 8"
     nb = L // bs
     tables = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
     return paged_flash_decode(
-        q, k.reshape(B * nb, bs, *k.shape[2:]),
-        v.reshape(B * nb, bs, *v.shape[2:]), block_tables=tables,
-        lengths=lengths, softcap=softcap, scale=scale, interpret=interpret)
+        q, k.reshape(B * nb, bs, -1), v.reshape(B * nb, bs, -1),
+        block_tables=tables, lengths=lengths, softcap=softcap, scale=scale,
+        interpret=interpret)
 

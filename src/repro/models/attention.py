@@ -120,6 +120,7 @@ def apply_attention(
     block_tables=None,
     lane_valid=None,
     mesh=None,
+    layer=None,
     impl: str = "auto",
 ):
     """Returns (out (B,S,D), new_cache_or_None).  ``mesh`` (tensor-parallel
@@ -136,8 +137,13 @@ def apply_attention(
     could have written.
 
     With ``block_tables`` (B, nb) the cache entries are *paged*: ``k``/``v``
-    are shared ``(num_blocks, block_size, Hkv, hd)`` pools and slot ``b``'s
-    cache position ``p`` lives at ``(block_tables[b, p // bs], p % bs)``.
+    are shared lane-merged ``(num_blocks, block_size, W)`` pools (see
+    :func:`init_paged_attn_cache`) and slot ``b``'s cache position ``p``
+    lives at ``(block_tables[b, p // bs], p % bs)``.  With ``layer`` (a
+    traced int, the layer scan's index) they are the whole stack
+    ``(layers, num_blocks, block_size, W)``: this layer writes its rows
+    into it at ``layer`` and reads only its own blocks, and the updated
+    stack is returned, so no layer's pool is ever sliced out or copied.
     Decode requires the per-slot length vector; prefill continues behind
     the seated blocks (static ``cache_index`` base, as in the dense path).
     """
@@ -177,12 +183,15 @@ def apply_attention(
             # every slot seated on the task but stored once)
             assert jnp.ndim(cache_index) == 1, "paged decode needs (slots,) lengths"
             k_pool = ops.paged_scatter(cache["k"], k_new, block_tables,
-                                       cache_index, valid=lane_valid)
+                                       cache_index, valid=lane_valid,
+                                       layer=layer)
             v_pool = ops.paged_scatter(cache["v"], v_new, block_tables,
-                                       cache_index, valid=lane_valid)
+                                       cache_index, valid=lane_valid,
+                                       layer=layer)
             out = ops.paged_decode_attention(
                 q, k_pool, v_pool, block_tables=block_tables,
-                lengths=cache_index + S, softcap=softcap, scale=scale,
+                lengths=cache_index + S, layer=layer,
+                kv_heads=cfg.num_kv_heads, softcap=softcap, scale=scale,
                 impl=impl, mesh=mesh)
             return out.reshape(B, S, -1) @ p["wo"], {"k": k_pool, "v": v_pool}
         if jnp.ndim(cache_index) == 1:
@@ -229,14 +238,16 @@ def apply_attention(
         # (compressed memory or an earlier prefill segment) — attend to
         # them as a fully-visible prefix.  Static start only.
         if block_tables is not None:
-            bs = cache["k"].shape[1]
+            bs = cache["k"].shape[-2]
             nbt = -(-cache_index // bs)  # ceil: blocks covering the base
             blk = block_tables[:, :nbt]
+            lanes = cfg.num_kv_heads * cfg.hd
             prefix = {
-                "k": ops.paged_gather(cache["k"], blk)[:, :cache_index]
-                .astype(x.dtype),
-                "v": ops.paged_gather(cache["v"], blk)[:, :cache_index]
-                .astype(x.dtype),
+                key: ops.paged_gather(cache[key], blk, layer)
+                [:, :cache_index, :lanes]
+                .reshape(B, cache_index, cfg.num_kv_heads, cfg.hd)
+                .astype(x.dtype)
+                for key in ("k", "v")
             }
         else:
             prefix = {"k": cache["k"][:, :cache_index].astype(x.dtype),
@@ -258,8 +269,10 @@ def apply_attention(
         if block_tables is not None:
             starts = jnp.full((B,), start, jnp.int32)
             new_cache = {
-                "k": ops.paged_scatter(cache["k"], k, block_tables, starts),
-                "v": ops.paged_scatter(cache["v"], v, block_tables, starts),
+                "k": ops.paged_scatter(cache["k"], k, block_tables, starts,
+                                       layer=layer),
+                "v": ops.paged_scatter(cache["v"], v, block_tables, starts,
+                                       layer=layer),
             }
         else:
             new_cache = {
@@ -281,10 +294,20 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
 
 def init_paged_attn_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                           dtype) -> dict:
+    """One layer's K/V block pools, lane-merged: ``(num_blocks, block_size,
+    W)``, head ``g`` at lanes ``[g*hd, (g+1)*hd)`` of every row and zeros
+    from ``Hkv*hd`` up to ``W``, the next multiple of 128 lanes — the
+    layout the paged decode kernel reads a block in, so no step relays the
+    pool out.  (With rows off the 128-lane tile, e.g. 320 lanes, the TPU
+    would lay the pool out with the blocks minor and every step would
+    relay it out on entry and back on exit; the tiled layout pads such
+    rows to 384 lanes in memory anyway.)  Writes and reads reshape
+    ``(..., Hkv, hd)`` rows to and from it on the rows they touch only."""
     nkv, hd = cfg.num_kv_heads, cfg.hd
+    lanes = -(-nkv * hd // 128) * 128
     return {
-        "k": jnp.zeros((num_blocks, block_size, nkv, hd), dtype),
-        "v": jnp.zeros((num_blocks, block_size, nkv, hd), dtype),
+        "k": jnp.zeros((num_blocks, block_size, lanes), dtype),
+        "v": jnp.zeros((num_blocks, block_size, lanes), dtype),
     }
 
 

@@ -63,6 +63,7 @@ def apply_block(
     block_tables=None,
     lane_valid=None,
     mesh=None,
+    layer=None,
     encoder_out=None,
     memcom: Optional[dict] = None,
     impl: str = "auto",
@@ -71,7 +72,9 @@ def apply_block(
 
     ``block_tables`` routes the attention/MLA cache entries through the
     paged block-pool layout; recurrent (conv/ssm) and cross-attention
-    entries stay per-slot dense either way.
+    entries stay per-slot dense either way.  ``layer`` (the layer scan's
+    index) says the pool entries are the scan's whole per-layer stacks,
+    updated in place at that layer.
 
     ``lane_valid`` (fused serving step) masks ragged decode lanes in the
     attention/MLA cache writes.  Recurrent mixers cannot honour it (the
@@ -91,7 +94,7 @@ def apply_block(
             p["attn"], cfg, hn, positions=positions, mask_offset=mask_offset,
             prefix=prefix, cache=self_cache, cache_index=cache_index,
             decode=decode, block_tables=block_tables, lane_valid=lane_valid,
-            mesh=mesh, impl=impl)
+            mesh=mesh, layer=layer, impl=impl)
         if c is not None:
             new_cache.update(c)
     elif desc.mixer == "mla":
@@ -102,7 +105,7 @@ def apply_block(
             p["attn"], cfg, hn, positions=positions, mask_offset=mask_offset,
             prefix=prefix, cache=self_cache, cache_index=cache_index,
             decode=decode, block_tables=block_tables, lane_valid=lane_valid,
-            mesh=mesh, impl=impl)
+            mesh=mesh, layer=layer, impl=impl)
         if c is not None:
             new_cache.update(c)
     else:  # mamba

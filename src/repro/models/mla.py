@@ -85,6 +85,7 @@ def apply_mla(
     block_tables=None,
     lane_valid=None,
     mesh=None,
+    layer=None,
     impl: str = "auto",
 ):
     """Returns (out, new_cache_or_None).  Cache = {"ckv", "kr"}.
@@ -105,7 +106,9 @@ def apply_mla(
     With ``block_tables`` the latent cache is paged: ``ckv``/``kr`` are
     ``(num_blocks, block_size, ...)`` pools indexed per slot through the
     table — the absorbed-MQA decode walks blocks instead of a contiguous
-    stripe, and prefix blocks shared across slots are stored once.
+    stripe, and prefix blocks shared across slots are stored once.  With
+    ``layer`` they are the layer scan's whole stacks, written at ``layer``
+    in place (see :func:`repro.models.attention.apply_attention`).
     """
     m = cfg.mla
     B, S, _ = x.shape
@@ -121,10 +124,11 @@ def apply_mla(
         if block_tables is not None:
             assert per_slot, "paged decode needs (slots,) lengths"
             ckv_cache = ops.paged_scatter(cache["ckv"], ckv_new, block_tables,
-                                          cache_index, valid=lane_valid)
+                                          cache_index, valid=lane_valid,
+                                          layer=layer)
             kr_cache = ops.paged_scatter(cache["kr"], kr_new[:, :, 0, :],
                                          block_tables, cache_index,
-                                         valid=lane_valid)
+                                         valid=lane_valid, layer=layer)
         elif per_slot:
             from repro.models.attention import scatter_rows
 
@@ -146,8 +150,11 @@ def apply_mla(
         # the whole pool is concatenated — same O(cache) data movement as
         # dense; splitting the latent/rope dot inside the kernel would
         # remove it entirely)
-        k_eff = jnp.concatenate([ckv_cache, kr_cache], axis=-1)[:, :, None, :]
-        v_eff = ckv_cache[:, :, None, :]
+        ckv_l, kr_l = ckv_cache, kr_cache
+        if layer is not None:  # this layer's pools out of the scan's stacks
+            ckv_l, kr_l = ckv_cache[layer], kr_cache[layer]
+        k_eff = jnp.concatenate([ckv_l, kr_l], axis=-1)[:, :, None, :]
+        v_eff = ckv_l[:, :, None, :]
         if block_tables is not None:
             o_lat = ops.paged_decode_attention(
                 q_eff, k_eff.astype(q_eff.dtype), v_eff.astype(q_eff.dtype),
@@ -174,12 +181,12 @@ def apply_mla(
             and isinstance(cache_index, int) and cache_index > 0):
         # prefill continuation over already-seated latent slots
         if block_tables is not None:
-            bs_blk = cache["ckv"].shape[1]
+            bs_blk = cache["ckv"].shape[-2]
             nbt = -(-cache_index // bs_blk)
             blk = block_tables[:, :nbt]
             prefix = {
-                "ckv": ops.paged_gather(cache["ckv"], blk)[:, :cache_index],
-                "kr": ops.paged_gather(cache["kr"], blk)[:, :cache_index],
+                key: ops.paged_gather(cache[key], blk, layer)[:, :cache_index]
+                for key in ("ckv", "kr")
             }
         else:
             prefix = {"ckv": cache["ckv"][:, :cache_index],
@@ -217,9 +224,9 @@ def apply_mla(
             starts = jnp.full((B,), start, jnp.int32)
             new_cache = {
                 "ckv": ops.paged_scatter(cache["ckv"], ckv, block_tables,
-                                         starts),
+                                         starts, layer=layer),
                 "kr": ops.paged_scatter(cache["kr"], k_rope[:, :, 0, :],
-                                        block_tables, starts),
+                                        block_tables, starts, layer=layer),
             }
         else:
             new_cache = {

@@ -13,7 +13,8 @@ all share the *Layerwise* layout::
 
 so the three stacks (which are copies of the same architecture) can
 exchange them directly, and the period part rides through ``jax.lax.scan``
-as xs/ys with a leading ``repeats`` dim.
+as xs/ys with a leading ``repeats`` dim — except a paged cache's block
+pools, which the scan carries whole and updates in place layer by layer.
 
 See docs/ARCHITECTURE.md for the layout's batch-axis conventions and the
 per-layer O^i prefix formats each mixer family exchanges.
@@ -46,6 +47,10 @@ from repro.models.attention import apply_attention, init_attention
 from repro.models.param import ParamBuilder
 from repro.sharding.ctx import constrain
 from repro.utils.rng import Keys
+
+#: cache leaves that are block pools under block tables (attention K/V,
+#: MLA latents); every other leaf is per-slot
+POOL_KEYS = ("k", "v", "ckv", "kr")
 
 
 # ---------------------------------------------------------------------------
@@ -234,7 +239,7 @@ def forward(
     memx_params = memcom["params"] if memcom is not None else None
     memx_src = memcom["src"] if memcom is not None else None
 
-    def one_block(p, desc, h, *, lpre, lcache, lmemx, lsrc):
+    def one_block(p, desc, h, *, lpre, lcache, lmemx, lsrc, layer=None):
         mem = None
         if lmemx is not None and desc.mixer in ("attn", "mla"):
             mem = {"params": lmemx, "src": lsrc}
@@ -242,7 +247,7 @@ def forward(
             p, cfg, desc, h, positions=positions, mask_offset=mask_offset,
             prefix=lpre, cache=lcache, cache_index=cache_index, decode=decode,
             block_tables=block_tables, lane_valid=lane_valid, mesh=mesh,
-            encoder_out=encoder_out, memcom=mem, impl=impl)
+            layer=layer, encoder_out=encoder_out, memcom=mem, impl=impl)
 
     for i, desc in enumerate(cfg.layout.prefix):
         if capture_hiddens:
@@ -264,39 +269,62 @@ def forward(
 
     period_caches, period_caps, period_omegas = {}, {}, {}
     if cfg.layout.repeats:
+        # Under block tables the period's block pools ride in the carry
+        # as whole (repeats, ...) stacks that each layer updates in place
+        # at its own index; sliced through xs/ys instead, every layer's
+        # pool would be copied out and stacked back on every step.
+        # Per-slot leaves (conv/ssm/cross) stay xs/ys.
+        pools, rest = {}, _lw_period(cache)
+        if block_tables is not None:
+            pools = {key: {k: x for k, x in c.items() if k in POOL_KEYS}
+                     for key, c in rest.items()}
+            pools = {key: c for key, c in pools.items() if c}
+            rest = {key: {k: x for k, x in c.items() if k not in POOL_KEYS}
+                    for key, c in rest.items()}
         xs = (
             params["period"],
             _lw_period(prefix),
-            _lw_period(cache),
+            rest,
             _lw_period(memx_params),
             _lw_period(memx_src),
+            jnp.arange(cfg.layout.repeats, dtype=jnp.int32),
         )
 
         def body(carry, xs):
-            h, aux = carry
-            lp, lpre, lcache, lmemx, lsrc = xs
-            new_caches, caps, omegas = {}, {}, {}
+            h, aux, pools = carry
+            lp, lpre, lcache, lmemx, lsrc, layer = xs
+            new_caches, new_pools, caps, omegas = {}, {}, {}, {}
             for j, desc in enumerate(cfg.layout.period):
                 key = f"l{j}"
                 if capture_hiddens:
                     caps[key] = h
+                lc = lcache.get(key) if lcache else None
+                if key in pools:
+                    lc = {**(lc or {}), **pools[key]}
                 h, c, a = one_block(
                     lp[key], desc, h,
                     lpre=lpre.get(key) if lpre else None,
-                    lcache=lcache.get(key) if lcache else None,
+                    lcache=lc,
                     lmemx=lmemx.get(key) if lmemx else None,
-                    lsrc=lsrc.get(key) if lsrc else None)
+                    lsrc=lsrc.get(key) if lsrc else None,
+                    layer=layer if key in pools else None)
                 h = constrain(h)
                 aux = aux + a["moe_loss"]
                 if c is not None:
+                    if key in pools:
+                        new_pools[key] = {k: c[k] for k in pools[key]}
+                        c = {k: x for k, x in c.items() if k not in pools[key]}
                     new_caches[key] = c
                 if a["omega"] is not None:
                     omegas[key] = a["omega"]
-            return (h, aux), (new_caches, caps, omegas)
+            return (h, aux, new_pools), (new_caches, caps, omegas)
 
         scan_body = jax.checkpoint(body, policy=remat_policy) if remat else body
-        (h, aux_loss), (period_caches, period_caps, period_omegas) = jax.lax.scan(
-            scan_body, (h, aux_loss), xs, unroll=True if unroll else 1)
+        (h, aux_loss, pools), (period_caches, period_caps, period_omegas) = \
+            jax.lax.scan(scan_body, (h, aux_loss, pools), xs,
+                         unroll=True if unroll else 1)
+        for key, c in pools.items():
+            period_caches[key] = {**period_caches[key], **c}
 
     hn = apply_norm(params["final_norm"], cfg, h)
     out = hn
@@ -342,8 +370,9 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     """Block-pool KV cache: attention/MLA leaves are a single
     ``(num_blocks, block_size, ...)`` physical pool per layer (period
     section stacks a pool per repeat on the leading axis, as always),
-    addressed through per-slot block tables; recurrent conv/ssm and
-    cross-attention leaves keep the per-slot ``(slots, ...)`` layout."""
+    addressed through per-slot block tables; attention K/V rows are
+    lane-merged ``(Hkv*hd,)``.  Recurrent conv/ssm and cross-attention
+    leaves keep the per-slot ``(slots, ...)`` layout."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     prefix = [
         init_block_paged_cache(cfg, desc, num_blocks, block_size, slots, dtype)

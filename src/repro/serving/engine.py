@@ -31,7 +31,9 @@ Two KV layouts (``kv_layout=``):
   layer plus per-slot block tables; slots seated on the same task share
   its ref-counted prefix blocks (prefix memory O(tasks)), with
   copy-on-write only for a partially-filled tail block, private blocks
-  freed on refill, and admission gated on free blocks.
+  freed on refill, and admission gated on free blocks.  Where the pool
+  lives on an accelerator the step programs take the cache donated and
+  update the pool in place.
 
 With ``host_capacity=``/``disk_dir=`` set, the HBM store is fronted by
 a :class:`~repro.serving.tiers.TieredPrefixStore`: evictions demote the
@@ -172,6 +174,19 @@ def _bucket(n: int, cap: int) -> int:
     slot's remaining cache space.  A handful of buckets ⇒ a handful of
     prefill compilations, ever."""
     return max(1, min(pow2_bucket(n, 8), cap))
+
+
+def _donates_cache(kv_layout: str, mesh) -> bool:
+    """Do the step programs take the cache donated?  Donation lets a step
+    update the paged pool in place (the layer scan carries it whole, see
+    ``transformer.forward``), so no step allocates or copies a second
+    pool: the paged layout donates wherever its pool lives on an
+    accelerator.  On the host CPU the steps keep the functional contract,
+    so a caller there may hold on to a cache it passed in.  The dense
+    layout's stripes still ride the layer scan as xs/ys, where donation
+    would only add a copy of the whole stack."""
+    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    return kv_layout == "paged" and device.platform != "cpu"
 
 
 def _lane_capable(cfg: ModelConfig) -> bool:
@@ -411,16 +426,19 @@ class ServingEngine:
             self.tiers.tier_stats = self.metrics.group(
                 "serving_prefix_tiers", self.tiers.tier_stats,
                 help="tiered prefix cache counter")
-        # KV stripes/pools split by head on the "model" axis, recurrent
-        # state by channel/head; everything non-divisible replicates
-        self.cache = shard_cache(self.cache, mesh, self.rules)
+        # KV stripes/pools split by head on the "model" axis (a paged
+        # pool's lanes by whole heads), recurrent state by channel/head;
+        # everything non-divisible replicates
+        pool_rows = ((cfg.num_kv_heads, cfg.hd) if kv_layout == "paged"
+                     else None)
+        self.cache = shard_cache(self.cache, mesh, self.rules, pool_rows)
         rules = self.rules
 
         def pin(cache):
             # hold the step *outputs* to the seeded cache layout — left to
             # itself GSPMD drifts (e.g. re-sharding KV on head_dim), and
             # every later step then pays a reshard of the whole pool
-            return constrain_cache(cache, mesh, rules)
+            return constrain_cache(cache, mesh, rules, pool_rows)
 
         def prefill_fn(params, cache, tokens, slot, base):
             row = _slice_slot(cache, slot)
@@ -459,18 +477,23 @@ class ServingEngine:
             fn.__name__ = fn.__qualname__ = step.__name__
             return fn
 
+        self.donate_cache = _donates_cache(kv_layout, mesh)
+        donate = (1,) if self.donate_cache else ()
         # base is static: prefill-continuation slices the seated cache
         # region with a python int (one trace per (bucket, base) pair);
         # slot, lengths and block tables are traced, so admission/refill
         # (and block re-mapping) never recompile
         if kv_layout == "paged":
-            self._prefill = jax.jit(paged_prefill_fn, static_argnums=(5,))
-            self._decode = jax.jit(paged_decode_fn)
-            self._decode_greedy = jax.jit(greedy(paged_decode_fn))
+            prefill, decode, base_arg = paged_prefill_fn, paged_decode_fn, 5
         else:
-            self._prefill = jax.jit(prefill_fn, static_argnums=(4,))
-            self._decode = jax.jit(decode_fn)
-            self._decode_greedy = jax.jit(greedy(decode_fn))
+            prefill, decode, base_arg = prefill_fn, decode_fn, 4
+        self._prefill = jax.jit(prefill, static_argnums=(base_arg,),
+                                donate_argnums=donate)
+        # one-shot scoring (persist=False) must leave the cache it reads
+        self._prefill_keep = (jax.jit(prefill, static_argnums=(base_arg,))
+                              if donate else self._prefill)
+        self._decode = jax.jit(decode, donate_argnums=donate)
+        self._decode_greedy = jax.jit(greedy(decode), donate_argnums=donate)
         self._pin = pin
 
         # ---- fused step + speculative decoding ----
@@ -665,6 +688,7 @@ class ServingEngine:
         causality hides everything an invalid lane could touch."""
         cfg, impl, mesh = self.cfg, self.impl, self.mesh
         pin = self._pin
+        donate = (1,) if self.donate_cache else ()
         body = (self.compiler.chunk_body(comp_geom[0])
                 if comp_geom is not None else None)
 
@@ -682,7 +706,7 @@ class ServingEngine:
                     comp_out = body(compressor, src_cache, chunk)
                 return out, pin(aux["cache"]), comp_out
 
-            return jax.jit(run)
+            return jax.jit(run, donate_argnums=donate)
 
         return self._program("fused", (W, bool(greedy), comp_geom), make)
 
@@ -1793,13 +1817,15 @@ class ServingEngine:
             self._charge("prefill_token", width)
             padded = np.zeros((1, width), np.int32)
             padded[0, :n] = tokens
+            # a donating prefill would consume the cache it reads
+            prefill = self._prefill if persist else self._prefill_keep
             if self.kv_layout == "paged":
                 snap = None
                 if not persist:
                     snap = (self.alloc.snapshot(), self.tables[slot].copy(),
                             list(self._slot_blocks[slot]))
                 self._prepare_prefill(slot, base, width)
-                logits, new_cache = self._prefill(
+                logits, new_cache = prefill(
                     self.params, self.cache, jnp.asarray(padded),
                     jnp.int32(slot), jnp.asarray(self.tables[slot]), base)
                 if snap is not None:
@@ -1811,7 +1837,7 @@ class ServingEngine:
                     self.tables[slot] = snap[1]
                     self._slot_blocks[slot] = snap[2]
             else:
-                logits, new_cache = self._prefill(
+                logits, new_cache = prefill(
                     self.params, self.cache, jnp.asarray(padded),
                     jnp.int32(slot), base)
             if persist:

@@ -31,9 +31,8 @@ from repro.config import ModelConfig
 from repro.kernels import ops
 from repro.models.attention import project_kv
 from repro.models.mla import _latent  # shared latent-cache constructor
+from repro.models.transformer import POOL_KEYS as _KV_KEYS
 from repro.serving.block_pool import BlockAllocator
-
-_KV_KEYS = ("k", "v", "ckv", "kr")
 
 
 def materialize_prefix(target_params, cfg: ModelConfig, prefix):
@@ -303,17 +302,18 @@ def write_prefix_row_to_blocks(cache, row, block_ids: List[int]):
     ids = jnp.asarray(block_ids, jnp.int32)[None, :]  # (1, nbt)
     zero = jnp.zeros((1,), jnp.int32)
 
+    def scatter(pool, new):
+        # rows given as (m, Hkv, hd) land lane-merged in an attention pool
+        return ops.paged_scatter(pool, new[None], ids, zero)
+
     def write(c, p, axis):
         c = dict(c)
         for key in _KV_KEYS:
             if key in p:
                 if axis == 0:  # prefix section: pool (N, bs, ...), row (m, ...)
-                    c[key] = ops.paged_scatter(c[key], p[key][None], ids, zero)
+                    c[key] = scatter(c[key], p[key])
                 else:  # period: pool (repeats, N, bs, ...), row (repeats, m, ...)
-                    c[key] = jax.vmap(
-                        lambda pool, new: ops.paged_scatter(pool, new[None],
-                                                            ids, zero)
-                    )(c[key], p[key])
+                    c[key] = jax.vmap(scatter)(c[key], p[key])
         return c
 
     return _map_rowwise(cache, row, write)

@@ -297,6 +297,7 @@ class TieredPrefixStore:
         cache = self._cache_ref()
         base = int(entry["base_len"])
         ids = jnp.asarray(list(entry["blocks"]), jnp.int32)
+        cfg = self.hbm.cfg
 
         def take(c, _p, axis):
             out = {}
@@ -311,6 +312,10 @@ class TieredPrefixStore:
                         g = jnp.take(c[key], ids, axis=1)
                         g = g.reshape(g.shape[:1] + (-1,) + g.shape[3:])
                         g = g[:, :base]
+                    if key in ("k", "v"):  # lane-merged rows -> (Hkv, hd)
+                        lanes = cfg.num_kv_heads * cfg.hd
+                        g = g[..., :lanes].reshape(
+                            g.shape[:-1] + (cfg.num_kv_heads, cfg.hd))
                     out[key] = np.asarray(g)
             return out
 

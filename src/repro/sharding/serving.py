@@ -7,12 +7,13 @@ tables, materialized compressed prefixes — none of which carries logical
 axes.  This module derives those placements from the one invariant the
 whole serving design preserves: **attention splits by head**.
 
-* ``k``/``v`` (dense ``(slots, L, Hkv, hd)``, paged ``(N, bs, Hkv, hd)``,
-  cross ``ck``/``cv``) shard the head axis on the mesh "model" axis and
-  replicate everything else — slots, positions and block structure are
-  identical on every shard, so the host-side block tables and per-slot
-  length vectors stay plain replicated numpy and the control plane never
-  becomes mesh-aware.
+* ``k``/``v`` (dense ``(slots, L, Hkv, hd)``, cross ``ck``/``cv``)
+  shard the head axis on the mesh "model" axis, paged pools
+  ``(N, bs, W)`` their lane axis by whole heads (where the heads fill the
+  row), and replicate everything else — slots, positions and block
+  structure are identical on every shard, so the host-side block tables
+  and per-slot length vectors stay plain replicated numpy and the control
+  plane never becomes mesh-aware.
 * MLA ``ckv``/``kr`` latents have *no* head axis (that is the point of
   the absorbed decode) and stay replicated — at kv_lora_rank floats per
   token they are the cheap leaf.
@@ -74,7 +75,19 @@ def _leaf_key(path) -> Optional[str]:
 
 
 def leaf_spec(key: Optional[str], ndim: int, shape: Tuple[int, ...],
-              mesh: Mesh, rules: Rules) -> P:
+              mesh: Mesh, rules: Rules,
+              pool_rows: Optional[Tuple[int, int]] = None) -> P:
+    """PartitionSpec of one leaf.  ``pool_rows = (Hkv, hd)`` marks
+    ``k``/``v`` as lane-merged paged pools whose rows hold ``Hkv`` heads
+    of ``hd`` lanes (zero-padded to the 128-lane tile): their lane axis
+    splits by whole heads where the heads divide the "model" axis and fill
+    the row, and replicates otherwise."""
+    if pool_rows is not None and key in ("k", "v"):
+        heads, hd = pool_rows
+        if heads % model_axis_size(mesh) or shape[-1] != heads * hd:
+            return P()
+        return spec_for(shape, (None,) * (ndim - 1) + ("kv_heads",), mesh,
+                        rules)
     trailing = _TRAILING.get(key, ())
     if ndim < len(trailing):
         return P()
@@ -92,27 +105,34 @@ def leaf_sharding(key: Optional[str], arr, mesh: Mesh,
         mesh, leaf_spec(key, arr.ndim, tuple(arr.shape), mesh, rules))
 
 
-def cache_shardings(tree, mesh: Mesh, rules: Rules = BASELINE_RULES):
+def cache_shardings(tree, mesh: Mesh, rules: Rules = BASELINE_RULES,
+                    pool_rows: Optional[Tuple[int, int]] = None):
     """NamedSharding pytree for any Layerwise cache / prefix / store-row
     tree, keyed by leaf name (``k``/``v``/``ckv``/…).  Works for dense and
-    paged layouts alike — the head axis is trailing in both."""
+    paged layouts alike — the head axis is trailing in both; a paged
+    cache passes ``pool_rows`` (its KV heads and head width), since its
+    ``k``/``v`` pools keep the heads merged into the lane axis."""
 
     def one(path, x):
         return NamedSharding(
-            mesh, leaf_spec(_leaf_key(path), x.ndim, x.shape, mesh, rules))
+            mesh, leaf_spec(_leaf_key(path), x.ndim, x.shape, mesh, rules,
+                            pool_rows))
 
     return jax.tree_util.tree_map_with_path(one, tree)
 
 
-def shard_cache(tree, mesh: Optional[Mesh], rules: Rules = BASELINE_RULES):
+def shard_cache(tree, mesh: Optional[Mesh], rules: Rules = BASELINE_RULES,
+                pool_rows: Optional[Tuple[int, int]] = None):
     """Place a cache/prefix tree on the mesh (no-op without a mesh)."""
     if mesh is None:
         return tree
-    return jax.device_put(tree, cache_shardings(tree, mesh, rules))
+    return jax.device_put(tree, cache_shardings(tree, mesh, rules,
+                                                pool_rows))
 
 
 def constrain_cache(tree, mesh: Optional[Mesh],
-                    rules: Rules = BASELINE_RULES):
+                    rules: Rules = BASELINE_RULES,
+                    pool_rows: Optional[Tuple[int, int]] = None):
     """``with_sharding_constraint`` a cache/prefix tree inside jit — pins
     freshly materialized prefixes to the pool layout so the compile →
     store.put handoff never round-trips through a replicated gather."""
@@ -122,7 +142,8 @@ def constrain_cache(tree, mesh: Optional[Mesh],
     def one(path, x):
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, leaf_spec(_leaf_key(path), x.ndim,
-                                             x.shape, mesh, rules)))
+                                             x.shape, mesh, rules,
+                                             pool_rows)))
 
     return jax.tree_util.tree_map_with_path(one, tree)
 
@@ -148,26 +169,30 @@ def _shard_map(f, mesh: Mesh, in_specs, out_specs):
 
 
 def shard_map_heads(f, mesh: Mesh, head_args, replicated_args: int,
-                    head_axis: int = 2, out_ndims=4):
+                    head_axis: int = 2, out_ndims=4, head_axes=None):
     """Wrap a head-parallel kernel in shard_map: the first ``head_args``
     operands split their ``head_axis`` over "model" (batch, positions and
     block structure replicated), the remaining ``replicated_args``
     operands (positions, lengths, block tables) are replicated on every
     shard, and the outputs — ``out_ndims`` gives each one's rank, an int
     for a single output or a tuple — are head-split like the inputs.
+    ``head_axes`` gives each head operand its own split axis (negative
+    counts from the end: a lane-merged pool splits its last axis).
 
     This is what makes the *pallas* kernels mesh-runnable: unlike jnp ops
     they have no GSPMD partitioning rule, so each shard must run the
     kernel on its own head slice explicitly.
     """
-    def head_spec(ndim):
+    def head_spec(ndim, axis=head_axis):
         entries = [None] * ndim
-        entries[head_axis] = "model"
+        entries[axis] = "model"
         return P(*entries)
 
     def wrapped(*args):
         assert len(args) == head_args + replicated_args
-        in_specs = tuple(head_spec(a.ndim) for a in args[:head_args]) + \
+        axes = head_axes or (head_axis,) * head_args
+        in_specs = tuple(head_spec(a.ndim, ax) for a, ax in
+                         zip(args[:head_args], axes)) + \
             tuple(P() for _ in args[head_args:])
         out_specs = (head_spec(out_ndims) if isinstance(out_ndims, int)
                      else tuple(head_spec(n) for n in out_ndims))

@@ -1,8 +1,10 @@
 """Paged KV-cache tests: block allocator, ops-level paged/dense decode
 parity over ragged lengths (jnp + pallas-interpret), engine parity,
-copy-on-write isolation, admission gating, and PrefixStore LRU eviction
-with the seated-refcount guard."""
+copy-on-write isolation, admission gating, PrefixStore LRU eviction
+with the seated-refcount guard, and in-place pool updates (stacked pools
+read and written at a layer index, the donated cache)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -11,6 +13,7 @@ from repro.configs import get_smoke_config
 from repro.core import memcom
 from repro.kernels import ops
 from repro.models import transformer as tfm
+from repro.serving import engine as engine_mod
 from repro.serving import (
     BlockAllocationError,
     BlockAllocator,
@@ -524,3 +527,139 @@ def test_admission_need_is_exact_at_block_boundary(setup, rng):
     tight.add_prefix("task", mat)
     with pytest.raises(OutOfBlocksError):
         tight.serve([Request(tokens=prompt, max_new=2, prefix="task")])
+
+
+# ---------------------------------------------------------------------------
+# In-place pool updates: stacked pools read at a layer index, donated cache
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("S", [1, 3])
+@pytest.mark.parametrize("hq,hkv", [(4, 2), (15, 5), (4, 1)])
+def test_paged_flash_decode_stacked_layer_matches_slice(rng, hq, hkv, S):
+    """The kernel on a stacked lane-merged pool and a layer index reads
+    exactly that layer's blocks: the same result as the 3-D call on the
+    layer's slice (interpret mode, GQA and MQA folds)."""
+    from repro.kernels import paged_attention as pa
+
+    R, N, bs, D, B, nb = 3, 13, 8, 16, 3, 4
+    kst = jnp.asarray(rng.standard_normal((R, N, bs, hkv * D)), jnp.float32)
+    vst = jnp.asarray(rng.standard_normal((R, N, bs, hkv * D)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, S, hq, D)), jnp.float32)
+    tables = jnp.asarray(rng.integers(1, N, (B, nb)), jnp.int32)
+    lengths = jnp.asarray([S, 17, nb * bs], jnp.int32)
+    for layer in range(R):
+        want = pa.paged_flash_decode(q, kst[layer], vst[layer],
+                                     block_tables=tables, lengths=lengths,
+                                     interpret=True)
+        got = pa.paged_flash_decode(q, kst, vst, block_tables=tables,
+                                    lengths=lengths, layer=jnp.int32(layer),
+                                    interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        jnp_got = ops.paged_decode_attention(
+            q, kst, vst, block_tables=tables, lengths=lengths,
+            layer=jnp.int32(layer), impl="jnp")
+        np.testing.assert_allclose(np.asarray(jnp_got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_paged_decode_ignores_row_padding(rng, impl):
+    """Rows padded past their heads to the 128-lane tile (the serving
+    cache's pools) read like rows that hold the heads exactly, whatever
+    the padding lanes hold."""
+    N, bs, hq, hkv, D, B, nb = 9, 8, 15, 5, 16, 3, 3
+    k = jnp.asarray(rng.standard_normal((N, bs, hkv * D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((N, bs, hkv * D)), jnp.float32)
+    junk = jnp.asarray(rng.standard_normal((N, bs, 128 - hkv * D)),
+                       jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, 1, hq, D)), jnp.float32)
+    tables = jnp.asarray(rng.integers(1, N, (B, nb)), jnp.int32)
+    lengths = jnp.asarray([1, 10, nb * bs], jnp.int32)
+    want = ops.paged_decode_attention(q, k, v, block_tables=tables,
+                                      lengths=lengths, impl="dense")
+    got = ops.paged_decode_attention(
+        q, jnp.concatenate([k, junk], -1), jnp.concatenate([v, junk], -1),
+        block_tables=tables, lengths=lengths, kv_heads=hkv, impl=impl)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_paged_scatter_gather_one_layer_of_a_stack(rng):
+    """A write at ``layer`` lands in that layer's blocks only (rows given
+    as (Hkv, hd) land lane-merged); a gather at ``layer`` reads them
+    back."""
+    R, N, bs, H, D = 3, 9, 4, 2, 8
+    stack = jnp.asarray(rng.standard_normal((R, N, bs, H * D)), jnp.float32)
+    tables = jnp.asarray([[3, 5], [7, 1]], jnp.int32)
+    starts = jnp.asarray([2, 5], jnp.int32)
+    new = jnp.asarray(rng.standard_normal((2, 3, H, D)), jnp.float32)
+    out = ops.paged_scatter(stack, new, tables, starts, layer=jnp.int32(1))
+    want = np.asarray(stack).copy()
+    want[1] = np.asarray(ops.paged_scatter(stack[1], new, tables, starts))
+    np.testing.assert_array_equal(np.asarray(out), want)
+    view = np.asarray(ops.paged_gather(out, tables, jnp.int32(1)))
+    for b in range(2):
+        s = int(starts[b])
+        np.testing.assert_array_equal(
+            view[b, s:s + 3], np.asarray(new[b]).reshape(3, H * D))
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_donated_paged_engine_matches_functional(setup, rng, fused,
+                                                 monkeypatch):
+    """An engine that donates its cache to the step programs serves the
+    same tokens as one that keeps the functional contract, through
+    ragged prompts, a shared partial prefix block (COW) and refills — and
+    the fused step's chunked joins when ``fused``."""
+    cfg, params, _ = setup
+    m = cfg.memcom.num_memory_tokens
+    mat = _materialize(setup, rng)
+    prompts = [rng.integers(4, cfg.vocab_size, n).astype(np.int32)
+               for n in (5, 11, 3, 7)]
+    outs = []
+    for donate in (False, True):
+        # the host CPU keeps the functional contract unless told otherwise
+        monkeypatch.setattr(engine_mod, "_donates_cache", lambda *_: donate)
+        eng = ServingEngine(cfg, params, slots=2, max_len=m + 24,
+                            kv_layout="paged", block_size=16,
+                            fused_step=fused, fused_chunk_tokens=4)
+        assert eng.donate_cache is donate
+        eng.add_prefix("task", mat)
+        reqs = [Request(tokens=p, max_new=4, prefix="task") for p in prompts]
+        out = eng.serve(reqs)
+        outs.append([out[r.uid].tolist() for r in reqs])
+    assert outs[0] == outs[1]
+
+
+def test_scoring_prefill_keeps_the_donated_cache(setup, rng, monkeypatch):
+    """``persist=False`` scoring reads the pool through a non-donating
+    twin of the prefill: after it, the engine's cache is still readable
+    and unchanged, and serving goes on from it as if it never ran."""
+    cfg, params, _ = setup
+    mat = _materialize(setup, rng)
+    m = cfg.memcom.num_memory_tokens
+    query = rng.integers(4, cfg.vocab_size, 6).astype(np.int32)
+    labels = np.arange(4, 12, dtype=np.int32)
+    prompt = rng.integers(4, cfg.vocab_size, 5).astype(np.int32)
+    monkeypatch.setattr(engine_mod, "_donates_cache", lambda *_: True)
+    eng = ServingEngine(cfg, params, slots=2, max_len=m + 24,
+                        kv_layout="paged", block_size=8)
+    monkeypatch.undo()
+    assert eng.donate_cache
+    eng.add_prefix("task", mat)
+    eng.seat_prefix(0, "task")
+    before = [np.asarray(x) for x in jax.tree.leaves(eng.cache)]
+    pred = eng.score_labels(np.empty((0,), np.int32), query, labels)
+    assert pred in labels
+    after = jax.tree.leaves(eng.cache)
+    assert not any(x.is_deleted() for x in after)
+    for a, b in zip(before, after):
+        np.testing.assert_array_equal(a, np.asarray(b))
+    got = eng.serve([Request(tokens=prompt, max_new=4, prefix="task")])
+    fresh = ServingEngine(cfg, params, slots=2, max_len=m + 24,
+                          kv_layout="paged", block_size=8)
+    fresh.add_prefix("task", mat)
+    want = fresh.serve([Request(tokens=prompt, max_new=4, prefix="task")])
+    np.testing.assert_array_equal(next(iter(got.values())),
+                                  next(iter(want.values())))

@@ -14,6 +14,7 @@ imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -78,9 +79,86 @@ def test_paged_decode_compiles(one_chip):
                                      lengths=lengths)
 
     text = _compile(fn, one_chip, ((B, 1, HQ, HD), BF16),
-                    ((N, bs, HKV, HD), BF16), ((N, bs, HKV, HD), BF16),
+                    ((N, bs, HKV * HD), BF16), ((N, bs, HKV * HD), BF16),
                     ((B, nb), I32), ((B,), I32))
     assert "tpu_custom_call" in text
+
+
+def test_paged_decode_stacked_compiles(one_chip):
+    """The kernel reads layer ``layer`` of a stacked pool in place: the
+    layer index is a scalar-prefetch operand, no slice of the stack is
+    materialized for the call."""
+    B, bs, nb, N, R = 8, 16, 36, 300, 4
+
+    def fn(q, k_pool, v_pool, tables, lengths, layer):
+        return pa.paged_flash_decode(q, k_pool, v_pool, block_tables=tables,
+                                     lengths=lengths, layer=layer)
+
+    text = _compile(fn, one_chip, ((B, 1, HQ, HD), BF16),
+                    ((R, N, bs, HKV * HD), BF16), ((R, N, bs, HKV * HD), BF16),
+                    ((B, nb), I32), ((B,), I32), ((), I32))
+    assert "tpu_custom_call" in text
+    assert not re.search(rf"= \S*\[{N},{bs},\S* (copy|dynamic-slice)\(", text)
+
+
+def test_paged_step_programs_update_the_pool_in_place(one_chip, monkeypatch):
+    """The serving engine's paged decode and prefill step programs at
+    smollm-360m widths (2 layers, a pool of 2,241 blocks of 16, 32 slots
+    of 37 table columns) update the KV pool in place: the cache is
+    donated (its buffers alias the outputs), nothing copies a pool, and
+    no slice or scatter produces one layer's pool — only the kernel's
+    block reads and the row writes touch it.  Arguments and results take
+    the chip's default layouts, as the engine's arrays do."""
+    from repro.config import LayerDesc, LayerLayout, ModelConfig
+    from repro.kernels import ops
+    from repro.models import transformer as tfm
+    from repro.serving import ServingEngine
+    from repro.serving import engine as engine_mod
+
+    # build the programs as for the chip: the Pallas kernel lowered for
+    # it (not the CPU interpreter) and the cache donated
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(engine_mod, "_donates_cache", lambda *_: True)
+    N, bs, slots, cols = 2241, 16, 32, 37
+    cfg = ModelConfig(
+        name="smollm-360m-2l", family="dense",
+        layout=LayerLayout.uniform(LayerDesc("attn", "dense"), 2),
+        d_model=960, num_heads=HQ, num_kv_heads=HKV, d_ff=2560,
+        vocab_size=49152, rope_theta=1e5, tie_embeddings=True, max_seq=8192,
+        dtype="bfloat16")
+    eng = ServingEngine(cfg, tfm.init_params(cfg, 0), slots=slots,
+                        max_len=cols * bs, kv_layout="paged", block_size=bs,
+                        num_blocks=N, impl="pallas")
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    params, cache = shapes(eng.params), shapes(eng.cache)
+    # a copy of any pool or stack, or a slice or scatter that yields one
+    # layer's pool; the row scatter into the carried (layers, ...) stack
+    # is in place (copy insertion would put a copy before it otherwise)
+    pool = re.compile(
+        rf"= \S*\[\S*{N},{bs},\S* (copy|copy-start)\("
+        rf"|= \S*\[(1,)?{N},{bs},\S* (scatter|dynamic-slice)\(")
+    programs = {  # jitted step and its arguments (the prefill's base last)
+        "decode": (eng._decode_greedy,
+                   (params, cache, i32(slots, 1), i32(slots),
+                    i32(slots, cols))),
+        "prefill": (eng._prefill,
+                    (params, cache, i32(1, 64), i32(), i32(cols), 512)),
+    }
+    for name, (step, args) in programs.items():
+        text = step.lower(*args).compile().as_text()
+        header = text.split("\n", 1)[0]
+        aliases = header.split("entry_computation_layout", 1)[0]
+        assert aliases.count("may-alias") == len(jax.tree.leaves(cache)), (
+            name, aliases)
+        assert not pool.search(text), (name, pool.search(text).group(0))
+        assert "tpu_custom_call" in text, name
 
 
 def test_dense_decode_compiles(one_chip):
