@@ -197,8 +197,10 @@ def _unrecord(eng) -> None:
 
 def end_to_end(served: Served) -> Dict[str, float]:
     """TTFT and TPOT 95th percentiles over all requests of the window
-    (exact, numpy's linear interpolation)."""
+    (exact, numpy's linear interpolation), and the completed requests per
+    second of the window's ``serve`` call."""
     log = [served.log[u] for u in served.uids]
+    done = sum(1 for r in log if r["finish_s"] is not None)
     ttft = [r["first_token_s"] - r["arrival_s"] for r in log
             if r["first_token_s"] is not None]
     tpot = [(r["finish_s"] - r["first_token_s"]) / (r["tokens"] - 1)
@@ -208,6 +210,8 @@ def end_to_end(served: Served) -> Dict[str, float]:
         out["ttft_p95_ms"] = 1e3 * float(np.percentile(ttft, 95))
     if tpot:
         out["tpot_p95_ms"] = 1e3 * float(np.percentile(tpot, 95))
+    if done:
+        out["queries_per_s"] = done / served.seconds
     return out
 
 

@@ -40,15 +40,36 @@ def _altered_token(run):
     eng._decode_greedy = decode
 
 
+@contextmanager
+def _no_kv_write():
+    """``paged_scatter`` writes no row: it returns the pool it was given."""
+    from repro.kernels import ops
+
+    saved = ops.paged_scatter
+    ops.paged_scatter = lambda pool, *_a, **_kw: pool
+    try:
+        yield
+    finally:
+        ops.paged_scatter = saved
+
+
 def _unchanged_state(run):
+    """The decode step is built again, donating its cache as the engine's
+    own does, from the same function traced with a ``paged_scatter`` that
+    writes no row: its new tokens' keys and values never reach the pool,
+    and it hands back the pool it was given."""
+    import jax
+
     eng = run.engine
-    step = eng._decode_greedy
+    step = eng._decode_greedy.__wrapped__
 
-    def decode(params, cache, *rest):
-        ids, _ = step(params, cache, *rest)
-        return ids, cache
+    def decode(*args):
+        with _no_kv_write():
+            return step(*args)
 
-    eng._decode_greedy = decode
+    decode.__name__ = decode.__qualname__ = step.__name__
+    eng._decode_greedy = jax.jit(
+        decode, donate_argnums=(1,) if eng.donate_cache else ())
 
 
 def _wrong_task(run):
@@ -69,7 +90,8 @@ def altered_token():
 
 
 def unchanged_state():
-    """A decode step that returns the KV cache it was given."""
+    """A decode step whose KV writes write nothing: the pool comes back
+    as it went in."""
     return _after_setup(_unchanged_state)
 
 

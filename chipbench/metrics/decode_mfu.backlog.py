@@ -1,0 +1,8 @@
+"""Model operations of the decode step programs over their device time
+under a backlog, as a share of the chip's bf16 peak (%)."""
+
+from chipbench import readers
+
+
+def read(ctx):
+    return readers.program_mfu(ctx, "decode")

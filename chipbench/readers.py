@@ -53,3 +53,24 @@ def program_mfu(ctx, program: str):
         work = sum(flops.prefill(ctx.shape, w, b)["model_flops"]
                    for w, b in calls)
     return 100.0 * work / t / ctx.peak["bf16_flops_per_s"]
+
+
+def prefill_share(ctx):
+    """Device time of the prefill programs over that of the prefill and
+    decode programs, in percent: how much of the step work is batch-1
+    admission."""
+    pre = ctx.trace.program_seconds("prefill")
+    dec = ctx.trace.program_seconds("decode")
+    if not pre or not dec:
+        return None
+    return 100.0 * pre / (pre + dec)
+
+
+def occupancy(ctx):
+    """Mean live slots per decode step: the engine counts one generated
+    token per live slot in each batched decode step."""
+    eng = (ctx.served.stats or {}).get("engine") or {}
+    steps = eng.get("decode_steps")
+    if not steps:
+        return None
+    return eng["tokens_generated"] / steps

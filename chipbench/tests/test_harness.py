@@ -35,10 +35,11 @@ MIX = {"name": "tinymix",
 PEAK = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 
 
-def run_tiny(fault=None, seed=2**31 + 99, control=False):
-    cell = spec.Cell(name="tiny.open", chips=1, config=TINY, mix=MIX,
+def run_tiny(fault=None, seed=2**31 + 99, control=False, mix=MIX):
+    cell = spec.Cell(name="tiny.open", chips=1, config=TINY, mix=mix,
                      end_to_end=[{"name": n, "unit": u} for n, u in
                                  (("ttft_p95_ms", "ms"), ("tpot_p95_ms", "ms"),
+                                  ("queries_per_s", "q/s"),
                                   ("setup_s", "s"))],
                      per_layer=[], adapter=adapter, reference=reference)
     with fault() if fault else contextlib.nullcontext():
@@ -52,10 +53,22 @@ def test_sound_run_is_correct():
     res = run_tiny()
     assert res["correct"], res["checks"]
     assert res["failed"] == 0 and res["attempted"] == 20
-    assert set(res["metrics"]) == {"ttft_p95_ms", "tpot_p95_ms", "setup_s"}
+    assert set(res["metrics"]) == {"ttft_p95_ms", "tpot_p95_ms",
+                                   "queries_per_s", "setup_s"}
     assert res["window_compiles"] == 0
     assert list(res)[-1] == "checks"
     assert res["device"]["platform"] == "cpu"
+
+
+def test_backlog_run_is_correct():
+    """The whole window queued at once: every request completes, and the
+    rate is all of them over the serve call."""
+    res = run_tiny(mix=dict(MIX, arrivals={"process": "backlog",
+                                           "rate_per_s": 40.0}))
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] == 20
+    assert res["metrics"]["queries_per_s"]["value"] > 0
+    assert res["window_compiles"] == 0
 
 
 @pytest.mark.parametrize("fault", sorted(faults.FAULTS))

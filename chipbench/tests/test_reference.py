@@ -37,9 +37,18 @@ def program_logits(conf, seed, shots, query):
     return np.asarray(logits[0], np.float32)
 
 
-@pytest.mark.parametrize("tied", [True, False])
-def test_reference_matches_program(tied):
-    conf = dict(TINY, tie_word_embeddings=tied)
+# Mistral's own shape at a tiny width: untied head, RoPE theta 1e6, heads
+# of 128 lanes, four query heads to each key/value head
+MISTRAL_SHAPE = dict(TINY, hidden_size=512, intermediate_size=192,
+                     num_attention_heads=4, num_key_value_heads=1,
+                     head_dim=128, rope_theta=1e6, tie_word_embeddings=False)
+
+
+@pytest.mark.parametrize("conf", [dict(TINY, tie_word_embeddings=True),
+                                  dict(TINY, tie_word_embeddings=False),
+                                  MISTRAL_SHAPE],
+                         ids=["True", "False", "mistral_shape"])
+def test_reference_matches_program(conf):
     rng = np.random.default_rng(0)
     shots = rng.integers(0, conf["vocab_size"], 40).astype(np.int32)
     query = rng.integers(0, conf["vocab_size"], 6).astype(np.int32)

@@ -65,3 +65,28 @@ def test_unknown_device_kind_is_an_error():
     assert spec.load_peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
     with pytest.raises(KeyError):
         spec.load_peaks("cpu")
+
+
+def test_mistral_backlog_cell_resolves():
+    """Mistral-7B v0.3 at its published widths, one stage of 8 of its 32
+    layers; only the depth differs from the source."""
+    cell = spec.load_cell("mistral7b.backlog")
+    c = cell.config
+    assert (c["hidden_size"], c["intermediate_size"]) == (4096, 14336)
+    assert (c["num_attention_heads"], c["num_key_value_heads"]) == (32, 8)
+    assert (c["vocab_size"], c["rope_theta"]) == (32768, 1e6)
+    assert not c["tie_word_embeddings"] and c["num_memory_tokens"] == 768
+    assert c["num_hidden_layers"] == 8 and c["published"] == {
+        "num_hidden_layers": 32}
+    assert c["limits"]["max_logit_gap"] > 0
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    entry = {x["name"]: x for x in bench["configs"]}["mistral7b"]
+    assert entry["reduced"] == ["num_hidden_layers"]
+    assert cell.mix["arrivals"]["process"] == "backlog"
+    assert [m["name"] for m in cell.end_to_end] == ["queries_per_s",
+                                                    "setup_s"]
+    assert {m["name"] for m in cell.per_layer} == {
+        "prefill_share.backlog", "occupancy.backlog", "decode_mfu.backlog",
+        "prefill_mfu.backlog"}
+    assert {m["moves"] for m in cell.per_layer} == {"queries_per_s"}
+    assert cell.adapter.program_config(c).hd == 128

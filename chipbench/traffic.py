@@ -2,9 +2,15 @@
 
 A mix names a catalog of tasks (how many, their many-shot lengths, and
 the Zipf skew of task choice; every task is compressed before the
-window), the query and answer lengths, the arrivals (open-loop Poisson
-at a rate), and the engine's geometry (slots, block size).  Token ids
-are drawn from the configuration's own vocabulary.
+window), the query and answer lengths, the arrivals, and the engine's
+geometry (slots, block size).  Token ids are drawn from the
+configuration's own vocabulary.  Arrivals are one of two processes, each
+with ``rate_per_s`` requests per second of the window:
+
+* ``poisson``: open loop, exponential gaps at that rate;
+* ``backlog``: the window's whole batch is queued at once, every request
+  due at 0 (a dataset labelled in bulk); the serving loop drains it as
+  fast as it can.
 
 Sizes, task popularity counts and inter-arrival gaps are stratified
 quantiles of the stated distributions.  The timeline of the work (when
@@ -97,11 +103,14 @@ def generate(mix: dict, vocab: int, seed: int, seconds: float) -> Traffic:
                                            - q["max_new"][0])
         - 0.5).astype(int))
     a = mix["arrivals"]
-    if a["process"] != "poisson":
+    if a["process"] == "poisson":
+        gaps = _exp_quantiles(n, 1.0 / a["rate_per_s"])
+        due = np.maximum(np.cumsum(timeline.permutation(gaps))
+                         - gaps.mean(), 0.0)
+    elif a["process"] == "backlog":
+        due = np.zeros(n)
+    else:
         raise ValueError(f"unknown arrival process {a['process']!r}")
-    gaps = _exp_quantiles(n, 1.0 / a["rate_per_s"])
-    due = np.maximum(np.cumsum(timeline.permutation(gaps)) - gaps.mean(),
-                     0.0)
     queries = [Query(task=int(tasks[i]),
                      tokens=rng.integers(0, vocab, int(lens[i]),
                                          dtype=np.int32),
