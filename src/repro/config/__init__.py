@@ -9,6 +9,7 @@ from repro.config.base import (
     ModelConfig,
     ShapeSpec,
     SHAPES,
+    YarnScaling,
 )
 
 __all__ = [
@@ -22,4 +23,5 @@ __all__ = [
     "ModelConfig",
     "ShapeSpec",
     "SHAPES",
+    "YarnScaling",
 ]

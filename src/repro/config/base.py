@@ -66,7 +66,10 @@ class MoEConfig:
     expert_d_ff: int
     num_shared_experts: int = 0
     shared_d_ff: int = 0  # d_ff of the shared expert(s); defaults to expert_d_ff
-    capacity_factor: float = 1.25
+    # tokens an expert takes per dispatch, as a factor of its even share
+    # (N·k/E); None sizes every held expert's buffer for all N tokens, so
+    # nothing can overflow (dropless: what the serving engine runs)
+    capacity_factor: Optional[float] = 1.25
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
     router_dtype: str = "float32"
@@ -77,9 +80,22 @@ class MoEConfig:
     # single-group (global-sort) baseline; the launcher sets G to the
     # data-shard count (see EXPERIMENTS.md §Perf hillclimb 1).
     dispatch_groups: int = 1
+    # top-k gates renormalised to sum 1 (False: the softmax probabilities
+    # themselves, as DeepSeek-V2 publishes)
+    norm_topk_prob: bool = True
+    # expert share (expert parallelism): the router scores all
+    # ``num_experts``; this device holds experts [expert_offset,
+    # expert_offset + held_experts) and returns their part of the layer
+    # (plus the shared experts).  0 holds them all.
+    held_experts: int = 0
+    expert_offset: int = 0
 
     def shared_ff(self) -> int:
         return self.shared_d_ff or self.expert_d_ff
+
+    @property
+    def held(self) -> int:
+        return self.held_experts or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -103,7 +119,7 @@ class MLAConfig:
     """DeepSeek-V2 Multi-head Latent Attention."""
 
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536  # None: queries straight from x (wq)
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -111,6 +127,27 @@ class MLAConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Lanes of one latent cache row: ``[ckv | k_rope]``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv:2309.00071), with DeepSeek-V2's
+    ``rope_scaling`` keys: frequencies interpolated by ``factor`` below
+    ``beta_slow`` rotations over the original context, kept above
+    ``beta_fast``, with a linear ramp between; attention logits scaled by
+    ``mscale(factor, mscale_all_dim) ** 2``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -160,6 +197,7 @@ class ModelConfig:
     memcom: Optional[MemComConfig] = None
 
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnScaling] = None
     mrope_sections: Tuple[int, ...] = ()  # Qwen2-VL M-RoPE (t, h, w)
     pos_embed: str = "rope"  # "rope" | "learned" | "none"
     norm_type: str = "rmsnorm"  # "rmsnorm" | "layernorm"
@@ -229,7 +267,10 @@ class ModelConfig:
             return n
         if desc.mixer == "mla":
             m = self.mla
-            n = d * m.q_lora_rank + m.q_lora_rank * self.num_heads * m.qk_head_dim
+            if m.q_lora_rank is None:
+                n = d * self.num_heads * m.qk_head_dim
+            else:
+                n = d * m.q_lora_rank + m.q_lora_rank * self.num_heads * m.qk_head_dim
             n += d * (m.kv_lora_rank + m.qk_rope_head_dim)
             n += m.kv_lora_rank * self.num_heads * (m.qk_nope_head_dim + m.v_head_dim)
             n += self.num_heads * m.v_head_dim * d
@@ -251,7 +292,7 @@ class ModelConfig:
             return 0
         if desc.mlp == "moe":
             m = self.moe
-            n = m.num_experts * 3 * d * m.expert_d_ff
+            n = m.held * 3 * d * m.expert_d_ff
             n += m.num_shared_experts * 3 * d * m.shared_ff()
             n += d * m.num_experts
             return n

@@ -1,6 +1,7 @@
 """Architecture registry: ``get_config(name)`` / ``--arch <id>``.
 
-Ten assigned architectures + the paper's own two (Gemma2-2B, Mistral-7B).
+Ten assigned architectures, DeepSeek-V2-Lite, and the paper's own two
+(Gemma2-2B, Mistral-7B).
 Each module exposes ``config()`` (full published config) and
 ``smoke_config()`` (reduced same-family config for CPU smoke tests).
 """
@@ -19,6 +20,7 @@ ARCH_IDS = (
     "stablelm-1.6b",
     "granite-moe-3b-a800m",
     "deepseek-v2-236b",
+    "deepseek-v2-lite",
     "mamba2-370m",
     "qwen2-vl-2b",
     "jamba-1.5-large-398b",

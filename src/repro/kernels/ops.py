@@ -292,6 +292,48 @@ def paged_decode_attention(q, k_pool, v_pool, *, block_tables, lengths,
         kv_heads=None if exact else Hkv)
 
 
+def paged_latent_attention(q, pool, *, block_tables, lengths, v_dim,
+                           layer=None, scale=None, impl="auto", mesh=None):
+    """Absorbed-MLA decode over a paged latent pool.
+
+    ``q`` (B, S, H, K) holds each slot's last S query rows folded into the
+    latent space; ``pool`` is ``(num_blocks, block_size, W)`` latent rows
+    (``W >= K``, lanes past the row zero), or with ``layer`` a per-layer
+    stack of them.  Keys are a row's first ``K`` lanes and values its
+    first ``v_dim``: all H heads share the one latent head.  Masking and
+    tables as in :func:`paged_decode_attention`.  The pallas path is
+    :func:`repro.kernels.paged_attention.paged_latent_decode` (replicated
+    on every device of a mesh: one latent head offers nothing to split);
+    the others materialize each slot's view."""
+    B, S, H, K = q.shape
+    lead = 0 if layer is None else 1
+    L = block_tables.shape[1] * pool.shape[lead + 1]
+    impl = _resolve(impl, S * L <= 256 * 256)
+    if impl == "pallas":
+        from repro.kernels import paged_attention  # lazy: TPU-targeted
+
+        def run(q, pool, block_tables, lengths, *lyr):
+            return paged_attention.paged_latent_decode(
+                q, pool, block_tables=block_tables, lengths=lengths,
+                v_dim=v_dim, layer=lyr[0] if lyr else None, scale=scale,
+                interpret=_interpret())
+
+        rep = (q, pool, block_tables, lengths)
+        if layer is not None:
+            rep += (jnp.asarray(layer, jnp.int32),)
+        return _per_device(mesh, run, (), rep)
+    view = jnp_impl.paged_gather(pool, block_tables, layer)  # (B, L, W)
+    k = view[:, :, None, :K].astype(q.dtype)
+    v = view[:, :, None, :v_dim].astype(q.dtype)
+    if impl == "dense":
+        kv_pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (B, L))
+        q_pos = lengths[:, None] - S + jnp.arange(S, dtype=jnp.int32)[None, :]
+        return ref.attention_ref(q, k, v, q_pos=q_pos, kv_pos=kv_pos,
+                                 causal=True, scale=scale)
+    return jnp_impl.decode_attention_lengths(q, k, v, lengths=lengths,
+                                             scale=scale)
+
+
 def attention_with_prefix(q, k_self, v_self, k_pre, v_pre, *, pre_pos=None,
                           offset=None, softcap=0.0, scale=None, impl="auto",
                           mesh=None):
@@ -358,14 +400,32 @@ def memcom_xattn(q, k, v, *, scale=None, impl="auto", mesh=None):
 # ---------------------------------------------------------------------------
 
 
-def gmm(x, w, *, impl="auto"):
-    """(E,C,D) x (E,D,F) -> (E,C,F) per-expert matmul."""
+def gmm_uses_kernel(impl="auto") -> bool:
+    """Whether :func:`gmm` runs the Pallas kernel for ``impl``."""
+    return _resolve(impl, small=False) == "pallas"
+
+
+def gmm_rows(C: int) -> int:
+    """Rows a kernel call without counts computes for each expert of a
+    ``C``-row buffer (``C`` padded to whole row tiles)."""
+    from repro.kernels import moe_gmm
+
+    bc = moe_gmm._row_block(C)
+    return -(-C // bc) * bc
+
+
+def gmm(x, w, *, counts=None, block_c=None, impl="auto"):
+    """(E,C,D) x (E,D,F) -> (E,C,F) per-expert matmul.  ``counts`` (E,):
+    only each expert's first ``counts[e]`` rows are needed; the kernel
+    then computes those alone (in row tiles of ``block_c``) and leaves the
+    other rows undefined, the oracles compute every row."""
     small = x.shape[0] * x.shape[1] * x.shape[2] <= 64 * 64 * 64
     impl = _resolve(impl, small)
     if impl == "pallas":
         from repro.kernels import moe_gmm
 
-        return moe_gmm.gmm(x, w, interpret=_interpret())
+        kw = {} if block_c is None else {"block_c": block_c}
+        return moe_gmm.gmm(x, w, counts, interpret=_interpret(), **kw)
     return ref.gmm_ref(x, w)
 
 

@@ -251,3 +251,145 @@ def dense_flash_decode(q, k, v, *, lengths, softcap=0.0, scale=None,
         block_tables=tables, lengths=lengths, softcap=softcap, scale=scale,
         interpret=interpret)
 
+
+def _latent_kernel(
+    *refs, scale: float, block_size: int, blocks: int, s_valid: int,
+    heads: int, v_dim: int, num_scalars: int,
+):
+    # scalar prefetch (SMEM): tables, lengths[, layer]; then the queries,
+    # ``blocks`` pool blocks, the output and the scratch
+    len_ref = refs[1]
+    q_ref = refs[num_scalars]
+    pool_refs = refs[num_scalars + 1:num_scalars + 1 + blocks]
+    o_ref, acc, m_scr, l_scr = refs[num_scalars + 1 + blocks:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    nb = pl.num_programs(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+
+    length = len_ref[b]
+    span = blocks * block_size
+    start = j * span
+
+    @pl.when(start < length)
+    def _compute():
+        rows = q_ref.shape[0]  # every head's query rows: one latent head
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, span), 0)
+        tok = sum((row >= t * heads).astype(jnp.int32)  # row // heads
+                  for t in range(1, s_valid)) if s_valid > 1 else 0
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+        valid = (row < s_valid * heads) & (pos <= length - s_valid + tok)
+        prec = mxu_precision(q_ref.dtype)
+        q = q_ref[...]  # (rows, W)
+        kv = jnp.concatenate([r[...] for r in pool_refs], axis=0)  # (span, W)
+        logits = jax.lax.dot_general(
+            q, kv.astype(q.dtype), (((1,), (1,)), ((), ())),
+            precision=prec, preferred_element_type=jnp.float32) * scale
+        logits = jnp.where(valid, logits, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        v = kv[:, :v_dim]  # the value is the key row's first lanes
+        acc[...] = acc[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == nb - 1)
+    def _finish():
+        l = l_scr[...]
+        out = acc[...] / jnp.maximum(l, 1e-37)
+        o_ref[...] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("v_dim", "scale", "blocks", "interpret"))
+def paged_latent_decode(
+    q, pool, *, block_tables, lengths, v_dim, layer=None, scale=None,
+    blocks=8, interpret=False,
+):
+    """Absorbed-MLA decode: (B,S,H,K) x latent pool (N,bs,W) -> (B,S,H,v_dim).
+
+    Every query head of a slot reads the same latent rows: the key is a
+    row's first ``K`` lanes (``[ckv | k_rope]``; the pool's pad lanes
+    past ``K`` are zero and meet zero query lanes) and the value its first
+    ``v_dim`` lanes (``ckv``), so one DMA of a pool block feeds both and
+    the pool is read where it lies.  With ``layer`` (traced int32) the
+    pool is a per-layer stack ``(layers, N, bs, W)``.  Each grid step
+    streams ``blocks`` table columns of one slot (the table is padded to
+    a multiple with the reserved block 0, masked by ``lengths``), so
+    short blocks do not pay a grid step each.  Masking and the per-slot
+    skip are those of :func:`paged_flash_decode`.
+    """
+    B, S, H, K = q.shape
+    stacked = layer is not None
+    bs, W = pool.shape[-2:]
+    assert K <= W and v_dim <= K, (K, W, v_dim)
+    nb = block_tables.shape[1]
+    P = max(1, min(blocks, nb))
+    nbp = -(-nb // P) * P
+    tables = block_tables.astype(jnp.int32)
+    if nbp != nb:
+        tables = jnp.pad(tables, ((0, 0), (0, nbp - nb)))
+    if scale is None:
+        scale = K**-0.5
+    # token-major query rows, S·H of them padded to a multiple of 8 as a
+    # whole (16 heads of one decode token fill two sublane tiles)
+    R = -(-(S * H) // 8) * 8
+    q_rows = jnp.pad(q.reshape(B, S * H, K), ((0, 0), (0, R - S * H),
+                                              (0, W - K)))
+
+    scalars = [tables.reshape(-1), lengths.astype(jnp.int32)]
+    if stacked:
+        scalars.append(jnp.asarray(layer, jnp.int32).reshape(1))
+
+        def pool_spec(t):
+            return pl.BlockSpec(
+                (None, None, bs, W),
+                lambda b, j, tbl, lens, lyr: (lyr[0], tbl[(b * nbp) + j * P + t],
+                                              0, 0))
+
+        def row_spec(rows, w):
+            return pl.BlockSpec((None, rows, w),
+                                lambda b, j, tbl, lens, lyr: (b, 0, 0))
+    else:
+        def pool_spec(t):
+            return pl.BlockSpec(
+                (None, bs, W),
+                lambda b, j, tbl, lens: (tbl[(b * nbp) + j * P + t], 0, 0))
+
+        def row_spec(rows, w):
+            return pl.BlockSpec((None, rows, w),
+                                lambda b, j, tbl, lens: (b, 0, 0))
+
+    kernel = functools.partial(
+        _latent_kernel, scale=scale, block_size=bs, blocks=P, s_valid=S,
+        heads=H, v_dim=v_dim, num_scalars=len(scalars))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(B, nbp // P),
+        in_specs=[row_spec(R, W)] + [pool_spec(t) for t in range(P)],
+        out_specs=row_spec(R, v_dim),
+        scratch_shapes=[
+            pltpu.VMEM((R, v_dim), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, R, v_dim), q.dtype),
+        compiler_params=_CompilerParams(
+            dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
+        interpret=interpret,
+        name="mla_latent_decode",
+    )(*scalars, q_rows, *([pool] * P))
+    return out[:, :S * H].reshape(B, S, H, v_dim)

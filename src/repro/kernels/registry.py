@@ -23,6 +23,9 @@ REFERENCE_TWINS = {
     "moe_gmm:gmm": "ref:gmm_ref",
     # paged decode attention <-> streaming jnp block-table walk
     "paged_attention:paged_flash_decode": "jnp_impl:paged_decode_attention_lengths",
+    # absorbed-MLA decode over a paged latent pool (one latent head; the
+    # jnp path gathers each slot's view and runs the length-masked decode)
+    "paged_attention:paged_latent_decode": "jnp_impl:decode_attention_lengths",
     # dense-stripe decode (the paged kernel over a reshaped cache)
     "paged_attention:dense_flash_decode": "jnp_impl:decode_attention_lengths",
     # mamba2 state-space chunked scan

@@ -135,7 +135,7 @@ def cache_shardings(cfg: ModelConfig, mesh: Mesh, cache_abstract):
         bdim = off
         if shape[bdim] % n_data == 0:
             entries[bdim] = daxes
-        if name in ("k", "v", "ck", "cv", "ckv", "kr"):
+        if name in ("k", "v", "ck", "cv", "lat"):
             sdim = off + 1  # cache sequence
             if shape[sdim] % n_model == 0:
                 entries[sdim] = "model"

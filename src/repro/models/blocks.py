@@ -68,7 +68,10 @@ def apply_block(
     memcom: Optional[dict] = None,
     impl: str = "auto",
 ):
-    """Returns (h, new_cache_or_None, aux{moe_loss, omega}).
+    """Returns (h, new_cache_or_None, aux{moe_loss, moe_rows, omega}):
+    ``moe_rows`` the row counts of :func:`repro.models.moe.apply_moe`
+    (held experts + 1 + tokens,) int32, for an MoE block (None
+    otherwise).
 
     ``block_tables`` routes the attention/MLA cache entries through the
     paged block-pool layout; recurrent (conv/ssm) and cross-attention
@@ -81,7 +84,7 @@ def apply_block(
     SSM state would advance over garbage lanes regardless), which is why
     the engine gates the fused path to attention/MLA-only layouts.
     """
-    aux = {"moe_loss": jnp.float32(0.0), "omega": None}
+    aux = {"moe_loss": jnp.float32(0.0), "moe_rows": None, "omega": None}
     new_cache = {} if cache is not None else None
 
     # ---- sequence mixer ----
@@ -99,8 +102,8 @@ def apply_block(
             new_cache.update(c)
     elif desc.mixer == "mla":
         self_cache = None
-        if cache is not None and "ckv" in cache:
-            self_cache = {"ckv": cache["ckv"], "kr": cache["kr"]}
+        if cache is not None and "lat" in cache:
+            self_cache = {"lat": cache["lat"]}
         o, c = apply_mla(
             p["attn"], cfg, hn, positions=positions, mask_offset=mask_offset,
             prefix=prefix, cache=self_cache, cache_index=cache_index,
@@ -144,8 +147,8 @@ def apply_block(
     if desc.mlp != "none":
         hn = apply_norm(p["norm2"], cfg, h)
         if desc.mlp == "moe":
-            o, moe_loss = apply_moe(p["moe"], cfg, hn, impl=impl)
-            aux["moe_loss"] = moe_loss
+            o, aux["moe_loss"], aux["moe_rows"] = apply_moe(
+                p["moe"], cfg, hn, impl=impl)
         else:
             o = apply_mlp(p["mlp"], cfg, hn)
         h = h + o

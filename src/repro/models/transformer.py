@@ -50,7 +50,7 @@ from repro.utils.rng import Keys
 
 #: cache leaves that are block pools under block tables (attention K/V,
 #: MLA latents); every other leaf is per-slot
-POOL_KEYS = ("k", "v", "ckv", "kr")
+POOL_KEYS = ("k", "v", "lat")
 
 
 # ---------------------------------------------------------------------------
@@ -198,6 +198,8 @@ def forward(
 
     aux keys: "cache" (Layerwise), "hiddens" (Layerwise, layer inputs H^i),
     "omega" (Layerwise, Memory-LLM compressed reps O^i), "moe_loss",
+    "moe_rows" ((MoE layers, held experts + 1 + B·S) int32 row counts,
+    :func:`repro.models.moe.split_rows`, or None without MoE layers),
     "encoder_out".
     """
     if embeds is None:
@@ -234,7 +236,7 @@ def forward(
 
     aux_loss = jnp.float32(0.0)
     n_prefix = len(cfg.layout.prefix)
-    caps_p, omegas_p, caches_p = [], [], []
+    caps_p, omegas_p, caches_p, rows_p = [], [], [], []
 
     memx_params = memcom["params"] if memcom is not None else None
     memx_src = memcom["src"] if memcom is not None else None
@@ -262,12 +264,14 @@ def forward(
                      lsrc=_lw_prefix(memx_src, i))
         h = constrain(h)
         aux_loss = aux_loss + a["moe_loss"]
+        if a["moe_rows"] is not None:
+            rows_p.append(a["moe_rows"][None])
         if c is not None:
             caches_p.append(c)
         if a["omega"] is not None:
             omegas_p.append(a["omega"])
 
-    period_caches, period_caps, period_omegas = {}, {}, {}
+    period_caches, period_caps, period_omegas, period_rows = {}, {}, {}, {}
     if cfg.layout.repeats:
         # Under block tables the period's block pools ride in the carry
         # as whole (repeats, ...) stacks that each layer updates in place
@@ -293,7 +297,7 @@ def forward(
         def body(carry, xs):
             h, aux, pools = carry
             lp, lpre, lcache, lmemx, lsrc, layer = xs
-            new_caches, new_pools, caps, omegas = {}, {}, {}, {}
+            new_caches, new_pools, caps, omegas, rows = {}, {}, {}, {}, {}
             for j, desc in enumerate(cfg.layout.period):
                 key = f"l{j}"
                 if capture_hiddens:
@@ -310,6 +314,8 @@ def forward(
                     layer=layer if key in pools else None)
                 h = constrain(h)
                 aux = aux + a["moe_loss"]
+                if a["moe_rows"] is not None:
+                    rows[key] = a["moe_rows"]
                 if c is not None:
                     if key in pools:
                         new_pools[key] = {k: c[k] for k in pools[key]}
@@ -317,10 +323,11 @@ def forward(
                     new_caches[key] = c
                 if a["omega"] is not None:
                     omegas[key] = a["omega"]
-            return (h, aux, new_pools), (new_caches, caps, omegas)
+            return (h, aux, new_pools), (new_caches, caps, omegas, rows)
 
         scan_body = jax.checkpoint(body, policy=remat_policy) if remat else body
-        (h, aux_loss, pools), (period_caches, period_caps, period_omegas) = \
+        (h, aux_loss, pools), (period_caches, period_caps, period_omegas,
+                               period_rows) = \
             jax.lax.scan(scan_body, (h, aux_loss, pools), xs,
                          unroll=True if unroll else 1)
         for key, c in pools.items():
@@ -335,8 +342,10 @@ def forward(
             out = hn @ params["lm_head"]
         out = softcap(out, cfg.final_logit_softcap)
 
+    rows_p += [period_rows[key] for key in sorted(period_rows)]
     aux = {
         "moe_loss": aux_loss,
+        "moe_rows": jnp.concatenate(rows_p) if rows_p else None,
         "cache": layerwise(caches_p, period_caches) if cache is not None else None,
         "hiddens": layerwise(caps_p, period_caps) if capture_hiddens else None,
         "omega": layerwise(omegas_p, period_omegas) if memcom is not None else None,

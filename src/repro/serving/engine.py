@@ -83,6 +83,7 @@ See docs/ARCHITECTURE.md for the cache layouts and scheduling design.
 from __future__ import annotations
 
 import copy
+import dataclasses
 import time
 from collections import OrderedDict
 from typing import Dict, Iterable, List, Optional, Tuple
@@ -93,6 +94,7 @@ import numpy as np
 
 from repro.config import ModelConfig
 from repro.core import memcom
+from repro.kernels import ops
 from repro.models import transformer as tfm
 from repro.sharding import rules as sharding_rules
 from repro.sharding.serving import constrain_cache, shard_cache
@@ -296,6 +298,12 @@ class ServingEngine:
         self._gap_samples: List[float] = []  # every decode gap (p50/p99)
         self._gap_window: List[float] = []   # gaps since last autotune step
         self.request_log: Dict[int, dict] = {}  # per-request SLO timings
+        if cfg.moe is not None and cfg.moe.capacity_factor is not None:
+            # serving routes dropless: every program (compress, prefill,
+            # decode) sizes each held expert's buffer for all its tokens,
+            # so no request's output depends on its batch-mates' routing
+            cfg = cfg.replace(moe=dataclasses.replace(cfg.moe,
+                                                      capacity_factor=None))
         self.cfg = cfg
         self.slots = slots
         self.max_len = max_len
@@ -356,6 +364,18 @@ class ServingEngine:
             # speculative decoding
             "spec_rounds": 0, "draft_proposed": 0, "draft_accepted": 0,
         }, help="engine loop counter")
+        # MoE dispatch, summed from the row counts each MoE step program
+        # returns with its output: the real tokens' pairs routed to a
+        # held expert and those whose expert is held elsewhere, rows the
+        # grouped matmuls computed beyond the routed ones (tile padding,
+        # bucket padding and idle slots), grouped-matmul kernel calls,
+        # and (layer, held expert) pairs that got at least one row
+        self._moe_counters = (self.metrics.group("serving_moe", {
+            "routed_rows": 0, "absent_rows": 0, "padded_rows": 0,
+            "gmm_calls": 0, "expert_hits": 0,
+        }, help="MoE dispatch counter") if any(
+            d.mlp == "moe" for d in cfg.layout.descriptors()) else None)
+        self._gmm_kernel = ops.gmm_uses_kernel(impl)
         self._m_gap = self.metrics.histogram(
             "serving_decode_gap_seconds",
             "non-decode time between consecutive decode steps")
@@ -440,12 +460,20 @@ class ServingEngine:
             # every later step then pays a reshard of the whole pool
             return constrain_cache(cache, mesh, rules, pool_rows)
 
+        def out(logits, aux):
+            # MoE stacks return the held experts' row counts beside the
+            # logits, fetched with them (stats()["moe"])
+            if aux["moe_rows"] is None:
+                return logits
+            return logits, aux["moe_rows"]
+
         def prefill_fn(params, cache, tokens, slot, base):
             row = _slice_slot(cache, slot)
             logits, aux = tfm.forward(
                 params, cfg, tokens=tokens, cache=row, cache_index=base,
                 mask_offset=base, mesh=mesh, impl=impl)
-            return logits[0], pin(_merge_slot(cache, aux["cache"], slot))
+            return (out(logits[0], aux),
+                    pin(_merge_slot(cache, aux["cache"], slot)))
 
         def paged_prefill_fn(params, cache, tokens, slot, table_row, base):
             row = _slice_slot_paged(cache, slot)
@@ -453,23 +481,28 @@ class ServingEngine:
                 params, cfg, tokens=tokens, cache=row, cache_index=base,
                 mask_offset=base, block_tables=table_row[None, :], mesh=mesh,
                 impl=impl)
-            return logits[0], pin(_merge_slot_paged(cache, aux["cache"], slot))
+            return (out(logits[0], aux),
+                    pin(_merge_slot_paged(cache, aux["cache"], slot)))
 
         def decode_fn(params, cache, tok, lengths):
             logits, aux = tfm.forward(
                 params, cfg, tokens=tok, cache=cache, cache_index=lengths,
                 decode=True, mesh=mesh, impl=impl)
-            return logits[:, -1], pin(aux["cache"])
+            return out(logits[:, -1], aux), pin(aux["cache"])
 
         def paged_decode_fn(params, cache, tok, lengths, tables):
             logits, aux = tfm.forward(
                 params, cfg, tokens=tok, cache=cache, cache_index=lengths,
                 decode=True, block_tables=tables, mesh=mesh, impl=impl)
-            return logits[:, -1], pin(aux["cache"])
+            return out(logits[:, -1], aux), pin(aux["cache"])
 
         def greedy(step):
             def fn(params, cache, tok, lengths, *rest):
                 logits, new_cache = step(params, cache, tok, lengths, *rest)
+                if isinstance(logits, tuple):  # MoE: the row counts follow
+                    logits, rows = logits
+                    ids = jnp.argmax(logits, -1).astype(jnp.int32)
+                    return jnp.concatenate([ids, rows.reshape(-1)]), new_cache
                 # argmax on device: ship (slots,) ids, not (slots, vocab)
                 return jnp.argmax(logits, -1).astype(jnp.int32), new_cache
             # runs under the step's own name (jit_paged_decode_fn), which
@@ -700,6 +733,8 @@ class ServingEngine:
                     lane_valid=valids, mesh=mesh, impl=impl)
                 out = (jnp.argmax(logits, -1).astype(jnp.int32)
                        if greedy else logits)
+                if aux["moe_rows"] is not None:  # fetched with the output
+                    out = (out, aux["moe_rows"])
                 comp_out = None
                 if body is not None:
                     compressor, src_cache, chunk = comp
@@ -1138,7 +1173,9 @@ class ServingEngine:
                     # state (idle rows included), so all slots are dirty
                     self._dirty[:] = True
                 with tr.phase("decode.fetch"):
-                    out = np.asarray(out)  # greedy: (slots,) ids; else logits
+                    # greedy: (slots,) ids; else logits
+                    out = self._fetch(out, np.isin(np.arange(self.slots),
+                                                   active))
                 with tr.phase("tokens"):
                     self._counters["decode_time_s"] += self.clock() - t_start
                     if last_decode_done is not None:
@@ -1257,7 +1294,8 @@ class ServingEngine:
                     self._dirty[:] = True
                 with tr.phase("fused.fetch"):
                     # greedy: (slots, W) ids; else logits
-                    out = np.asarray(out)
+                    out = self._fetch(
+                        out, (np.arange(W) < valids[:, None]).reshape(-1))
                 with tr.phase("tokens"):
                     self._counters["decode_time_s"] += self.clock() - t_start
                     if last_decode_done is not None:
@@ -1708,6 +1746,8 @@ class ServingEngine:
         after their untimed jit-warmup serves."""
         for k in self._counters:
             self._counters[k] = type(self._counters[k])(0)
+        for k in self._moe_counters or ():
+            self._moe_counters[k] = 0
         self._gap_samples = []
         self._gap_window = []
         for k in self.store.stats:
@@ -1757,6 +1797,8 @@ class ServingEngine:
                 "autotune": bool(self._autotune),
             },
         }
+        if self._moe_counters is not None:
+            out["moe"] = dict(self._moe_counters)
         if self.fused or self.spec_k:
             out["fused"] = {
                 "enabled": self.fused,
@@ -1844,7 +1886,45 @@ class ServingEngine:
                 self.cache = new_cache
                 self._dirty[slot] = True
         with self.tracer.phase("prefill.fetch"):
+            if isinstance(logits, tuple):  # MoE: the row counts follow
+                row, rows = jax.device_get((logits[0][n - 1], logits[1]))
+                self._note_rows(rows, np.arange(width) < n)
+                return row
             return np.asarray(logits[n - 1])
+
+    def _fetch(self, out, valid: np.ndarray) -> np.ndarray:
+        """A step's output on the host; for MoE stacks the held experts'
+        row counts ride with it, as a pair or (greedy decode) after the
+        slots' ids, and are split off into the counters.  ``valid`` says
+        which of the step's tokens are real."""
+        if self._moe_counters is None:
+            return np.asarray(out)
+        if isinstance(out, tuple):
+            out, rows = jax.device_get(out)
+        else:
+            out = np.asarray(out)
+            out, rows = out[:self.slots], out[self.slots:]
+        self._note_rows(rows, valid)
+        return out
+
+    def _note_rows(self, rows: np.ndarray, valid: np.ndarray) -> None:
+        """Count one MoE program's dispatch from its ``moe_rows``
+        (:func:`repro.models.moe.split_rows`) over the tokens that
+        ``valid`` marks real; the rest of their top-k pairs went to
+        experts held elsewhere."""
+        from repro.models.moe import split_rows
+
+        m = self.cfg.moe
+        rows = np.asarray(rows).reshape(-1, m.held + 1 + len(valid))
+        per_expert, computed, per_token = split_rows(rows, m.held)
+        layers, routed = rows.shape[0], int(per_token[:, valid].sum())
+        c = self._moe_counters
+        c["routed_rows"] += routed
+        c["absent_rows"] += layers * int(valid.sum()) * m.top_k - routed
+        c["padded_rows"] += int(computed.sum()) - routed
+        if self._gmm_kernel:  # gate, up and down projections a layer
+            c["gmm_calls"] += 3 * layers
+        c["expert_hits"] += int((per_expert > 0).sum())
 
     # ------------------------------------------------------------------
     # Paged capacity management

@@ -4,7 +4,7 @@ The compress → serve handoff (paper §1) in three steps:
 
 1. :func:`materialize_prefix` pushes the compressor's per-layer output
    O^i through the frozen target's projections, yielding the layer-family
-   cache entries (``attn → k/v``, ``mla → ckv/kr``, ``mamba → ssm``
+   cache entries (``attn → k/v``, ``mla → lat``, ``mamba → ssm``
    passthrough; see docs/ARCHITECTURE.md for the exact shapes).
 2. :class:`PrefixStore` caches one materialized prefix per ICL task — the
    "many users, each with their own compressed task memory" serving shape.
@@ -30,14 +30,16 @@ import jax.numpy as jnp
 from repro.config import ModelConfig
 from repro.kernels import ops
 from repro.models.attention import project_kv
-from repro.models.mla import _latent  # shared latent-cache constructor
+from repro.models.mla import latent_rows  # the latent cache's rows
 from repro.models.transformer import POOL_KEYS as _KV_KEYS
 from repro.serving.block_pool import BlockAllocator
 
 
 def materialize_prefix(target_params, cfg: ModelConfig, prefix):
     """Turn {"h": O^i} entries into precomputed compressed caches:
-    attn -> {"k","v"}; mla -> {"ckv","kr"}; mamba -> passthrough state."""
+    attn -> {"k","v"}; mla -> {"lat"} (rows ``[ckv | k_rope]`` through
+    the target's ``wdkv``/``kv_norm`` and rotary positions 0..m-1);
+    mamba -> passthrough state."""
 
     def project(desc, layer_params, entry):
         if "h" not in entry:
@@ -48,8 +50,7 @@ def materialize_prefix(target_params, cfg: ModelConfig, prefix):
         if cfg.mrope_sections:
             pos = jnp.broadcast_to(pos, (3, B, m))
         if desc.mixer == "mla":
-            ckv, kr = _latent(layer_params["attn"], cfg, h, pos)
-            return {"ckv": ckv, "kr": kr[:, :, 0, :]}
+            return {"lat": latent_rows(layer_params["attn"], cfg, h, pos)}
         k, v = project_kv(layer_params["attn"], cfg, h, pos)
         return {"k": k, "v": v}
 

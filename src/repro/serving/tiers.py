@@ -316,6 +316,8 @@ class TieredPrefixStore:
                         lanes = cfg.num_kv_heads * cfg.hd
                         g = g[..., :lanes].reshape(
                             g.shape[:-1] + (cfg.num_kv_heads, cfg.hd))
+                    elif key == "lat":  # latent rows, without the pad lanes
+                        g = g[..., :cfg.mla.latent_dim]
                     out[key] = np.asarray(g)
             return out
 

@@ -14,7 +14,7 @@ whole serving design preserves: **attention splits by head**.
   structure are identical on every shard, so the host-side block tables
   and per-slot length vectors stay plain replicated numpy and the control
   plane never becomes mesh-aware.
-* MLA ``ckv``/``kr`` latents have *no* head axis (that is the point of
+* MLA ``lat`` latent rows have *no* head axis (that is the point of
   the absorbed decode) and stay replicated — at kv_lora_rank floats per
   token they are the cheap leaf.
 * mamba ``conv``/``ssm`` recurrent state shards its channel/head dims
@@ -52,8 +52,7 @@ _TRAILING = {
     "v": ("kv_heads", None),
     "ck": ("heads", None),
     "cv": ("heads", None),
-    "ckv": (),
-    "kr": (),
+    "lat": (),
     "h": (),            # compressor output O^i: (B, m, d_model), replicated
     "conv": ("mamba_inner",),
     "ssm": ("mamba_heads", None, None),

@@ -54,7 +54,7 @@ def test_smoke_train_step(arch, rng):
 
 
 @pytest.mark.parametrize("arch", ["smollm-135m", "deepseek-v2-236b",
-                                  "mamba2-370m", "jamba-1.5-large-398b",
+                                  "deepseek-v2-lite", "mamba2-370m", "jamba-1.5-large-398b",
                                   "whisper-medium", "qwen2-vl-2b",
                                   "gemma2-2b"])
 def test_prefill_decode_parity(arch, rng):
@@ -104,6 +104,7 @@ def test_param_count_matches_published_scale():
         "mistral-nemo-12b": (11e9, 13.5e9),
         "mamba2-370m": (0.3e9, 0.5e9),
         "deepseek-v2-236b": (200e9, 260e9),
+        "deepseek-v2-lite": (14e9, 17e9),
         "jamba-1.5-large-398b": (330e9, 430e9),
     }
     for arch, (lo, hi) in expect.items():

@@ -53,7 +53,7 @@ def test_moe_matches_dense_at_lossless_capacity(rng):
     cfg = _cfg(E=8, k=2, cf=8.0 / 2.0)  # C >= N·k/E·(E/k) = N: no drops
     p = _params(cfg)
     x = jnp.asarray(rng.standard_normal((2, 16, 32)) * 0.5, jnp.float32)
-    y, aux = apply_moe(p, cfg, x)
+    y, aux, _ = apply_moe(p, cfg, x)
     y_ref = _dense_reference(p, cfg, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                atol=1e-5, rtol=1e-5)
@@ -68,8 +68,8 @@ def test_grouped_dispatch_matches_global(rng, groups):
     cfgG = _cfg(E=8, k=2, cf=4.0, groups=groups)
     p = _params(cfg1)
     x = jnp.asarray(rng.standard_normal((2, 16, 32)) * 0.5, jnp.float32)
-    y1, a1 = apply_moe(p, cfg1, x)
-    yG, aG = apply_moe(p, cfgG, x)
+    y1, a1, _ = apply_moe(p, cfg1, x)
+    yG, aG, _ = apply_moe(p, cfgG, x)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(yG),
                                atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(float(a1), float(aG), rtol=1e-6)
@@ -84,7 +84,7 @@ def test_capacity_drops_zero_dropped_tokens(rng):
     # capacity rounds up to 8, so shrink further: N=4 tokens, C=8 means
     # nothing actually drops here — use many tokens instead
     x_big = jnp.asarray(rng.standard_normal((1, 512, 32)), jnp.float32)
-    y, _ = apply_moe(p, cfg, x_big)
+    y, _, _ = apply_moe(p, cfg, x_big)
     # C=8 slots per expert × 8 experts = 64 of 1024 assignments survive
     kept_rows = (np.abs(np.asarray(y)).sum(-1) > 0).sum()
     assert kept_rows <= 64 * 2  # each kept assignment affects ≤1 token/expert
@@ -94,7 +94,7 @@ def test_shared_expert_always_on(rng):
     cfg = _cfg(E=4, k=1, cf=1e-9, shared=1)
     p = _params(cfg)
     x = jnp.asarray(rng.standard_normal((1, 256, 32)), jnp.float32)
-    y, _ = apply_moe(p, cfg, x)
+    y, _, _ = apply_moe(p, cfg, x)
     # even fully-dropped tokens get the shared-expert path
     assert (np.abs(np.asarray(y)).sum(-1) > 0).all()
 

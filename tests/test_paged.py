@@ -212,12 +212,12 @@ def test_cow_isolation(setup, rng):
         tables = jnp.asarray(eng.tables[1:2])
         leaves = []
         for entry in eng.cache.get("prefix", []):
-            for key in ("k", "v", "ckv", "kr"):
+            for key in ("k", "v", "lat"):
                 if key in entry:
                     leaves.append(np.asarray(
                         ops.paged_gather(entry[key], tables))[:, :m])
         for entry in eng.cache.get("period", {}).values():
-            for key in ("k", "v", "ckv", "kr"):
+            for key in ("k", "v", "lat"):
                 if key in entry:
                     for r in range(entry[key].shape[0]):
                         leaves.append(np.asarray(
@@ -413,11 +413,11 @@ def _block_leaves(eng, blocks):
     """Bit-exact content of the given pool blocks across every KV leaf."""
     out = []
     for entry in eng.cache.get("prefix", []):
-        for key in ("k", "v", "ckv", "kr"):
+        for key in ("k", "v", "lat"):
             if key in entry:
                 out.append(np.asarray(entry[key][np.asarray(blocks)]))
     for entry in eng.cache.get("period", {}).values():
-        for key in ("k", "v", "ckv", "kr"):
+        for key in ("k", "v", "lat"):
             if key in entry:
                 out.append(np.asarray(entry[key][:, np.asarray(blocks)]))
     return out

@@ -101,15 +101,61 @@ def test_paged_decode_stacked_compiles(one_chip):
     assert not re.search(rf"= \S*\[{N},{bs},\S* (copy|dynamic-slice)\(", text)
 
 
-def test_paged_step_programs_update_the_pool_in_place(one_chip, monkeypatch):
-    """The serving engine's paged decode and prefill step programs at
-    smollm-360m widths (2 layers, a pool of 2,241 blocks of 16, 32 slots
-    of 37 table columns) update the KV pool in place: the cache is
-    donated (its buffers alias the outputs), nothing copies a pool, and
-    no slice or scatter produces one layer's pool — only the kernel's
-    block reads and the row writes touch it.  Arguments and results take
-    the chip's default layouts, as the engine's arrays do."""
+def _dense_cfg(name, layers, d, hq, hkv, hd, d_ff, vocab, theta, tied):
     from repro.config import LayerDesc, LayerLayout, ModelConfig
+
+    return ModelConfig(
+        name=name, family="dense",
+        layout=LayerLayout.uniform(LayerDesc("attn", "dense"), layers),
+        d_model=d, num_heads=hq, num_kv_heads=hkv, head_dim=hd, d_ff=d_ff,
+        vocab_size=vocab, rope_theta=theta, tie_embeddings=tied,
+        max_seq=8192, dtype="bfloat16")
+
+
+def _dsv2lite_2l():
+    """DeepSeek-V2-Lite's widths, cut to its dense layer and one MoE layer
+    holding 8 of the 64 experts, as one chip of the benchmark holds them."""
+    import dataclasses
+
+    from repro.config import LayerDesc, LayerLayout
+    from repro.configs import get_config
+
+    cfg = get_config("deepseek-v2-lite")
+    return cfg.replace(
+        layout=LayerLayout(prefix=(LayerDesc("mla", "dense"),),
+                           period=(LayerDesc("mla", "moe"),), repeats=1),
+        moe=dataclasses.replace(cfg.moe, held_experts=8))
+
+
+# (config, pool blocks, block size, slots, table columns, prefill base)
+POOL_CASES = {
+    # smollm-360m widths: 15/5 heads of 64, 384-lane rows
+    "smollm360m": (lambda: _dense_cfg("smollm-360m-2l", 2, 960, HQ, HKV, HD,
+                                      2560, 49152, 1e5, True),
+                   2241, 16, 32, 37, 512),
+    # mistral-7b widths: 32/8 heads of 128, 1,024-lane rows
+    "mistral7b": (lambda: _dense_cfg("mistral-7b-2l", 2, 4096, 32, 8, 128,
+                                     14336, 32768, 1e6, False),
+                  961, 16, 32, 53, 768),
+    # deepseek-v2-lite widths: one 640-lane latent row [ckv|k_rope|0]
+    "dsv2lite": (_dsv2lite_2l, 2305, 16, 256, 53, 768),
+}
+
+
+@pytest.mark.parametrize("case", list(POOL_CASES))
+def test_paged_step_programs_update_the_pool_in_place(one_chip, monkeypatch,
+                                                      case):
+    """The serving engine's paged decode and prefill step programs (2
+    layers at each configuration's widths, its pool, slots and table)
+    update the KV pool in place: the cache is donated (its buffers alias
+    the outputs), nothing copies or concatenates a pool, and no slice
+    produces one layer's pool — only the kernel's block reads and the row
+    writes touch it.  With every layer in the scanned period (the dense
+    cases) no scatter yields one layer's pool either: the row writes go
+    into the carried (layers, ...) stack; DeepSeek's leading dense layer
+    has a pool of its own, which its row scatter updates in place.
+    Arguments and results take the chip's default layouts, as the
+    engine's arrays do."""
     from repro.kernels import ops
     from repro.models import transformer as tfm
     from repro.serving import ServingEngine
@@ -119,14 +165,9 @@ def test_paged_step_programs_update_the_pool_in_place(one_chip, monkeypatch):
     # it (not the CPU interpreter) and the cache donated
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     monkeypatch.setattr(engine_mod, "_donates_cache", lambda *_: True)
-    N, bs, slots, cols = 2241, 16, 32, 37
-    cfg = ModelConfig(
-        name="smollm-360m-2l", family="dense",
-        layout=LayerLayout.uniform(LayerDesc("attn", "dense"), 2),
-        d_model=960, num_heads=HQ, num_kv_heads=HKV, d_ff=2560,
-        vocab_size=49152, rope_theta=1e5, tie_embeddings=True, max_seq=8192,
-        dtype="bfloat16")
-    eng = ServingEngine(cfg, tfm.init_params(cfg, 0), slots=slots,
+    make, N, bs, slots, cols, base = POOL_CASES[case]
+    cfg = make()
+    eng = ServingEngine(cfg, tfm.abstract_params(cfg), slots=slots,
                         max_len=cols * bs, kv_layout="paged", block_size=bs,
                         num_blocks=N, impl="pallas")
 
@@ -138,18 +179,21 @@ def test_paged_step_programs_update_the_pool_in_place(one_chip, monkeypatch):
         return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
 
     params, cache = shapes(eng.params), shapes(eng.cache)
-    # a copy of any pool or stack, or a slice or scatter that yields one
-    # layer's pool; the row scatter into the carried (layers, ...) stack
-    # is in place (copy insertion would put a copy before it otherwise)
+    # a copy or concatenation of any pool or stack, or a slice (or, all
+    # layers stacked, a scatter) that yields one layer's pool; the row
+    # scatter into the carried (layers, ...) stack is in place (copy
+    # insertion would put a copy before it otherwise)
+    one_layer = "scatter|dynamic-slice" if not cfg.layout.prefix \
+        else "dynamic-slice"
     pool = re.compile(
-        rf"= \S*\[\S*{N},{bs},\S* (copy|copy-start)\("
-        rf"|= \S*\[(1,)?{N},{bs},\S* (scatter|dynamic-slice)\(")
+        rf"= \S*\[\S*{N},{bs},\S* (copy|copy-start|concatenate)\("
+        rf"|= \S*\[(1,)?{N},{bs},\S* ({one_layer})\(")
     programs = {  # jitted step and its arguments (the prefill's base last)
         "decode": (eng._decode_greedy,
                    (params, cache, i32(slots, 1), i32(slots),
                     i32(slots, cols))),
         "prefill": (eng._prefill,
-                    (params, cache, i32(1, 64), i32(), i32(cols), 512)),
+                    (params, cache, i32(1, 64), i32(), i32(cols), base)),
     }
     for name, (step, args) in programs.items():
         text = step.lower(*args).compile().as_text()
